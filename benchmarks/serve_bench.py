@@ -66,6 +66,7 @@ _ensure_tp_devices()
 import jax  # noqa: E402  (after the device-count env fixup)
 
 import repro.configs as C
+from repro.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.serving.engine import Engine, EngineConfig
 from repro.serving.workload import ArrivalSpec, LengthDist, Trace, TraceSpec, generate
@@ -620,6 +621,7 @@ def _tp_gate(args, params, cfg, trace: Trace) -> Dict[str, Any]:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinymistral_248m")
     ap.add_argument("--ql", type=int, default=4)

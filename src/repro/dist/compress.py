@@ -18,7 +18,6 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 
 
@@ -58,6 +57,6 @@ def make_compressed_allreduce(mesh: Mesh, axes: Sequence[str], specs):
                                          is_leaf=lambda p: isinstance(p, tuple))
         return mean, new_err
 
-    fn = shard_map(shard_fn, mesh=mesh, in_specs=(specs, specs),
-                   out_specs=(specs, specs))
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=(specs, specs),
+                       out_specs=(specs, specs))
     return jax.jit(fn)

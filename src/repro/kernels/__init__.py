@@ -1,3 +1,17 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels for the paper's hot spots (LUT-GEMV, typeconv).
+
+Backend selection for every kernel lives here: a kernel compiles for the
+TPU when one is attached and runs the Pallas interpreter otherwise.
+"""
+from __future__ import annotations
+
+import jax
+
+
+def default_interpret() -> bool:
+    """Interpret Pallas kernels only when no TPU is attached.
+
+    The one place the interpreter is chosen, so a TPU run never silently
+    executes it.
+    """
+    return jax.default_backend() != "tpu"

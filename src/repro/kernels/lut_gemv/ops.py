@@ -1,8 +1,7 @@
 """Jitted public wrapper for the LUT-dequant matmul kernel.
 
 Handles padding to block multiples, block-size selection (VMEM budgeting),
-backend selection (compiled Pallas on TPU, interpret mode off-TPU), the
-int-activation dispatch (real low-bit serve path whenever the QTensor
+the int-activation dispatch (real low-bit serve path whenever the QTensor
 carries ``abits``), and the jnp fallback used inside the 512-device
 dry-run (same semantics as the kernel; the kernel itself is validated
 against ``ref.lut_matmul_ref`` / ``ref.lut_matmul_ref_int``).
@@ -16,50 +15,48 @@ import jax.numpy as jnp
 
 from repro.core.quant import QTensor, quantize_activations, words_per_group
 from repro.kernels.lut_gemv.kernel import (lut_matmul_int_pallas,
-                                           lut_matmul_pallas)
+                                           lut_matmul_pallas, vmem_bytes)
 from repro.kernels.lut_gemv.ref import lut_matmul_ref, lut_matmul_ref_int
 
-VMEM_BUDGET = 64 * 2**20  # bytes; leave headroom below the 128MB v5e VMEM+
-
-
-def default_interpret() -> bool:
-    """Interpret the kernel only when no TPU is attached.
-
-    Backend selection lives here — not in the kernel defaults — so a real
-    TPU run never silently executes the Pallas interpreter.
-    """
-    return jax.default_backend() != "tpu"
+# Bytes of VMEM one grid step may hold (``kernel.vmem_bytes``): a quarter
+# of v5e's 128 MiB.  Each call sets its scoped limit from its own need.
+VMEM_BUDGET = 32 * 2**20
+MAX_BK = 2048
 
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def pick_blocks(m: int, n: int, k: int, bits: int, group_size: int):
-    """Choose (bm, bn, bk) hardware-aligned and within the VMEM budget.
+def pick_blocks(m: int, n: int, k: int, bits: int, group_size: int,
+                int_act: bool = False):
+    """Choose (bm, bn, bk) hardware-aligned and within ``VMEM_BUDGET``.
 
-    MXU wants multiples of (8, 128); the K block must cover whole quant
-    groups.  Working set per grid step ~ x(bm,bk)4 + packed + scales +
-    w_dequant(bk,bn)4 + acc(bm,bn)4, double-buffered (x2).
+    MXU wants multiples of (8, 128).  A K block covers whole quant groups,
+    and its scales block (``bk // group_size`` rows) must be a multiple of
+    8 sublanes unless one block spans all of K.  The working set is
+    ``kernel.vmem_bytes``, which counts the dequantized tile as well as
+    the pipelined blocks.
     """
     bm = min(_round_up(m, 8), 128)
     bn = min(_round_up(n, 128), 512)
-    bk = min(_round_up(k, group_size), 2048)
-    wpg = words_per_group(bits, group_size)
+    kg = _round_up(k, group_size)
+    step = 8 * group_size              # smallest K block short of all of K
+    if kg <= max(MAX_BK, step):
+        bk = kg
+    else:
+        bk = max(step, MAX_BK // step * step)
 
-    def vmem(bm, bn, bk):
-        x = bm * bk * 4
-        pk = (bk // group_size) * wpg * bn * 4
-        sc = (bk // group_size) * bn * 4
-        w = bk * bn * 4
-        acc = bm * bn * 4
-        return 2 * (x + pk + sc) + w + acc
+    def fits():
+        return vmem_bytes(bm, bn, bk, bits, group_size,
+                          int_act) <= VMEM_BUDGET
 
-    while vmem(bm, bn, bk) > VMEM_BUDGET and bk > group_size:
+    while not fits() and bk % (2 * step) == 0:
         bk //= 2
-        bk = _round_up(bk, group_size)
-    while vmem(bm, bn, bk) > VMEM_BUDGET and bn > 128:
-        bn //= 2
+    while not fits() and bn > 128:
+        bn = _round_up(bn // 2, 128)
+    while not fits() and bm > 8:
+        bm = _round_up(bm // 2, 8)
     return bm, bn, bk
 
 
@@ -99,8 +96,6 @@ def lut_matmul(x: jax.Array, qt: QTensor, out_dtype=jnp.float32,
                                     backend=backend, interpret=interpret)
     if backend == "jnp":
         return lut_matmul_ref(x, qt, out_dtype)
-    if interpret is None:
-        interpret = default_interpret()
     m, k = x.shape
     n = qt.n
     bm, bn, bk = pick_blocks(m, n, k, qt.bits, qt.group_size)
@@ -128,11 +123,9 @@ def lut_matmul_quantized(x_q: jax.Array, x_scale: jax.Array, qt: QTensor,
     abits = qt.abits if qt.abits is not None else 8
     if backend == "jnp":
         return lut_matmul_ref_int(x_q, x_scale, qt, out_dtype)
-    if interpret is None:
-        interpret = default_interpret()
     m, k = x_q.shape
     n = qt.n
-    bm, bn, bk = pick_blocks(m, n, k, qt.bits, qt.group_size)
+    bm, bn, bk = pick_blocks(m, n, k, qt.bits, qt.group_size, int_act=True)
     mp, np_, kp = _round_up(m, bm), _round_up(n, bn), _round_up(k, bk)
 
     if (mp, kp) != (m, k):

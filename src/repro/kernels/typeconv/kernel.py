@@ -14,10 +14,13 @@ Used fused at the tail of the serving path to keep dequantization off the
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import default_interpret
 
 
 def int_to_f32_compute(a: jax.Array, n: int) -> jax.Array:
@@ -73,9 +76,12 @@ def _typeconv_kernel(a_ref, o_ref, *, n: int):
 
 @functools.partial(jax.jit, static_argnames=("n", "block", "interpret"))
 def int_to_f32_pallas(a: jax.Array, n: int = 25, block: int = 512,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: Optional[bool] = None) -> jax.Array:
     """Vectorized Algorithm 1 over a 2D array [R, C] (R % 8 == 0 padded by
-    ops.py; C % 128 == 0)."""
+    ops.py; C % 128 == 0).  ``interpret=None`` takes the platform's choice
+    (``repro.kernels.default_interpret``)."""
+    if interpret is None:
+        interpret = default_interpret()
     r, c = a.shape
     grid = (r // 8, c // block) if c % block == 0 else (r // 8, 1)
     bc = block if c % block == 0 else c

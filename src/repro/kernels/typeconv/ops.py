@@ -1,6 +1,8 @@
 """Public wrapper for the Algorithm-1 conversion kernel (padding + fallback)."""
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
@@ -9,11 +11,12 @@ from repro.kernels.typeconv.kernel import int_to_f32_pallas
 
 
 def int_to_f32(a: jax.Array, n: int = 25, backend: str = "pallas",
-               interpret: bool = True) -> jax.Array:
+               interpret: Optional[bool] = None) -> jax.Array:
     """Convert int array (|a| < 2**(n-1), n <= 25) to f32, Algorithm 1.
 
     backend "jnp" uses the pure-JAX line-by-line implementation from
-    repro.core.typeconv; "pallas" runs the TPU kernel (interpret on CPU).
+    repro.core.typeconv; "pallas" runs the TPU kernel (compiled on a TPU,
+    interpreted elsewhere when ``interpret`` is None).
     """
     if backend == "jnp":
         return _core_int_to_f32(a.reshape(-1), n).reshape(a.shape)

@@ -16,14 +16,8 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     n = int(np.prod(shape))
-    try:
-        return jax.make_mesh(shape, axes)
-    except (ValueError, RuntimeError):
-        # jax.make_mesh wants exactly len(jax.devices()) in some versions;
-        # build explicitly from the first n devices (dry-run uses 512
-        # host devices for both meshes).
-        devs = np.asarray(jax.devices()[:n]).reshape(shape)
-        return Mesh(devs, axes)
+    # the dry-run forces 512 host devices for both meshes: take the first n
+    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
 
 
 def make_mesh(shape, axes) -> Mesh:

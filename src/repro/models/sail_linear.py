@@ -60,13 +60,18 @@ __all__ = [
 ]
 
 # Module-level backend switch: "jnp" (XLA path — used under pjit / dry-run)
-# or "pallas" (kernel path, interpret=True on CPU).
+# or "pallas" (the kernel: compiled on a TPU, interpreted elsewhere).
 _BACKEND = "jnp"
 
 
 def set_backend(backend: str) -> None:
     global _BACKEND
     assert backend in ("jnp", "pallas")
+    if backend != _BACKEND:
+        # the switch is read at trace time and is not part of any jit
+        # cache key: a program traced under the old backend would keep
+        # serving it (the engine's jitted decode step among them)
+        jax.clear_caches()
     _BACKEND = backend
 
 

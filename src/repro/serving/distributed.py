@@ -47,7 +47,6 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.dist.sharding import (_NAME_RE, _QUANT_FIELDS, _ROW_PARALLEL,
@@ -282,8 +281,9 @@ class TPServing:
                 p, l, {"params": "params", "cache": "cache"}.get(
                     self._kind_of(p), "other")), arrays)
         out_spec = (P(None, None), in_spec["cache"])
-        return jax.jit(shard_map(body, mesh=self.mesh, in_specs=(in_spec,),
-                                 out_specs=out_spec, check_rep=False))
+        return jax.jit(jax.shard_map(body, mesh=self.mesh,
+                                     in_specs=(in_spec,),
+                                     out_specs=out_spec, check_vma=False))
 
     def _get(self, key, arrays, body):
         fn = self._fns.get(key)

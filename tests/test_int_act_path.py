@@ -193,6 +193,29 @@ def test_engine_decode_token_identity_across_backends():
     assert decode("jnp") == decode("pallas")
 
 
+def test_set_backend_retraces_jitted_programs():
+    """A program traced under one backend must not keep serving it after
+    set_backend: the switch is read at trace time, outside any cache key."""
+    qt = _qt(4, None, 256, 256)
+    x = jax.random.normal(jax.random.PRNGKey(4), (8, 256))
+    traced = []
+
+    @jax.jit
+    def f(x, w):
+        traced.append(sail_linear._BACKEND)
+        return mm(x, w)
+
+    try:
+        f(x, qt)
+        sail_linear.set_backend("pallas")
+        f(x, qt)
+        assert "pallas_call" in str(f.trace(x, qt).jaxpr)
+    finally:
+        sail_linear.set_backend("jnp")
+    f(x, qt)
+    assert traced == ["jnp", "pallas", "jnp"]
+
+
 def _iter_qtensors(tree, prefix=""):
     from repro.core.quant import QTensor
     from repro.models.sail_linear import StackedQTensor

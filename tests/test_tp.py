@@ -281,7 +281,6 @@ def test_lut_matmul_shard_map_bitexact():
     res = run_subprocess(textwrap.dedent("""
         import dataclasses, json
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.core import quant
         from repro.kernels.lut_gemv import ops
@@ -322,12 +321,12 @@ def test_lut_matmul_shard_map_bitexact():
 
                 single = ops.lut_matmul(x, qt, backend="jnp")
 
-                col = shard_map(
+                col = jax.shard_map(
                     lambda x, q: ops.lut_matmul(x, q, backend="jnp"),
                     mesh=mesh,
                     in_specs=(P(None, None),
                               qspec(qt, P(None, "model"), P(None, "model"))),
-                    out_specs=P(None, "model"), check_rep=False)(x, qt)
+                    out_specs=P(None, "model"), check_vma=False)(x, qt)
 
                 xq, xs = quant.quantize_activations(x, ab)
                 single_int = ops.lut_matmul_quantized(
@@ -339,11 +338,11 @@ def test_lut_matmul_shard_map_bitexact():
                         xq, xs, local, backend="jnp")
                     return jax.lax.psum(part, "model")
 
-                row = shard_map(
+                row = jax.shard_map(
                     row_body, mesh=mesh,
                     in_specs=(P(None, "model"), P(None, None),
                               qspec(qt, P("model", None), P("model", None))),
-                    out_specs=P(None, None), check_rep=False)(xq, xs, qt)
+                    out_specs=P(None, None), check_vma=False)(xq, xs, qt)
 
                 key = f"w{wb}a{ab}"
                 out[key] = {
@@ -370,7 +369,6 @@ def test_int8_wire_allreduce():
     res = run_subprocess(textwrap.dedent("""
         import json
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.dist.sharding import tp_all_reduce, tp_context
         from repro.launch.mesh import make_mesh
@@ -384,9 +382,9 @@ def test_int8_wire_allreduce():
             def body(p):
                 with tp_context("model", wire):
                     return tp_all_reduce(p[0])
-            fn = shard_map(body, mesh=mesh,
-                           in_specs=(P("model", None, None),),
-                           out_specs=P(None, None), check_rep=False)
+            fn = jax.shard_map(body, mesh=mesh,
+                               in_specs=(P("model", None, None),),
+                               out_specs=P(None, None), check_vma=False)
             return np.asarray(fn(parts))
 
         r8a, r8b, r32 = run(8), run(8), run(32)
