@@ -188,52 +188,55 @@ def block_apply_decode(p, x, cfg: ModelConfig, layer_cache, position,
         q = apply_rope(q, position[:, None], cfg)
         k = apply_rope(k, position[:, None], cfg)
 
-    if block_tables is not None:
-        # paged write: scatter through the block table, then gather the
-        # lane's blocks back into a contiguous [B, S, KV, Dh] view
-        bs = layer_cache["k"].shape[1]
-        nbp = block_tables.shape[1]
-        logical = jnp.clip(position // bs, 0, nbp - 1)
-        off = position % bs
-        phys = jnp.take_along_axis(block_tables, logical[:, None],
-                                   axis=1)[:, 0]
-        gather = lambda pool: pool[block_tables].reshape(
-            (b, nbp * bs) + pool.shape[2:])
-        if quant_kv:
-            kq, ks = quantize_kv(k)
-            vq, vs = quantize_kv(v)
-            kc = _paged_write(layer_cache["k"], kq, phys, off)
-            vc = _paged_write(layer_cache["v"], vq, phys, off)
-            ksc = _paged_write(layer_cache["k_scale"], ks, phys, off)
-            vsc = _paged_write(layer_cache["v_scale"], vs, phys, off)
-            new_cache.update(k=kc, v=vc, k_scale=ksc, v_scale=vsc)
-            kf = gather(kc).astype(jnp.float32) * gather(ksc)
-            vf = gather(vc).astype(jnp.float32) * gather(vsc)
+    # the KV write, the paged gather, the int8 dequant and attention:
+    # one ``attn`` scope in the trace's op names
+    with jax.named_scope("attn"):
+        if block_tables is not None:
+            # paged write: scatter through the block table, then gather the
+            # lane's blocks back into a contiguous [B, S, KV, Dh] view
+            bs = layer_cache["k"].shape[1]
+            nbp = block_tables.shape[1]
+            logical = jnp.clip(position // bs, 0, nbp - 1)
+            off = position % bs
+            phys = jnp.take_along_axis(block_tables, logical[:, None],
+                                       axis=1)[:, 0]
+            gather = lambda pool: pool[block_tables].reshape(
+                (b, nbp * bs) + pool.shape[2:])
+            if quant_kv:
+                kq, ks = quantize_kv(k)
+                vq, vs = quantize_kv(v)
+                kc = _paged_write(layer_cache["k"], kq, phys, off)
+                vc = _paged_write(layer_cache["v"], vq, phys, off)
+                ksc = _paged_write(layer_cache["k_scale"], ks, phys, off)
+                vsc = _paged_write(layer_cache["v_scale"], vs, phys, off)
+                new_cache.update(k=kc, v=vc, k_scale=ksc, v_scale=vsc)
+                kf = gather(kc).astype(jnp.float32) * gather(ksc)
+                vf = gather(vc).astype(jnp.float32) * gather(vsc)
+            else:
+                kc = _paged_write(layer_cache["k"], k, phys, off)
+                vc = _paged_write(layer_cache["v"], v, phys, off)
+                new_cache.update(k=kc, v=vc)
+                kf, vf = gather(kc), gather(vc)
         else:
-            kc = _paged_write(layer_cache["k"], k, phys, off)
-            vc = _paged_write(layer_cache["v"], v, phys, off)
-            new_cache.update(k=kc, v=vc)
-            kf, vf = gather(kc), gather(vc)
-    else:
-        # ring-buffer write at position % cache_len
-        slot = (position % cache_len)[:, None, None, None]
-        if quant_kv:
-            kq, ks = quantize_kv(k)
-            vq, vs = quantize_kv(v)
-            kc = _ring_write(layer_cache["k"], kq, slot)
-            vc = _ring_write(layer_cache["v"], vq, slot)
-            ksc = _ring_write(layer_cache["k_scale"], ks, slot)
-            vsc = _ring_write(layer_cache["v_scale"], vs, slot)
-            new_cache.update(k=kc, v=vc, k_scale=ksc, v_scale=vsc)
-            kf = kc.astype(jnp.float32) * ksc
-            vf = vc.astype(jnp.float32) * vsc
-        else:
-            kc = _ring_write(layer_cache["k"], k, slot)
-            vc = _ring_write(layer_cache["v"], v, slot)
-            new_cache.update(k=kc, v=vc)
-            kf, vf = kc, vc
+            # ring-buffer write at position % cache_len
+            slot = (position % cache_len)[:, None, None, None]
+            if quant_kv:
+                kq, ks = quantize_kv(k)
+                vq, vs = quantize_kv(v)
+                kc = _ring_write(layer_cache["k"], kq, slot)
+                vc = _ring_write(layer_cache["v"], vq, slot)
+                ksc = _ring_write(layer_cache["k_scale"], ks, slot)
+                vsc = _ring_write(layer_cache["v_scale"], vs, slot)
+                new_cache.update(k=kc, v=vc, k_scale=ksc, v_scale=vsc)
+                kf = kc.astype(jnp.float32) * ksc
+                vf = vc.astype(jnp.float32) * vsc
+            else:
+                kc = _ring_write(layer_cache["k"], k, slot)
+                vc = _ring_write(layer_cache["v"], v, slot)
+                new_cache.update(k=kc, v=vc)
+                kf, vf = kc, vc
 
-    attn_out = _decode_attend(q, kf, vf, position, cfg, cache_len)
+        attn_out = _decode_attend(q, kf, vf, position, cfg, cache_len)
     attn_out = tp_all_reduce(
         mm(attn_out.reshape(b, 1, cfg.q_dim), p["attn"]["wo"]))
 

@@ -100,21 +100,25 @@ def _apply_act_quant(x: jax.Array, w: Any):
 
 
 def mm(x: jax.Array, w: Any) -> jax.Array:
-    """x [..., K] @ w [K, N] with QTensor dispatch."""
-    x, w = _apply_act_quant(x, w)
-    if isinstance(w, StackedQTensor) and w.packed.ndim == 2:
-        # a scan-sliced layer: reinterpret as a plain QTensor
-        w = QTensor(packed=w.packed, scales=w.scales,
-                    codebook=w.codebook, bits=w.bits,
-                    group_size=w.group_size, k=w.k, abits=w.abits)
-    if isinstance(w, QTensor):
-        from repro.kernels.lut_gemv.ops import lut_matmul
-        lead = x.shape[:-1]
-        x2 = x.reshape(-1, x.shape[-1])
-        y = lut_matmul(x2, w, out_dtype=x.dtype if x.dtype != jnp.int32
-                       else jnp.float32, backend=_BACKEND)
-        return y.reshape(*lead, w.n)
-    return x @ w
+    """x [..., K] @ w [K, N] with QTensor dispatch.
+
+    Its ops carry the ``sail_mm`` scope in their names, so a profiler
+    trace can sum the matmuls' device time (dequant and dot)."""
+    with jax.named_scope("sail_mm"):
+        x, w = _apply_act_quant(x, w)
+        if isinstance(w, StackedQTensor) and w.packed.ndim == 2:
+            # a scan-sliced layer: reinterpret as a plain QTensor
+            w = QTensor(packed=w.packed, scales=w.scales,
+                        codebook=w.codebook, bits=w.bits,
+                        group_size=w.group_size, k=w.k, abits=w.abits)
+        if isinstance(w, QTensor):
+            from repro.kernels.lut_gemv.ops import lut_matmul
+            lead = x.shape[:-1]
+            x2 = x.reshape(-1, x.shape[-1])
+            y = lut_matmul(x2, w, out_dtype=x.dtype if x.dtype != jnp.int32
+                           else jnp.float32, backend=_BACKEND)
+            return y.reshape(*lead, w.n)
+        return x @ w
 
 
 @jax.tree_util.register_dataclass

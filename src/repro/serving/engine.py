@@ -85,6 +85,7 @@ from repro.core.scheduler import DECODE, IterationScheduler, Request
 from repro.models import lm
 from repro.models.common import ModelConfig
 from repro.models.sail_linear import QuantPolicy, quantize_params
+from repro.serving.trace import SpanTable
 
 
 @functools.partial(jax.jit, static_argnames=("seed", "temperature"))
@@ -201,9 +202,15 @@ class Engine:
         self._plan_units = None
         self._plan_fixed_bytes = 0
         self._iter_cache: Dict[Any, float] = {}
-        # measured decode throughput (stats()["measured_tps"] / drift)
+        # host spans of step() (repro.serving.trace); decode_seconds is
+        # the engine.decode span's total: the measured decode throughput
+        # behind stats()["measured_tps"] / drift
+        self.spans = SpanTable()
         self.decode_seconds = 0.0
         self._decode_tokens = 0
+        # submit -> first prefill, summed over first admissions
+        self.queue_wait_seconds = 0.0
+        self.queue_admissions = 0
         # modeled seconds of the SAME iterations at their true occupancy
         # — the occupancy-matched reference side of stats()["drift"]
         self.modeled_seconds = 0.0
@@ -472,12 +479,13 @@ class Engine:
                     f"{self._clen} — speculative rollback forbids ring "
                     "wrap; raise cache_len or shorten the request")
         self._uid += 1
+        now = time.perf_counter()
         self.sched.submit(Request(uid=self._uid, prompt_len=len(prompt),
                                   max_new_tokens=max_new_tokens,
-                                  arrived_at=time.perf_counter()))
+                                  arrived_at=now))
         self._orig_plen[self._uid] = len(prompt)
         self._gen[self._uid] = list(prompt)
-        self._t0[self._uid] = time.perf_counter()
+        self._t0[self._uid] = now
         if on_token is not None:
             self._on_token[self._uid] = on_token
         return self._uid
@@ -487,22 +495,29 @@ class Engine:
         active slot's pending token (retiring on EOS/max-tokens), then run
         one masked decode for every remaining slot.  Returns True while
         work remains."""
+        with self.spans.span("engine.step"):
+            return self._step()
+
+    def _step(self) -> bool:
         if self.ecfg.mode != "continuous":
             self._serve_batch()
             return not self.sched.idle()
         ctl = self.controller
-        cap = None
-        if ctl is not None and ctl.cfg.shed:
-            free_cap = self._block_free_cap() if self.paged else None
-            cap = ctl.batch_cap(self.ecfg.batch_size, free_cap=free_cap)
-        admitted = self.sched.schedule(
-            max_active=cap,
-            can_admit=self._try_allocate if self.paged else None)
-        if (cap is not None and self.sched.waiting and self.sched.free_slots
-                and self.sched.active >= cap):
-            # free slots exist but the SLO cap is binding: these
-            # admissions are shed (deferred in FIFO), not dropped
-            ctl.record_shed()
+        with self.spans.span("engine.admit"):
+            cap = None
+            if ctl is not None and ctl.cfg.shed:
+                free_cap = self._block_free_cap() if self.paged else None
+                cap = ctl.batch_cap(self.ecfg.batch_size,
+                                    free_cap=free_cap)
+            admitted = self.sched.schedule(
+                max_active=cap,
+                can_admit=self._try_allocate if self.paged else None)
+            if (cap is not None and self.sched.waiting
+                    and self.sched.free_slots
+                    and self.sched.active >= cap):
+                # free slots exist but the SLO cap is binding: these
+                # admissions are shed (deferred in FIFO), not dropped
+                ctl.record_shed()
         if admitted:
             # group same-padded-length admissions into ONE prefill pass:
             # a K-request burst streams each layer's weights once, not K
@@ -512,21 +527,27 @@ class Engine:
             for req in admitted:
                 groups.setdefault(self._padded_len(req), []).append(req)
             for padded, reqs in groups.items():
-                self._prefill_slots(reqs, padded)
+                with self.spans.span(
+                        "engine.prefill",
+                        uids=" ".join(str(r.uid) for r in reqs),
+                        padded=padded,
+                        tokens=sum(r.prompt_len for r in reqs)):
+                    self._prefill_slots(reqs, padded)
         # commit pending tokens, retire finished slots
-        for req in list(self.sched.running):
-            finished = req.generated >= req.max_new_tokens  # max_new == 0
-            if not finished:
-                tok = int(self._cur[req.slot])
-                self._gen[req.uid].append(tok)
-                req.generated += 1
-                cb = self._on_token.get(req.uid)
-                if cb is not None:
-                    cb(req.uid, tok)
-                finished = (tok == self.ecfg.eos_token or
-                            req.generated >= req.max_new_tokens)
-            if finished:
-                self._finish(req)
+        with self.spans.span("engine.commit"):
+            for req in list(self.sched.running):
+                finished = req.generated >= req.max_new_tokens  # max_new == 0
+                if not finished:
+                    tok = int(self._cur[req.slot])
+                    self._gen[req.uid].append(tok)
+                    req.generated += 1
+                    cb = self._on_token.get(req.uid)
+                    if cb is not None:
+                        cb(req.uid, tok)
+                    finished = (tok == self.ecfg.eos_token or
+                                req.generated >= req.max_new_tokens)
+                if finished:
+                    self._finish(req)
         # one masked decode iteration serves every still-active slot
         active = list(self.sched.running)
         spec = self.spec_decoder
@@ -536,8 +557,9 @@ class Engine:
             # verify, plus the bonus slot): grant block slots first (COW
             # off shared blocks, preempt the newest when the pool runs
             # dry)
-            active = self._ensure_append_blocks(
-                active, n=(spec.k + 1) if spec is not None else 1)
+            with self.spans.span("engine.kv_grant"):
+                active = self._ensure_append_blocks(
+                    active, n=(spec.k + 1) if spec is not None else 1)
         self.peak_active = max(self.peak_active, len(active))
         if spec is not None and active:
             self._speculative_round(active, ctl)
@@ -548,38 +570,38 @@ class Engine:
                 mask[req.slot] = True
             capture = (self.tap is not None
                        and self.tap.should_capture(self.decode_iterations))
-            t0 = time.perf_counter()
-            if self.tp_serving is not None:
-                out = self.tp_serving.decode_step(
-                    self.params, jnp.asarray(self._cur[:, None]),
-                    self.cache, quant_kv=self._quant_kv,
-                    active_mask=jnp.asarray(mask),
-                    block_tables=(jnp.asarray(self._tables_np)
-                                  if self.paged else None))
-            else:
-                out = lm.decode_step(
-                    self.params, jnp.asarray(self._cur[:, None]),
-                    self.cache, self.cfg, quant_kv=self._quant_kv,
-                    active_mask=jnp.asarray(mask),
-                    capture_layer_inputs=capture,
-                    block_tables=(jnp.asarray(self._tables_np)
-                                  if self.paged else None))
-            if capture:
-                logits, self.cache, layer_inputs = out
-                self.tap.observe(layer_inputs, mask)
-            else:
-                logits, self.cache = out
-            self.iterations += 1
-            self.decode_iterations += 1
-            uids = np.zeros((self.ecfg.batch_size,), np.uint32)
-            sidx = np.zeros((self.ecfg.batch_size,), np.uint32)
-            for req in active:
-                uids[req.slot] = req.uid
-                sidx[req.slot] = self._sample_index(req.uid)
-            nxt = self._sample(logits, uids, sidx)
-            # _sample's np.asarray blocks on the device, so dt covers the
-            # whole iteration (incl. any tap-capture sync)
-            dt = time.perf_counter() - t0
+            with self.spans.span("engine.decode", lanes=len(active)) as sp:
+                if self.tp_serving is not None:
+                    out = self.tp_serving.decode_step(
+                        self.params, jnp.asarray(self._cur[:, None]),
+                        self.cache, quant_kv=self._quant_kv,
+                        active_mask=jnp.asarray(mask),
+                        block_tables=(jnp.asarray(self._tables_np)
+                                      if self.paged else None))
+                else:
+                    out = lm.decode_step(
+                        self.params, jnp.asarray(self._cur[:, None]),
+                        self.cache, self.cfg, quant_kv=self._quant_kv,
+                        active_mask=jnp.asarray(mask),
+                        capture_layer_inputs=capture,
+                        block_tables=(jnp.asarray(self._tables_np)
+                                      if self.paged else None))
+                if capture:
+                    logits, self.cache, layer_inputs = out
+                    self.tap.observe(layer_inputs, mask)
+                else:
+                    logits, self.cache = out
+                self.iterations += 1
+                self.decode_iterations += 1
+                uids = np.zeros((self.ecfg.batch_size,), np.uint32)
+                sidx = np.zeros((self.ecfg.batch_size,), np.uint32)
+                for req in active:
+                    uids[req.slot] = req.uid
+                    sidx[req.slot] = self._sample_index(req.uid)
+                nxt = self._sample(logits, uids, sidx)
+            # _sample's np.asarray blocks on the device, so the span
+            # covers the whole iteration (incl. any tap-capture sync)
+            dt = sp.seconds
             self.decode_seconds += dt
             self._decode_tokens += len(active)
             exp = self._modeled_iter_seconds(len(active))
@@ -748,28 +770,28 @@ class Engine:
         amask = jnp.asarray(mask)
         tables = jnp.asarray(self._tables_np) if self.paged else None
         temp = self.ecfg.temperature
-        t0 = time.perf_counter()
-        d_toks, d_logits, self.cache = lm.draft_tokens(
-            spec.draft_params, jnp.asarray(self._cur[:, None]),
-            self.cache, self.cfg, k, quant_kv=self._quant_kv,
-            active_mask=amask, block_tables=tables, temperature=temp,
-            seed=self.ecfg.seed, uids=jnp.asarray(uids),
-            indices=jnp.asarray(sidx))
-        draft_np = np.asarray(d_toks)
-        # rewind: verify re-feeds the round from its first position
-        self.cache["length"] = jnp.asarray(prev_len)
-        vt = np.concatenate([self._cur[:, None], draft_np], axis=1)
-        v_logits, self.cache = lm.verify_step(
-            self.params, jnp.asarray(vt), self.cache, self.cfg,
-            quant_kv=self._quant_kv, active_mask=amask,
-            block_tables=tables)
-        n_acc, nxt = spec.accept(
-            draft_np, np.asarray(v_logits),
-            np.asarray(d_logits) if temp > 0 else None,
-            temperature=temp, seed=self.ecfg.seed, uids=uids,
-            indices=sidx)
+        with self.spans.span("engine.decode", lanes=len(active)) as sp:
+            d_toks, d_logits, self.cache = lm.draft_tokens(
+                spec.draft_params, jnp.asarray(self._cur[:, None]),
+                self.cache, self.cfg, k, quant_kv=self._quant_kv,
+                active_mask=amask, block_tables=tables, temperature=temp,
+                seed=self.ecfg.seed, uids=jnp.asarray(uids),
+                indices=jnp.asarray(sidx))
+            draft_np = np.asarray(d_toks)
+            # rewind: verify re-feeds the round from its first position
+            self.cache["length"] = jnp.asarray(prev_len)
+            vt = np.concatenate([self._cur[:, None], draft_np], axis=1)
+            v_logits, self.cache = lm.verify_step(
+                self.params, jnp.asarray(vt), self.cache, self.cfg,
+                quant_kv=self._quant_kv, active_mask=amask,
+                block_tables=tables)
+            n_acc, nxt = spec.accept(
+                draft_np, np.asarray(v_logits),
+                np.asarray(d_logits) if temp > 0 else None,
+                temperature=temp, seed=self.ecfg.seed, uids=uids,
+                indices=sidx)
         # np.asarray above blocked on the device: dt is the whole round
-        dt = time.perf_counter() - t0
+        dt = sp.seconds
         self.iterations += 1
         self.decode_iterations += 1
         produced = 0
@@ -840,6 +862,12 @@ class Engine:
         padding rows and shared-prefix rows are redirected to the trash
         block (shared blocks are append-only for sharers — the KV they
         attend to is the registrant's, bit-identical by construction)."""
+        now = time.perf_counter()
+        for req in reqs:
+            if "admitted_iteration" not in self.events.get(req.uid, {}):
+                # a first admission; a resume after preemption is none
+                self.queue_wait_seconds += now - req.arrived_at
+                self.queue_admissions += 1
         b = len(reqs)
         toks = np.zeros((b, padded), np.int32)
         lengths = np.zeros((b,), np.int32)
@@ -1258,16 +1286,18 @@ class Engine:
         ``uids``/``indices`` carry each row's (request uid, per-request
         sample index); rows without a live request (masked slots) pass
         uid 0 and their draws are discarded by the caller.  Greedy
-        ignores them entirely."""
-        if self.ecfg.temperature <= 0:
-            return np.asarray(jnp.argmax(logits, axis=-1))
-        if uids is None:
-            uids = np.zeros((logits.shape[0],), np.uint32)
-            indices = np.zeros((logits.shape[0],), np.uint32)
-        return np.asarray(_sample_rows_jit(
-            logits, jnp.asarray(np.asarray(uids, np.uint32)),
-            jnp.asarray(np.asarray(indices, np.uint32)),
-            self.ecfg.seed, self.ecfg.temperature))
+        ignores them entirely.  The host waits here for the device (the
+        ``engine.sample`` span)."""
+        with self.spans.span("engine.sample"):
+            if self.ecfg.temperature <= 0:
+                return np.asarray(jnp.argmax(logits, axis=-1))
+            if uids is None:
+                uids = np.zeros((logits.shape[0],), np.uint32)
+                indices = np.zeros((logits.shape[0],), np.uint32)
+            return np.asarray(_sample_rows_jit(
+                logits, jnp.asarray(np.asarray(uids, np.uint32)),
+                jnp.asarray(np.asarray(indices, np.uint32)),
+                self.ecfg.seed, self.ecfg.temperature))
 
     def _tp_stats(self) -> Dict[str, Any]:
         """Observability for tensor-parallel serving: shard count, wire

@@ -1,0 +1,198 @@
+"""Host spans and counters of ``Engine.step`` (``repro.serving.trace``):
+which spans a step records and how they nest, the decode seconds they
+carry, the queue-wait counters, JIT attribution, and the named scopes
+that leave the compiled decode step unchanged."""
+import contextlib
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import repro.configs as C
+from repro.models import lm
+from repro.serving import trace
+from repro.serving.engine import Engine, EngineConfig
+
+STEP_SPANS = ("engine.step", "engine.admit", "engine.prefill",
+              "engine.commit", "engine.kv_grant", "engine.decode",
+              "engine.sample")
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    cfg = C.get_smoke("tinymistral_248m")
+    params = lm.init_params(jax.random.PRNGKey(0), cfg)
+    return cfg, params
+
+
+def make_engine(tiny, **kw):
+    cfg, params = tiny
+    return Engine(params, cfg, EngineConfig(
+        batch_size=4, cache_len=64, quantize=True, ql=4, group_size=32,
+        quant_kv=True, kv_block_size=8, **kw))
+
+
+class Recorder:
+    """Stands in for ``TraceAnnotation``: logs each span's parent."""
+    log = []
+
+    def __init__(self, name, **attrs):
+        self.name, self.attrs = name, attrs
+
+    def __enter__(self):
+        Recorder.log.append(("enter", self.name, self.attrs))
+
+    def __exit__(self, *exc):
+        Recorder.log.append(("exit", self.name, None))
+
+
+def parents(log):
+    out, stack = [], []
+    for kind, name, attrs in log:
+        if kind == "enter":
+            out.append((name, stack[-1] if stack else None, attrs))
+            stack.append(name)
+        else:
+            assert stack.pop() == name
+    assert not stack
+    return out
+
+
+def test_one_step_records_every_span_nested_as_the_code(tiny, monkeypatch):
+    eng = make_engine(tiny)
+    uids = [eng.submit([5, 9, 2, 4, 11], 4), eng.submit([1, 2, 3], 4)]
+    monkeypatch.setattr(trace, "TraceAnnotation", Recorder)
+    Recorder.log = []
+    eng.step()
+    got = parents(Recorder.log)
+    assert {n for n, _, _ in got} == set(STEP_SPANS)
+    assert got[0][:2] == ("engine.step", None)
+    for name in STEP_SPANS[1:-1]:
+        assert {p for n, p, _ in got if n == name} == {"engine.step"}
+    assert ({p for n, p, _ in got if n == "engine.sample"}
+            == {"engine.prefill", "engine.decode"})
+    # both prompts pad to one bucket: one prefill group with both uids
+    (attrs,) = [a for n, _, a in got if n == "engine.prefill"]
+    assert attrs == {"uids": f"{uids[0]} {uids[1]}", "padded": 16,
+                     "tokens": 8}
+    assert [a for n, _, a in got if n == "engine.decode"] == [{"lanes": 2}]
+    for name, (count, secs, self_s, _, _) in eng.spans.items():
+        assert count >= 1 and 0 <= self_s <= secs, name
+    step = eng.spans["engine.step"]
+    children = sum(eng.spans[n][1] for n in STEP_SPANS[1:-1])
+    assert children <= step[1]
+    assert step[2] == pytest.approx(step[1] - children, abs=1e-9)
+
+
+def test_decode_seconds_is_the_decode_span_total(tiny):
+    eng = make_engine(tiny)
+    for p in ([5, 9, 2, 4, 11], [1, 2, 3], [7, 7, 7, 7]):
+        eng.submit(p, 5)
+    eng.run()
+    count, secs = eng.spans["engine.decode"][:2]
+    assert count == eng.decode_iterations > 0
+    assert eng.decode_seconds == secs
+
+
+def test_queue_admissions_count_a_preempted_request_once(tiny):
+    prefix = [5, 9, 2, 4, 11, 3, 8, 1]
+    prompts = [prefix + [7, 6], prefix + [10, 12], prefix + [7, 6],
+               [1, 2, 3], prefix + [13, 14, 15], prefix + [7, 6]]
+    eng = make_engine(tiny, kv_pool_blocks=7)
+    for p in prompts:
+        eng.submit(p, 8)
+    eng.run()
+    assert eng.block_mgr.stats()["preemptions"] > 0
+    # resumes re-prefill, yet each request is admitted from the queue once
+    assert eng.prefill_tokens > sum(map(len, prompts))
+    assert eng.queue_admissions == len(prompts)
+    assert eng.queue_wait_seconds > 0
+
+
+def test_submit_times_arrival_and_ttft_from_one_reading(tiny):
+    eng = make_engine(tiny)
+    uid = eng.submit([1, 2, 3], 2)
+    assert eng.sched.waiting[0].arrived_at == eng._t0[uid]
+
+
+@pytest.fixture
+def fresh_jit_state(monkeypatch):
+    monkeypatch.setattr(trace, "_covered", [])
+    monkeypatch.setattr(trace, "OUTSIDE", [0.0, 0])
+
+
+def test_jit_events_go_to_the_innermost_span_counted_once(
+        fresh_jit_state, monkeypatch):
+    now = [0.0]
+    monkeypatch.setattr(trace, "clock", lambda: now[0])
+    table = trace.SpanTable()
+    trace_ev, compile_ev = trace.JIT_EVENTS[0], trace.JIT_EVENTS[2]
+    with table.span("engine.step"):
+        with table.span("engine.prefill"):
+            now[0] = 2.0     # an inner jit traced over [1, 2] ...
+            jax.monitoring.record_event_duration_secs(trace_ev, 1.0)
+            now[0] = 5.0     # ... inside its caller's trace over [0, 5]
+            jax.monitoring.record_event_duration_secs(trace_ev, 5.0)
+            jax.monitoring.record_event_duration_secs("/other/event", 9.0)
+        now[0] = 7.0
+        jax.monitoring.record_event_duration_secs(compile_ev, 1.5)
+    now[0] = 9.0
+    jax.monitoring.record_event_duration_secs(compile_ev, 0.5)
+    assert table["engine.prefill"][3:] == [5.0, 2]
+    assert table["engine.step"][3:] == [1.5, 1]
+    assert trace.OUTSIDE == [0.5, 1]
+
+
+def test_eager_scan_under_prefill_is_charged_to_it(fresh_jit_state):
+    table = trace.SpanTable()
+
+    @jax.jit
+    def inner(x):
+        return jnp.sin(x) * 2.0
+
+    @jax.jit
+    def outer(x):
+        return inner(x) + 1.0
+
+    with table.span("engine.prefill") as sp:
+        # an eager scan traces its body anew on every call
+        for _ in range(2):
+            jax.lax.scan(lambda c, x: (c + x, c), 0.0,
+                         jnp.arange(4.0))[0].block_until_ready()
+        outer(jnp.ones(3)).block_until_ready()
+    count, secs, self_s, jit_s, jit_n = table["engine.prefill"]
+    assert jit_n >= 4 and 0 < jit_s <= sp.seconds
+    assert "engine.step" not in table
+
+
+def _op_count(hlo: str) -> int:
+    return len(re.findall(r"^\s*(?:ROOT )?%?[\w.\-]+ = ", hlo, re.M))
+
+
+def test_named_scopes_leave_the_compiled_decode_step_unchanged(
+        tiny, monkeypatch):
+    """The ``sail_mm`` and ``attn`` scopes rename ops, nothing else: the
+    optimized decode step has as many ops with them as without."""
+    eng = make_engine(tiny)
+    args = (eng.params, jnp.zeros((4, 1), jnp.int32), eng.cache, eng.cfg)
+    kw = dict(quant_kv=True, active_mask=jnp.ones((4,), bool),
+              block_tables=jnp.asarray(eng._tables_np))
+
+    def compiled():
+        # a persistent-cache key leaves op names out: compile afresh
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
+            jax.clear_caches()
+            return lm.decode_step.lower(*args, **kw).compile().as_text()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+
+    scoped = compiled()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = compiled()
+    assert "sail_mm" in scoped and "/attn/" in scoped
+    assert "sail_mm" not in plain and "/attn/" not in plain
+    assert _op_count(scoped) == _op_count(plain) > 100
