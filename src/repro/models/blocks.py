@@ -142,18 +142,24 @@ def _kv_from_seq(attn_p, h, cfg: ModelConfig, positions):
 
 def block_apply_decode(p, x, cfg: ModelConfig, layer_cache, position,
                        cache_len: int, moe_mode: str = "dense",
-                       quant_kv: bool = False, block_tables=None):
+                       quant_kv: bool = False, block_tables=None,
+                       layer=None):
     """One-token decode.  x: [B, 1, D]; position: [B] absolute positions.
 
     layer_cache holds this layer's state (ring-buffered KV of size
     ``cache_len``, ssm/xlstm states).  Returns (x, new_cache).
 
     block_tables: optional [B, max_blocks] int32 — paged mode, where
-    layer_cache KV is a block pool ``[num_blocks, block_size, KV, Dh]``
-    and ``cache_len == max_blocks * block_size``.  The write scatters
-    through the table; attention gathers the lane's blocks into a
-    contiguous view and reuses the ring validity math (paged lanes never
-    wrap, so "slot holds position slot" makes the two formulas agree).
+    layer_cache is the WHOLE block pool of every layer (lane-dense
+    ``[L, num_blocks, block_size, KV*Dh]``, scales ``[L, num_blocks,
+    KV*block_size]``; ``lm.init_paged_cache``), ``layer`` is this
+    layer's index into it and ``cache_len == max_blocks * block_size``.
+    The write scatters one token per lane at ``(layer, block, offset)``;
+    attention gathers the lane's blocks of this layer into a contiguous
+    view and reuses the ring validity math (paged lanes never wrap, so
+    "slot holds position slot" makes the two formulas agree).  The pool
+    goes in and out whole, so a caller that carries it through its layer
+    loop updates it in place.
     """
     from repro.core.quant import quantize_kv
     from repro.models.layers import apply_rope, _qk_norm
@@ -194,27 +200,27 @@ def block_apply_decode(p, x, cfg: ModelConfig, layer_cache, position,
         if block_tables is not None:
             # paged write: scatter through the block table, then gather the
             # lane's blocks back into a contiguous [B, S, KV, Dh] view
-            bs = layer_cache["k"].shape[1]
+            bs = layer_cache["k"].shape[2]
             nbp = block_tables.shape[1]
             logical = jnp.clip(position // bs, 0, nbp - 1)
             off = position % bs
             phys = jnp.take_along_axis(block_tables, logical[:, None],
                                        axis=1)[:, 0]
-            gather = lambda pool: pool[block_tables].reshape(
-                (b, nbp * bs) + pool.shape[2:])
+            write = lambda name, val: paged_write(
+                layer_cache[name], (layer,), phys, off, val[:, 0])
+            # one gather indexed by (layer, table): pool[layer][table]
+            # would slice the whole layer out of the pool first
+            gather = lambda arr: paged_view(arr[layer, block_tables], cfg)
             if quant_kv:
                 kq, ks = quantize_kv(k)
                 vq, vs = quantize_kv(v)
-                kc = _paged_write(layer_cache["k"], kq, phys, off)
-                vc = _paged_write(layer_cache["v"], vq, phys, off)
-                ksc = _paged_write(layer_cache["k_scale"], ks, phys, off)
-                vsc = _paged_write(layer_cache["v_scale"], vs, phys, off)
+                kc, vc = write("k", kq), write("v", vq)
+                ksc, vsc = write("k_scale", ks), write("v_scale", vs)
                 new_cache.update(k=kc, v=vc, k_scale=ksc, v_scale=vsc)
                 kf = gather(kc).astype(jnp.float32) * gather(ksc)
                 vf = gather(vc).astype(jnp.float32) * gather(vsc)
             else:
-                kc = _paged_write(layer_cache["k"], k, phys, off)
-                vc = _paged_write(layer_cache["v"], v, phys, off)
+                kc, vc = write("k", k), write("v", v)
                 new_cache.update(k=kc, v=vc)
                 kf, vf = gather(kc), gather(vc)
         else:
@@ -272,15 +278,39 @@ def _ring_write(cache, val, slot):
         indices_are_sorted=False)
 
 
-def _paged_write(pool, val, phys, off):
-    """Scatter one token per lane into the paged block pool.
+def paged_write(pool, lead, phys, off, val):
+    """Scatter token rows into a lane-dense paged pool.
 
-    pool [NB, BS, KV, D(or 1)], val [B, 1, KV, D], phys/off [B].
-    No ``unique_indices``: retired lanes share the trash block, so
-    duplicate destinations are expected — their values are dead either
-    way (the engine never reads the trash block through a live table).
+    pool: KV codes ``[.., NB, BS, KV*D]`` or their scales ``[.., NB,
+    KV*BS]`` (both heads-major in the last axis); ``lead`` indexes the
+    leading layer axis (``(layer,)``, ``(slice(None),)`` for every layer,
+    ``()`` for one layer's pool); phys/off [N]: each row's block and
+    in-block offset; val [.., N, KV, D] (scales [.., N, KV, 1]).  No
+    ``unique_indices``: retired lanes share the trash block, so duplicate
+    destinations are expected — their values are dead either way (the
+    engine never reads the trash block through a live table).
     """
-    return pool.at[phys, off].set(val[:, 0].astype(pool.dtype))
+    kv = val.shape[-2]
+    if pool.ndim - len(lead) == 3:                   # codes
+        rows = val.reshape(val.shape[:-2] + pool.shape[-1:])
+        return pool.at[lead + (phys, off)].set(rows.astype(pool.dtype))
+    bs = pool.shape[-1] // kv                        # scales
+    cols = off[:, None] + bs * jnp.arange(kv)
+    return pool.at[lead + (phys[:, None], cols)].set(
+        val[..., 0].astype(pool.dtype))
+
+
+def paged_view(blocks, cfg: ModelConfig):
+    """A lane's gathered blocks as one contiguous [B, S, KV, D] view.
+
+    blocks: codes [B, nbp, BS, KV*D] -> [B, nbp*BS, KV, D]; scales
+    [B, nbp, KV*BS] -> [B, nbp*BS, KV, 1]."""
+    b, nbp = blocks.shape[:2]
+    if blocks.ndim == 4:
+        return blocks.reshape(b, nbp * blocks.shape[2], cfg.n_kv,
+                              cfg.head_dim)
+    heads = blocks.reshape(b, nbp, cfg.n_kv, -1)     # [B, nbp, KV, BS]
+    return jnp.swapaxes(heads, 2, 3).reshape(b, -1, cfg.n_kv, 1)
 
 
 # ---------------------------------------------------------------------------
@@ -332,21 +362,22 @@ def block_apply_verify(p, x, cfg: ModelConfig, layer_cache, position,
         logical = jnp.clip(qpos // bs, 0, nbp - 1)       # [B, T]
         off = qpos % bs
         phys = jnp.take_along_axis(block_tables, logical, axis=1)
-        gather = lambda pool: pool[block_tables].reshape(
-            (b, nbp * bs) + pool.shape[2:])
+        # T tokens per lane, flattened to B*T rows; masked lanes' tables
+        # point at the trash block
+        write = lambda name, val: paged_write(
+            layer_cache[name], (), phys.reshape(-1), off.reshape(-1),
+            val.reshape((b * t,) + val.shape[2:]))
+        gather = lambda arr: paged_view(arr[block_tables], cfg)
         if quant_kv:
             kq, ks = quantize_kv(k)
             vq, vs = quantize_kv(v)
-            kc = _paged_write_multi(layer_cache["k"], kq, phys, off)
-            vc = _paged_write_multi(layer_cache["v"], vq, phys, off)
-            ksc = _paged_write_multi(layer_cache["k_scale"], ks, phys, off)
-            vsc = _paged_write_multi(layer_cache["v_scale"], vs, phys, off)
+            kc, vc = write("k", kq), write("v", vq)
+            ksc, vsc = write("k_scale", ks), write("v_scale", vs)
             new_cache.update(k=kc, v=vc, k_scale=ksc, v_scale=vsc)
             kf = gather(kc).astype(jnp.float32) * gather(ksc)
             vf = gather(vc).astype(jnp.float32) * gather(vsc)
         else:
-            kc = _paged_write_multi(layer_cache["k"], k, phys, off)
-            vc = _paged_write_multi(layer_cache["v"], v, phys, off)
+            kc, vc = write("k", k), write("v", v)
             new_cache.update(k=kc, v=vc)
             kf, vf = gather(kc), gather(vc)
     else:
@@ -391,19 +422,6 @@ def _ring_write_multi(cache, val, slot):
     return cache.at[jnp.arange(b)[:, None], slot].set(
         val.astype(cache.dtype), unique_indices=True,
         indices_are_sorted=False)
-
-
-def _paged_write_multi(pool, val, phys, off):
-    """Scatter T tokens per lane through the block tables.
-
-    pool [NB, BS, KV, D(or 1)], val [B, T, KV, D], phys/off [B, T].
-    Masked lanes' tables point at the trash block, so duplicate
-    destinations are expected there — no ``unique_indices``.
-    """
-    b, t = phys.shape
-    flat = val.reshape((b * t,) + val.shape[2:])
-    return pool.at[phys.reshape(-1), off.reshape(-1)].set(
-        flat.astype(pool.dtype))
 
 
 def _verify_attend(q, k, v, position, cfg: ModelConfig, cache_len: int):

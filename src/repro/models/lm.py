@@ -10,7 +10,7 @@ CLIP patch embeddings) are concatenated ahead of the token embeddings.
 """
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Dict, Optional
 
 import jax
@@ -286,13 +286,26 @@ def init_paged_cache(params, cfg: ModelConfig, max_batch: int,
                      quant_kv: bool = False) -> Dict[str, Any]:
     """Allocate a paged KV block pool.
 
-    KV arrays are ``[L, num_blocks, block_size, n_kv, head_dim]`` — a flat
-    pool of fixed-size blocks shared by every request; which block holds
-    which request's tokens is decided per step by the ``block_tables``
-    argument of :func:`decode_step`.  Callers conventionally reserve the
-    LAST physical block as a trash block: retired lanes' table entries and
-    masked scatter positions point at it so dead writes never land in a
-    live block.  ``length`` is still per-lane (``[max_batch]``).
+    A flat pool of fixed-size blocks shared by every request; which block
+    holds which request's tokens is decided per step by the
+    ``block_tables`` argument of :func:`decode_step`.  The layout is
+    lane-dense, heads-major in the last axis: ``k``/``v`` are ``[L,
+    num_blocks, block_size, n_kv * head_dim]``, and with ``quant_kv`` the
+    int8 codes' f32 scales are ``[L, num_blocks, n_kv * block_size]``.
+    The decode step scatters into and gathers from this layout directly,
+    so the pool rides its layer loop unchanged and is updated in place;
+    attention reshapes each gathered view back to ``[.., n_kv,
+    head_dim]`` (``blocks.paged_view``).  A ``[.., block_size, n_kv]``
+    scale array would leave XLA's TPU layout an 8-wide minor axis, and
+    the compiler then moves both whole scale pools through VMEM on every
+    layer.  Callers conventionally reserve the LAST physical block as a
+    trash block: retired lanes' table entries and masked scatter
+    positions point at it so dead writes never land in a live block.
+    ``length`` is still per-lane (``[max_batch]``).
+
+    The serving engine donates the pool to every decode step
+    (:func:`donating`): a caller holding an earlier ``engine.cache``
+    must not read it after the next step.
 
     Attention families only — recurrent state (ssm/xlstm) is O(1) per lane
     and gains nothing from paging.
@@ -301,8 +314,8 @@ def init_paged_cache(params, cfg: ModelConfig, max_batch: int,
         raise ValueError(
             f"paged KV cache requires an attention family, got {cfg.family!r}")
     nb = n_scan_blocks(cfg)
-    kv_shape = (nb, num_blocks, block_size, cfg.n_kv, cfg.head_dim)
-    sc_shape = (nb, num_blocks, block_size, cfg.n_kv, 1)
+    kv_shape = (nb, num_blocks, block_size, cfg.n_kv * cfg.head_dim)
+    sc_shape = (nb, num_blocks, cfg.n_kv * block_size)
     layers: Dict[str, Any] = {
         "k": jnp.zeros(kv_shape, jnp.int8 if quant_kv else jnp.float32),
         "v": jnp.zeros(kv_shape, jnp.int8 if quant_kv else jnp.float32),
@@ -425,17 +438,18 @@ def _scatter_blocks(pool: Dict[str, Any], fresh: Dict[str, Any],
                     offs: jax.Array) -> Dict[str, Any]:
     """Write a freshly prefilled batch-b cache into pool blocks.
 
-    fresh layers are ``[L, b, T, ...]``; ``phys``/``offs`` are flat
-    ``[b*T]`` (physical block, in-block offset) destinations for each of
-    the b*T prefilled token rows.  Padding rows and rows that land in
-    SHARED prefix blocks are redirected to the trash block by the caller,
-    so shared blocks are never rewritten (sharers keep attending to
+    fresh layers are ``[L, b, T, n_kv, Dh(or 1)]``, written into the
+    pool's lane-dense layout; ``phys``/``offs`` are flat ``[b*T]``
+    (physical block, in-block offset) destinations for each of the b*T
+    prefilled token rows.  Padding rows and rows that land in SHARED
+    prefix blocks are redirected to the trash block by the caller, so
+    shared blocks are never rewritten (sharers keep attending to
     bit-identical KV) and duplicate trash writes only ever carry dead
     values.
     """
     def put(dst, src):
-        flat = src.reshape((src.shape[0], -1) + src.shape[3:])
-        return dst.at[:, phys, offs].set(flat.astype(dst.dtype))
+        rows = src.reshape((src.shape[0], -1) + src.shape[3:])
+        return blk.paged_write(dst, (slice(None),), phys, offs, rows)
 
     layers = jax.tree_util.tree_map(put, pool["layers"], fresh["layers"])
     length = pool["length"].at[slots].set(fresh["length"])
@@ -476,8 +490,10 @@ def prefill_into_blocks(params, tokens, cache, slots, phys, offs,
         jnp.asarray(phys, jnp.int32), jnp.asarray(offs, jnp.int32))
 
 
-@partial(jax.jit, static_argnames=("cfg", "quant_kv", "moe_mode",
-                                   "capture_layer_inputs"))
+_DECODE_STATIC = ("cfg", "quant_kv", "moe_mode", "capture_layer_inputs")
+
+
+@partial(jax.jit, static_argnames=_DECODE_STATIC)
 def decode_step(params, tokens, cache, cfg: ModelConfig,
                 quant_kv: bool = False, moe_mode: str = "dense",
                 active_mask: Optional[jax.Array] = None,
@@ -500,7 +516,12 @@ def decode_step(params, tokens, cache, cfg: ModelConfig,
     wrap (callers enforce prompt+max_new <= max_blocks*block_size), under
     which the ring validity arithmetic reduces exactly to "slot <=
     position", so both layouts share one attention path.  Retired lanes'
-    table rows point at the trash block.
+    table rows point at the trash block.  The whole pool rides the layer
+    scan's carry (the scan runs over each layer's params and index), so
+    each layer writes its token and gathers its blocks in place instead
+    of slicing its layer out and stacking it back; under donation
+    (:func:`donating`) no pool-sized copy remains.  The ring pool keeps
+    its per-layer scan inputs and outputs.
 
     capture_layer_inputs: additionally return each block's input
     activations as a third result ([n_layers, B, 1, D]) — the vectors
@@ -518,34 +539,54 @@ def decode_step(params, tokens, cache, cfg: ModelConfig,
                  if cfg.family != "ssm" else 0)
     if block_tables is not None:
         # paged: logical length = table width * block size (shape[2] is the
-        # block size for a [L, NB, BS, n_kv, head_dim] pool)
+        # block size for a [L, NB, BS, n_kv * head_dim] pool)
         cache_len = block_tables.shape[1] * cache["layers"]["k"].shape[2]
 
-    def body(x, inp):
-        p_l, cache_l = inp
-        y, new_cache_l = blk.block_apply_decode(
-            p_l, x, cfg, cache_l, position, cache_len,
-            moe_mode=moe_mode, quant_kv=quant_kv,
-            block_tables=block_tables)
-        if capture_layer_inputs:
-            return y, (new_cache_l, x)
-        return y, new_cache_l
-
     segments = block_segments(params)
-    new_parts = []
     captured = []
-    offset = 0
-    for seg in segments:
-        n_seg = _segment_len(seg)
-        cache_seg = jax.tree_util.tree_map(
-            lambda a: a[offset:offset + n_seg], cache["layers"])
-        x, new_seg = jax.lax.scan(body, x, (seg, cache_seg))
-        if capture_layer_inputs:
-            new_seg, xs_seg = new_seg
+    if block_tables is not None:
+        def paged_body(carry, inp):
+            x, pool = carry
+            p_l, layer = inp
+            y, pool = blk.block_apply_decode(
+                p_l, x, cfg, pool, position, cache_len,
+                moe_mode=moe_mode, quant_kv=quant_kv,
+                block_tables=block_tables, layer=layer)
+            return (y, pool), (x if capture_layer_inputs else None)
+
+        pool = cache["layers"]
+        offset = 0
+        for seg in segments:
+            n_seg = _segment_len(seg)
+            layers = jnp.arange(offset, offset + n_seg, dtype=jnp.int32)
+            (x, pool), xs_seg = jax.lax.scan(paged_body, (x, pool),
+                                             (seg, layers))
             captured.append(xs_seg)
-        new_parts.append(new_seg)
-        offset += n_seg
-    new_layers = _concat_segments(new_parts)
+            offset += n_seg
+        new_layers = pool
+    else:
+        def body(x, inp):
+            p_l, cache_l = inp
+            y, new_cache_l = blk.block_apply_decode(
+                p_l, x, cfg, cache_l, position, cache_len,
+                moe_mode=moe_mode, quant_kv=quant_kv)
+            if capture_layer_inputs:
+                return y, (new_cache_l, x)
+            return y, new_cache_l
+
+        new_parts = []
+        offset = 0
+        for seg in segments:
+            n_seg = _segment_len(seg)
+            cache_seg = jax.tree_util.tree_map(
+                lambda a: a[offset:offset + n_seg], cache["layers"])
+            x, new_seg = jax.lax.scan(body, x, (seg, cache_seg))
+            if capture_layer_inputs:
+                new_seg, xs_seg = new_seg
+                captured.append(xs_seg)
+            new_parts.append(new_seg)
+            offset += n_seg
+        new_layers = _concat_segments(new_parts)
     x = apply_norm(params["final_norm"], x, cfg)
     logits = lm_logits(params, x, cfg)[:, 0]
     if active_mask is None:
@@ -556,6 +597,19 @@ def decode_step(params, tokens, cache, cfg: ModelConfig,
     if capture_layer_inputs:
         return logits, new_cache, jnp.concatenate(captured, axis=0)
     return logits, new_cache
+
+
+@lru_cache(maxsize=None)
+def donating(step):
+    """``step`` (:func:`decode_step`) compiled with its ``cache`` donated.
+
+    For callers that rebind their pool after every call (the serving
+    engine): XLA then writes the new KV into the old pool's buffers, and
+    the arrays passed in are deleted.  ``decode_step`` itself leaves its
+    input readable, for callers that reuse a cache (``core.sensitivity``).
+    """
+    return jax.jit(step, static_argnames=_DECODE_STATIC,
+                   donate_argnames="cache")
 
 
 # ---------------------------------------------------------------------------

@@ -22,8 +22,9 @@ kernels on its slice unchanged:
   * embeddings, ``lm_head``, and norms are replicated, so logits are
     computed redundantly on every shard and greedy decode is trivially
     shard-count invariant;
-  * the KV pool shards on the kv-head axis (axis 3 of both the ring
-    ``[L, B, S, n_kv, Dh]`` and paged ``[L, NB, BS, n_kv, Dh]`` layouts)
+  * the KV pool shards on the kv-head axis (axis 3 of the ring
+    ``[L, B, S, n_kv, Dh]`` layout, the heads-major last axis of the
+    lane-dense paged ``[L, NB, BS, n_kv*Dh]`` one)
     — block tables, lengths, and all pool *accounting* stay logical and
     replicated, so the engine's scheduler/block manager never see TP.
 
@@ -133,11 +134,15 @@ def serving_param_spec(path: str, shape: Tuple[int, ...]) -> P:
 
 
 def _cache_spec(shape: Tuple[int, ...]) -> P:
-    """KV pool arrays ([L, B|NB, S|BS, n_kv, Dh] and their scale
-    companions) shard on the kv-head axis; ``length`` and any other
-    bookkeeping replicate."""
+    """KV pool arrays shard on the kv-head axis: axis 3 of the ring
+    ``[L, B, S, n_kv, Dh]`` (and its ``[.., n_kv, 1]`` scales), and the
+    last axis of the lane-dense paged ``[L, NB, BS, n_kv*Dh]`` and its
+    ``[L, NB, n_kv*BS]`` scales (heads-major, so an even split is a split
+    by head); ``length`` and any other bookkeeping replicate."""
     if len(shape) == 5:
         return P(None, None, None, "model", None)
+    if len(shape) in (3, 4):
+        return P(*([None] * (len(shape) - 1) + ["model"]))
     return P(*([None] * len(shape)))
 
 

@@ -352,6 +352,10 @@ class Engine:
         self.iterations = 0            # total model iterations (prefill+decode)
         self.prefill_iterations = 0
         self.decode_iterations = 0
+        # decode steps that took the KV pool's buffers over (donation);
+        # equal to the single-device decode iterations unless the
+        # backend declined it
+        self.decode_pool_donated = 0
         self.prefill_tokens = 0
         clen = ecfg.cache_len if cfg.window is None \
             else min(ecfg.cache_len, cfg.window)
@@ -579,13 +583,16 @@ class Engine:
                         block_tables=(jnp.asarray(self._tables_np)
                                       if self.paged else None))
                 else:
-                    out = lm.decode_step(
+                    old_pool = jax.tree_util.tree_leaves(
+                        self.cache["layers"])[0]
+                    out = lm.donating(lm.decode_step)(
                         self.params, jnp.asarray(self._cur[:, None]),
-                        self.cache, self.cfg, quant_kv=self._quant_kv,
+                        self.cache, cfg=self.cfg, quant_kv=self._quant_kv,
                         active_mask=jnp.asarray(mask),
                         capture_layer_inputs=capture,
                         block_tables=(jnp.asarray(self._tables_np)
                                       if self.paged else None))
+                    self.decode_pool_donated += int(old_pool.is_deleted())
                 if capture:
                     logits, self.cache, layer_inputs = out
                     self.tap.observe(layer_inputs, mask)
@@ -992,9 +999,11 @@ class Engine:
                         done_at[r.uid] = step
             if len(done_at) == len(active) or step == steps - 1:
                 break
-            logits, cache = lm.decode_step(
-                self.params, cur[:, None], cache, cfg,
+            old_pool = jax.tree_util.tree_leaves(cache["layers"])[0]
+            logits, cache = lm.donating(lm.decode_step)(
+                self.params, cur[:, None], cache, cfg=cfg,
                 quant_kv=self._quant_kv)
+            self.decode_pool_donated += int(old_pool.is_deleted())
             self.iterations += 1
             self.decode_iterations += 1
             cur = self._sample(logits, [r.uid for r in active],
@@ -1358,6 +1367,7 @@ class Engine:
                 "iterations": self.iterations,
                 "prefill_iterations": self.prefill_iterations,
                 "decode_iterations": self.decode_iterations,
+                "decode_pool_donated": self.decode_pool_donated,
                 "prefill_tokens": self.prefill_tokens,
                 "weight_compression": round(self.compression, 2),
                 "mixed_precision": bool(self.quant_policy is not None

@@ -201,9 +201,30 @@ def tiny():
 
 def make_engine(tiny, batch=4, **kw):
     cfg, params = tiny
-    return Engine(params, cfg, EngineConfig(
-        batch_size=batch, cache_len=64, quantize=True, ql=4,
-        group_size=32, quant_kv=True, mode="continuous", **kw))
+    opts = dict(batch_size=batch, cache_len=64, quantize=True, ql=4,
+                group_size=32, quant_kv=True, mode="continuous")
+    opts.update(kw)
+    return Engine(params, cfg, EngineConfig(**opts))
+
+
+def _mixed_plan():
+    """A solved plan whose per-layer bits split the 2-layer stack into
+    two segments (``params["blocks"]`` served as a list)."""
+    from repro import planning
+    from repro.models.sail_linear import BitAllocation, QuantPolicy
+    alloc = BitAllocation(per_path={"['blocks']['mlp']['w_down']": (4, 8)})
+    return planning.PlanSpec.from_policy(
+        QuantPolicy(bits=4, group_size=32, min_size=1024, allocation=alloc),
+        quant_kv=True)
+
+
+# engine options of each case the paged pool must match the slot pool in
+PAGED_CASES = {
+    "int8_kv": lambda: {},
+    "f32_kv": lambda: {"quant_kv": False},
+    "mixed_plan": lambda: {"plan": _mixed_plan()},
+    "layer_taps": lambda: {"tap_capacity": 256},
+}
 
 
 PREFIX = [5, 9, 2, 4, 11, 3, 8, 1]
@@ -217,13 +238,31 @@ def _serve(eng, prompts, max_new=6):
     return {u: eng.completions[u].tokens for u in uids}
 
 
-def test_paged_tokens_identical_to_slot_pool(tiny):
+@pytest.mark.parametrize("case", list(PAGED_CASES))
+def test_paged_tokens_identical_to_slot_pool(tiny, case):
     """The tentpole guarantee: gather/scatter through block tables is a
     layout change, not a numerics change — greedy outputs match the
-    contiguous slot pool token for token."""
-    ref = _serve(make_engine(tiny), PROMPTS)
-    got = _serve(make_engine(tiny, kv_block_size=8), PROMPTS)
+    contiguous slot pool token for token, with int8 or f32 KV, over a
+    segmented (mixed-precision) layer stack, and with the activation tap
+    seeing the same layer inputs.  Every engine decode step takes its
+    pool's buffers over (donation)."""
+    slot = make_engine(tiny, **PAGED_CASES[case]())
+    paged = make_engine(tiny, kv_block_size=8, **PAGED_CASES[case]())
+    ref = _serve(slot, PROMPTS)
+    got = _serve(paged, PROMPTS)
     assert got == ref
+    if case == "mixed_plan":
+        assert isinstance(paged.params["blocks"], list)
+        assert len(paged.params["blocks"]) == 2
+    if case == "layer_taps":
+        assert paged.tap.n_layers == slot.tap.n_layers == 2
+        for layer in range(2):
+            np.testing.assert_array_equal(paged.tap.rows(layer),
+                                          slot.tap.rows(layer))
+    for eng in (slot, paged):
+        st_ = eng.stats()
+        assert st_["decode_iterations"] > 0
+        assert st_["decode_pool_donated"] == st_["decode_iterations"]
 
 
 def test_prefix_sharing_token_identity_and_hits(tiny):

@@ -1,23 +1,34 @@
-"""Compile-only checks of the LUT-GEMV kernels for a TPU v5e.
+"""Compile-only checks for a TPU v5e: the LUT-GEMV kernels and the
+serving engine's decode step.
 
 The TPU compiler is installed beside the CPU backend, so a described (not
 attached) ``v5e:2x2`` topology compiles the kernels exactly as the chip
 would: unsupported lowerings and scoped-VMEM overruns fail here, at no
 chip time.  Shapes are ``tinymistral_248m``'s quantized matrices at decode
 (M=8) and prefill (M=512) batch, with the blocks ``pick_blocks`` chooses.
+The decode step is compiled at the widths the engine serves it with in the
+``tinymistral.decode.q4a8`` benchmark cell, and its optimized HLO is held
+to updating the paged KV pool in place.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+import repro.configs as C
+from repro import planning
 from repro.core.quant import words_per_group
 from repro.kernels.lut_gemv.kernel import (lut_matmul_int_pallas,
                                            lut_matmul_pallas)
 from repro.kernels.lut_gemv.ops import _round_up, pick_blocks
+from repro.models import lm
+from repro.models.sail_linear import QuantPolicy, quantize_params
+from repro.serving.engine import Engine, EngineConfig
 
 G = 128
 # (K, N) of every quantized matrix of tinymistral_248m: wk/wv, wq/wo,
@@ -90,3 +101,84 @@ def test_lut_gemv_compiles_for_v5e_decode(one_chip, kn, bits, int_act):
 def test_lut_gemv_compiles_for_v5e_prefill(one_chip, kn, int_act):
     compiled = _compile(one_chip, 512, *kn, 4, int_act)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# --- the engine's decode step: the paged KV pool updated in place ----------
+
+# ops that would move a whole pool (or a layer of it) through memory
+POOL_MOVES = ("copy", "copy-start", "copy-done", "slice", "slice-start",
+              "slice-done", "dynamic-slice", "dynamic-update-slice")
+_INSTR = re.compile(r"\s*(?:ROOT )?%?[\w.\-]+ = (.+?) ([a-z][\w\-]*)\(")
+
+
+def pool_moves(hlo: str, num_blocks: int) -> list:
+    """Instructions of an optimized HLO module among ``POOL_MOVES`` whose
+    result shape carries the pool's block axis (``num_blocks``)."""
+    axis = re.compile(rf"[\[,]{num_blocks}[\],]")
+    return [line.strip() for line in hlo.splitlines()
+            if (m := _INSTR.match(line)) and m.group(2) in POOL_MOVES
+            and axis.search(m.group(1))]
+
+
+def pool_bytes(cache) -> int:
+    return sum(a.size * np.dtype(a.dtype).itemsize
+               for a in jax.tree_util.tree_leaves(cache["layers"]))
+
+
+def _lower_decode(fn, params, cache, cfg, batch, table_cols, **place):
+    """Lower ``fn`` (an ``lm`` decode step) as ``Engine.step`` calls it:
+    int8 KV, an active mask and block tables."""
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, **place)
+    return fn.lower(params, sds((batch, 1), jnp.int32), cache, cfg,
+                    quant_kv=True, active_mask=sds((batch,), jnp.bool_),
+                    block_tables=sds((batch, table_cols), jnp.int32))
+
+
+def test_engine_decode_step_updates_pool_in_place_for_v5e(one_chip):
+    """The decode program of the ``tinymistral.decode.q4a8`` cell (batch
+    32, ``cache_len`` 3072, block 16, int8 KV, ``uniform:4a8``; 6145
+    blocks with the trash block), as the engine donates its pool to it:
+    no op copies, slices or rewrites a pool-sized array, and the whole
+    pool aliases input to output."""
+    cfg = C.get_config("tinymistral_248m")
+    batch, cache_len, block = 32, 3072, 16
+    cols = cache_len // block
+    num_blocks = batch * cols + 1
+    policy = planning.PlanSpec.parse("uniform:4a8").to_policy(
+        QuantPolicy(bits=4, group_size=128, min_size=1024))
+    params = jax.eval_shape(
+        lambda key: quantize_params(lm.init_params(key, cfg), policy)[0],
+        jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: lm.init_paged_cache(
+        None, cfg, batch, num_blocks, block, quant_kv=True))
+    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip)
+    params, cache = jax.tree_util.tree_map(place, (params, cache))
+    compiled = _lower_decode(lm.donating(lm.decode_step), params, cache,
+                             cfg, batch, cols, sharding=one_chip).compile()
+    assert pool_moves(compiled.as_text(), num_blocks) == []
+    assert (compiled.memory_analysis().alias_size_in_bytes
+            >= pool_bytes(cache) > 0.6e9)
+
+
+@pytest.mark.parametrize("donate", [True, False], ids=["donating", "plain"])
+def test_engine_decode_step_updates_pool_in_place_on_cpu(donate):
+    """CPU-size twin: the engine's own pool (40 blocks + trash) through
+    the donating step aliases with no pool-sized move; the non-donating
+    ``lm.decode_step`` keeps its input readable and so copies the pool
+    once, at entry."""
+    cfg = C.get_smoke("tinymistral_248m")
+    params = lm.init_params(jax.random.PRNGKey(0), cfg)
+    eng = Engine(params, cfg, EngineConfig(
+        batch_size=4, cache_len=64, quantize=True, group_size=32,
+        plan="uniform:4a8", mode="continuous", kv_block_size=8,
+        kv_pool_blocks=40))
+    fn = lm.donating(lm.decode_step) if donate else lm.decode_step
+    compiled = _lower_decode(fn, eng.params, eng.cache, cfg, 4,
+                             eng._mbs).compile()
+    moves = pool_moves(compiled.as_text(), 41)
+    alias = compiled.memory_analysis().alias_size_in_bytes
+    if donate:
+        assert moves == [] and alias >= pool_bytes(eng.cache)
+    else:
+        assert moves and alias == 0
