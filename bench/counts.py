@@ -8,11 +8,12 @@ moves the time these counts are divided by, never the counts.
 
 ``m`` is a configuration's ``model`` dict; ``prec`` is a cell's
 ``precision`` dict (``weight_bits`` or null, ``group_size``,
-``kv_bits``).
+``kv_bits``).  These are the counts of a dense decoder; a configuration
+of another kind names its own module (``bench/run.py``).
 """
 from __future__ import annotations
 
-from typing import Dict, Iterable, Tuple
+from typing import Dict, Iterable, Sequence, Tuple
 
 
 def head_dim(m: Dict) -> int:
@@ -90,3 +91,20 @@ def prefill_flops(m: Dict, prompt_len: int) -> int:
     p = prompt_len
     return (2 * block_weights(m) * p + 2 * head_weights(m)
             + attention_flops(m, p * (p + 1) // 2))
+
+
+def decode_scopes(m: Dict, prec: Dict, steps: Sequence[Sequence[int]],
+                  stats: Dict) -> Dict[str, Tuple[int, int]]:
+    """(FLOPs, bytes) of the window's decode steps, each step given by
+    the contexts of its lanes, split by the scopes the program opens:
+    ``sail_mm``, every matmul a step runs, the head included (its weights
+    read once a step); ``attn``, attention over the positions each lane
+    attends to, whose KV it reads, and the KV it writes.  A dense model
+    needs none of the window's ``stats``."""
+    steps = [c for c in steps if c]
+    lanes = sum(len(c) for c in steps)
+    pairs = sum(sum(c) for c in steps)
+    kvp = kv_bytes_per_position(m, prec)
+    return {"sail_mm": (2 * matmul_weights(m) * lanes,
+                        len(steps) * weight_bytes(m, prec)),
+            "attn": (attention_flops(m, pairs), (pairs + lanes) * kvp)}

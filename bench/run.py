@@ -25,6 +25,49 @@ The last line of standard output is one JSON object.  With ``--trace 0``
 its metrics are the cell's end-to-end metrics; with ``--trace 1`` a
 profiler trace of the window feeds the per-layer metrics, one reader per
 metric in ``bench/metrics/<name>.py``.
+
+A configuration file holds the model (``model``: every ``ModelConfig``
+field) and names what the harness finds beside it, so that a new model
+brings new files and no edit here:
+
+- ``reference``: its plain reference, ``bench/reference/<name>.py``
+  (``make_params(m, seed)``, ``logits(params, seq, prompt_len, m,
+  prec)``, ``gaps(logits, tokens)``);
+- ``counts`` (optional): its work counts, ``bench/work/<name>.py``, with
+  ``decode_step(m, prec, contexts) -> (flops, bytes)`` of one step,
+  ``prefill_flops(m, prompt_len)`` and, optionally,
+  ``decode_scopes(m, prec, steps, stats) -> {scope: (flops, bytes)}``
+  over the window's decode steps; without the key, ``bench/counts.py``;
+- ``scopes`` (optional): the ``named_scope``s its program opens inside
+  the decode step; a traced run splits that step's op time over them
+  and ``other``.
+
+A reader's ``read(ctx)`` returns a number, or None where it finds
+nothing to read.  ``ctx`` is built for traced runs only, around the
+window, so an untraced run times what it always did:
+
+- ``window_s``: the window's host seconds; ``compiles``: XLA compiles
+  in it; ``memory_peak_bytes``; ``peaks``: the device's row of
+  ``bench/peaks.json``;
+- ``counters``: the window's change in the engine's
+  ``decode_iterations``, ``decode_seconds``, ``decode_tokens``,
+  ``prefill_tokens`` and ``prefill_iterations``; ``prefill_host_s``:
+  host seconds of the steps that prefilled, less their decode seconds;
+- ``trace``: ``trace_reduce.reduce`` (``busy_s``, ``window_s``,
+  ``modules``, ``device_ops``, ``idle_gaps``) and ``trace_spans.reduce``
+  (``idle_by_span``, ``scope_s`` over the configuration's scopes);
+- ``spans``: per span ``{count, s, self_s, jit_s, jit_events}`` and
+  ``queue``: ``{wait_s, admissions}`` (``trace_spans.delta``), None for
+  an engine that keeps no span table;
+- ``stats``: the window's change in every numeric leaf of
+  ``Engine.stats()``, keyed by its dotted path;
+- ``model``, ``precision``: the configuration's model and the cell's
+  precision; ``contexts``: for each decode step of the window, the
+  positions each lane attended to;
+- ``decode_flops``, ``decode_bytes``: the counts over those steps;
+  ``window_flops`` adds every prompt whose first token came in the
+  window; ``scope_work``: ``decode_scopes`` over them and ``stats``, or
+  None where the counts have none.
 """
 from __future__ import annotations
 
@@ -50,7 +93,8 @@ for p in (str(ROOT / "src"), str(ROOT)):
 
 import numpy as np  # noqa: E402
 
-from bench import counts, stats, traffic as traffic_lib  # noqa: E402
+from bench import (counts, stats, trace_reduce, trace_spans,  # noqa: E402
+                   traffic as traffic_lib)
 from bench.serve import Loop, compile_log, warm_shapes, warm_traffic  # noqa: E402
 
 # Engine options a traffic file may set; every other option keeps the
@@ -131,21 +175,31 @@ def _trace_start(tag: str) -> Path:
     return out
 
 
-def _trace_read(out: Path) -> Dict:
+def _trace_read(out: Path, scopes) -> Dict:
     import jax
-    from bench import trace_reduce
     jax.profiler.stop_trace()
     files = glob.glob(str(out / "**" / "*.xplane.pb"), recursive=True)
     try:
         if not files:
             raise RuntimeError("the profiler wrote no .xplane.pb")
-        tr = trace_reduce.load(files[0])
+        tr = trace_spans.load(files[0])
         win = trace_reduce.window(tr["host"], "bench.generate")
         if win is None:
             raise RuntimeError("no bench.generate span in the trace")
-        return trace_reduce.reduce(tr, *win)
+        return dict(trace_reduce.reduce(tr, *win),
+                    **trace_spans.reduce(tr, *win, scopes))
     finally:
         shutil.rmtree(out, ignore_errors=True)
+
+
+def counts_for(cell: Dict):
+    """The configuration's work counts: ``bench/work/<counts>.py`` where
+    its file names one, else ``bench/counts.py``."""
+    name = cell["config"].get("counts")
+    if not name:
+        return counts
+    return _load_module(cell["root"] / "bench" / "work" / f"{name}.py",
+                        f"counts_{name}")
 
 
 def _sample(reqs: List[stats.Timed], t_open: float, t_close: float,
@@ -206,7 +260,12 @@ def _per_layer(cell: Dict, ctx: Dict) -> Dict:
 
 def use_compile_cache() -> None:
     """JAX's persistent cache in the checkout (the program's own helper),
-    for every program, so that eager prefill pieces persist too."""
+    for every program, so that eager prefill pieces persist too.  The
+    key leaves op metadata out, as JAX's default: with metadata in it,
+    prefill programs missed the cache inside warm runs' windows and
+    compiled there for seconds each (PERF.md §7).  So a trace shows the
+    op names of the code that filled the cache, which in a checkout of
+    its own is the code that runs."""
     import jax
     from repro.compile_cache import enable_compile_cache
     enable_compile_cache()
@@ -241,8 +300,10 @@ def serve_window(cell: Dict, seed: int, seconds: float, trace: bool,
              "prefill_iterations")
     read = lambda: dict({k: getattr(eng, k) for k in names},
                         decode_tokens=eng._decode_tokens)
-    trace_out = (_trace_start(f"{cell['workload']['name']}.{seed}")
-                 if trace else None)
+    if trace:
+        trace_out = _trace_start(f"{cell['workload']['name']}.{seed}")
+        spans0 = trace_spans.snapshot(eng)
+        stats0 = trace_spans.numeric_leaves(eng.stats())
     before = read()
     t_open = time.perf_counter()
     n_steps = len(loop.steps)
@@ -250,15 +311,22 @@ def serve_window(cell: Dict, seed: int, seconds: float, trace: bool,
         loop.run(t_open + seconds)
     t_close = time.perf_counter()
     counters = {k: v - before[k] for k, v in read().items()}
-    reduced = _trace_read(trace_out) if trace else None
+    out = {"trace": None, "spans": None, "stats": None}
+    if trace:
+        out.update(
+            spans=trace_spans.delta(spans0, trace_spans.snapshot(eng)),
+            stats=trace_spans.leaves_delta(
+                stats0, trace_spans.numeric_leaves(eng.stats())),
+            trace=_trace_read(trace_out, cell["config"].get("scopes", ())))
     mem = jax.devices()[0].memory_stats() or {}
     device["memory_peak_bytes"] = int(mem.get("peak_bytes_in_use", 0))
-    out = {"device": device, "peaks": peaks, "reqs": loop.reqs,
-           "steps": loop.steps[n_steps:], "t_open": t_open,
-           "t_close": t_close, "setup_s": t_open - t_process,
-           "counters": counters, "trace": reduced,
-           "compiles": compiles.count(t_open, t_close),
-           "backlog": sum(1 for r in loop.inflight.values() if not r.tokens)}
+    out.update({"device": device, "peaks": peaks, "reqs": loop.reqs,
+                "steps": loop.steps[n_steps:], "t_open": t_open,
+                "t_close": t_close, "setup_s": t_open - t_process,
+                "counters": counters,
+                "compiles": compiles.count(t_open, t_close),
+                "backlog": sum(1 for r in loop.inflight.values()
+                               if not r.tokens)})
     # free the engine before the reference runs
     del eng, loop
     gc.collect()
@@ -277,11 +345,13 @@ def run_cell(cell: Dict, seed: int, seconds: float, trace: bool,
                     "failed": sum(r.failed for r in due), "metrics": {},
                     "device": w["device"]}
     if trace:
-        steps, reduced = w["steps"], w["trace"]
-        dec = [counts.decode_step(m, tr["precision"], s["contexts"])
-               for s in steps if s["contexts"]]
+        steps, reduced, prec = w["steps"], w["trace"], tr["precision"]
+        work = counts_for(cell)
+        contexts = [s["contexts"] for s in steps if s["contexts"]]
+        dec = [work.decode_step(m, prec, c) for c in contexts]
         first_in = [r for r in reqs
                     if r.times and t_open <= r.times[0] <= t_close]
+        spans = w["spans"] or {}
         ctx = {
             "window_s": t_close - t_open, "counters": w["counters"],
             "prefill_host_s": sum(s["t1"] - s["t0"] - s["decode_s"]
@@ -292,9 +362,20 @@ def run_cell(cell: Dict, seed: int, seconds: float, trace: bool,
             "decode_flops": sum(f for f, _ in dec),
             "decode_bytes": sum(b for _, b in dec),
             "window_flops": (sum(f for f, _ in dec)
-                             + sum(counts.prefill_flops(m, r.prompt_len)
+                             + sum(work.prefill_flops(m, r.prompt_len)
                                    for r in first_in)),
+            "spans": spans.get("spans"),
+            "queue": ({"wait_s": spans["queue_wait_s"],
+                       "admissions": spans["queue_admissions"]}
+                      if spans else None),
+            "stats": w["stats"], "model": m, "precision": prec,
+            "contexts": contexts,
+            "scope_work": (work.decode_scopes(m, prec, contexts, w["stats"])
+                           if hasattr(work, "decode_scopes") else None),
         }
+        print("trace scope_s " + json.dumps(reduced["scope_s"]) + " modules "
+              + json.dumps({k: v for k, v in reduced["modules"].items()
+                            if trace_spans.MODULE in k}), file=sys.stderr)
         result["metrics"] = _per_layer(cell, ctx)
         result["device"]["busy_s"] = reduced["busy_s"]
         result["device"]["window_s"] = reduced["window_s"]

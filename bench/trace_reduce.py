@@ -8,7 +8,9 @@ checked on a small trace written by hand.
   ``/device:`` plane (the ``XLA Modules`` line where a plane has
   neither): an asynchronous copy keeps the chip's memory system busy
   while no op of the other line runs.  Busy time is the union of their
-  intervals inside the window, averaged over planes.
+  intervals inside the window, averaged over the planes that hold an op
+  or a module event: a TPU trace also carries planes that run nothing,
+  such as ``/device:CUSTOM:Megascale Trace``, and ``load`` drops them.
 - Module time is the summed duration of ``XLA Modules`` events by name,
   with the trailing ``(<id>)`` of a program's name removed.
 - An idle gap is a stretch of the window with no device event.  Each is
@@ -24,25 +26,42 @@ Event = Tuple[str, float, float]          # name, start_ns, duration_ns
 
 OPS_LINES = ("XLA Ops", "Async XLA Ops")
 MODULES_LINE = "XLA Modules"
+SYNC_LINE = "XLA Ops"             # ops that run one at a time (they nest)
 
 
-def load(path: str) -> Dict:
-    """Device planes and host events of one ``.xplane.pb``."""
-    from jax.profiler import ProfileData
-    pd = ProfileData.from_file(path)
+def runs_ops(lines: Dict[str, List[Event]]) -> bool:
+    """Whether a device plane holds an op or a module event."""
+    return bool(lines["ops"] or lines["modules"])
+
+
+def read_planes(planes) -> Tuple[Dict[str, Dict[str, List[Event]]],
+                                 List[Event]]:
+    """Device planes that run ops, with their ``ops``, ``modules`` and
+    ``sync_ops`` (the ``XLA Ops`` line alone), and every host event, of
+    ``ProfileData`` planes."""
     device: Dict[str, Dict[str, List[Event]]] = {}
     host: List[Event] = []
-    for plane in pd.planes:
+    for plane in planes:
         if plane.name.startswith("/device:") and "CPU" not in plane.name:
             lines = {ln.name: [(e.name, e.start_ns, e.duration_ns)
                                for e in ln.events] for ln in plane.lines}
-            device[plane.name] = {
-                "ops": [e for n in OPS_LINES for e in lines.get(n, [])],
-                "modules": lines.get(MODULES_LINE, [])}
+            got = {"ops": [e for n in OPS_LINES for e in lines.get(n, [])],
+                   "modules": lines.get(MODULES_LINE, []),
+                   "sync_ops": lines.get(SYNC_LINE, [])}
+            if runs_ops(got):
+                device[plane.name] = got
         elif plane.name.startswith("/host:"):
             for ln in plane.lines:
                 host.extend((e.name, e.start_ns, e.duration_ns)
                             for e in ln.events)
+    return device, host
+
+
+def load(path: str) -> Dict:
+    """Device planes that run ops, and host events, of one
+    ``.xplane.pb``."""
+    from jax.profiler import ProfileData
+    device, host = read_planes(ProfileData.from_file(path).planes)
     return {"device": device, "host": host}
 
 
@@ -85,9 +104,9 @@ def _innermost(host: List[Event], t: float, bench: bool) -> Optional[str]:
 
 def reduce(trace: Dict, t0: float, t1: float, top: int = 10) -> Dict:
     """Numbers of the window ``[t0, t1]`` (nanoseconds, trace clock)."""
-    planes = trace["device"]
+    planes = {k: v for k, v in trace["device"].items() if runs_ops(v)}
     if not planes:
-        raise ValueError("trace holds no device plane")
+        raise ValueError("trace holds no device plane that runs ops")
     busy_ns, module_ns, op_ns = [], {}, {}
     union_all: List[Tuple[float, float]] = []
     for lines in planes.values():

@@ -6,31 +6,31 @@ spans and sums device time by module.  This module reads what the
 program itself records:
 
 - ``load`` reads an ``.xplane.pb`` into what ``trace_reduce.load``
-  returns, plus each device plane's ``XLA Ops`` line on its own
-  (``sync_ops``) and ``op_scopes``: the ``named_scope`` path of each op
-  (its ``tf_op`` stat), keyed by program id and op name.
+  returns, plus ``op_scopes``: the ``named_scope`` path of each op (its
+  ``tf_op`` stat), keyed by program id and op name.
 - ``idle_by_span`` puts every idle second of the window down to the
   innermost ``engine.*`` host span open at that moment (``none`` where
   no such span is open), in one sweep over sorted gaps and spans.
 - ``scope_s`` sums the device time of ops inside ``decode_step``
-  modules by scope: ``sail_mm`` (the LUT matmuls), ``attn`` (the KV
-  write, gather, dequant and attention) or ``other``; an op that holds
-  others (a ``while``) counts only its own time.
+  modules by scope, over the scopes a configuration lists (its file's
+  ``scopes``: for ``tinymistral_248m`` ``sail_mm``, the LUT matmuls, and
+  ``attn``, the KV write, gather, dequant and attention), and ``other``
+  for an op in none of them; an op that holds others (a ``while``)
+  counts only its own time.
 - ``snapshot``/``delta`` read ``Engine.spans`` and the queue counters
-  before and after a window.
+  before and after a window; ``numeric_leaves``/``leaves_delta`` do the
+  same for every number ``Engine.stats()`` reports.
 """
 from __future__ import annotations
 
+import numbers
 import re
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
-from bench.trace_reduce import (MODULES_LINE, OPS_LINES, Event, clip,
-                                merge)
+from bench.trace_reduce import Event, clip, merge, read_planes
 
 PREFIX = "engine."
-SCOPES = ("sail_mm", "attn")
 MODULE = "decode_step"
-SYNC_LINE = "XLA Ops"             # ops that run one at a time (they nest)
 SCOPE_STAT = "tf_op"              # an op's name-stack path
 
 
@@ -70,8 +70,8 @@ def _text(buf: bytes, span: Tuple[int, int]) -> str:
 
 def op_scopes(buf: bytes) -> Dict[str, List[list]]:
     """``[program_id, op name, tf_op]`` for each op of each device plane
-    of a serialized XSpace.  ``ProfileData`` shows an event's own stats
-    only; ``tf_op`` sits in the op's event metadata (XPlane field 4:
+    of a serialized XSpace that has such ops.  ``ProfileData`` shows an
+    event's own stats only; ``tf_op`` sits in the op's event metadata (XPlane field 4:
     XEventMetadata name 2, stats 5; XStat metadata_id 1, uint64 3,
     int64 4, str 5, ref 7; XStatMetadata name 2 in field 5)."""
     out: Dict[str, List[list]] = {}
@@ -94,7 +94,7 @@ def op_scopes(buf: bytes) -> Dict[str, List[list]]:
                         stat_names[got[1]] = _text(buf, got[2])
         if not name.startswith("/device:") or "CPU" in name:
             continue
-        rows = out.setdefault(name, [])
+        rows = []
         for m in metas:
             op, stats = "", {}
             for k, v in _fields(buf, *m):
@@ -111,30 +111,17 @@ def op_scopes(buf: bytes) -> Dict[str, List[list]]:
                         stats[key] = st.get(3, st.get(4))
             if SCOPE_STAT in stats and "program_id" in stats:
                 rows.append([stats["program_id"], op, stats[SCOPE_STAT]])
+        if rows:
+            out[name] = rows
     return out
 
 
 def load(path: str) -> Dict:
-    """``trace_reduce.load`` plus each device plane's ``sync_ops`` (the
-    ``XLA Ops`` line alone) and ``op_scopes``."""
+    """``trace_reduce.load`` plus each device plane's ``op_scopes``."""
     from jax.profiler import ProfileData
     with open(path, "rb") as f:
         buf = f.read()
-    pd = ProfileData.from_serialized_xspace(buf)
-    device: Dict[str, Dict[str, List[Event]]] = {}
-    host: List[Event] = []
-    for plane in pd.planes:
-        if plane.name.startswith("/device:") and "CPU" not in plane.name:
-            lines = {ln.name: [(e.name, e.start_ns, e.duration_ns)
-                               for e in ln.events] for ln in plane.lines}
-            device[plane.name] = {
-                "ops": [e for n in OPS_LINES for e in lines.get(n, [])],
-                "modules": lines.get(MODULES_LINE, []),
-                "sync_ops": lines.get(SYNC_LINE, [])}
-        elif plane.name.startswith("/host:"):
-            for ln in plane.lines:
-                host.extend((e.name, e.start_ns, e.duration_ns)
-                            for e in ln.events)
+    device, host = read_planes(ProfileData.from_serialized_xspace(buf).planes)
     return {"device": device, "host": host, "op_scopes": op_scopes(buf)}
 
 
@@ -200,9 +187,11 @@ def idle_by_span(trace: Dict, t0: float, t1: float) -> Dict[str, float]:
     return {k: v / 1e9 for k, v in out.items()}
 
 
-def scope_of(path: str) -> str:
+def scope_of(path: str, scopes: Sequence[str]) -> str:
+    """The first of ``scopes`` that names a part of the op's scope path,
+    or ``other``."""
     parts = path.split("/")
-    return next((s for s in SCOPES if s in parts), "other")
+    return next((s for s in scopes if s in parts), "other")
 
 
 def self_times(events: List[Event]) -> List[float]:
@@ -227,10 +216,12 @@ def _program_id(module: str) -> Optional[int]:
     return int(m.group(1)) % 2 ** 64 if m else None
 
 
-def scope_s(trace: Dict, t0: float, t1: float) -> Dict[str, float]:
+def scope_s(trace: Dict, t0: float, t1: float,
+            scopes: Sequence[str]) -> Dict[str, float]:
     """Device seconds of the ``XLA Ops`` line inside ``decode_step``
-    modules that overlap the window, by scope, each op counted for its
-    own time (less the ops nested in it)."""
+    modules that overlap the window, by the first of ``scopes`` in each
+    op's path (``other`` where none is), each op counted for its own
+    time (less the ops nested in it)."""
     out: Dict[str, float] = {}
     for plane, lines in trace["device"].items():
         paths = {(pid % 2 ** 64, op): path for pid, op, path
@@ -245,16 +236,17 @@ def scope_s(trace: Dict, t0: float, t1: float) -> Dict[str, float]:
             while j < len(mods) and mods[j][1] <= s:
                 j += 1
             if j < len(mods) and mods[j][0] <= s:
-                key = scope_of(paths.get((mods[j][2], name), ""))
+                key = scope_of(paths.get((mods[j][2], name), ""), scopes)
                 out[key] = out.get(key, 0.0) + own / 1e9
     return out
 
 
-def reduce(trace: Dict, t0: float, t1: float) -> Dict:
+def reduce(trace: Dict, t0: float, t1: float,
+           scopes: Sequence[str]) -> Dict:
     """The two numbers of the window this module adds to
     ``trace_reduce.reduce``'s."""
     return {"idle_by_span": idle_by_span(trace, t0, t1),
-            "scope_s": scope_s(trace, t0, t1)}
+            "scope_s": scope_s(trace, t0, t1, scopes)}
 
 
 def snapshot(eng) -> Optional[Dict]:
@@ -292,3 +284,24 @@ def delta(before: Optional[Dict], after: Optional[Dict]) -> Optional[Dict]:
             "queue_wait_s": after["queue_wait_s"] - before["queue_wait_s"],
             "queue_admissions": (after["queue_admissions"]
                                  - before["queue_admissions"])}
+
+
+def numeric_leaves(tree: Dict, prefix: str = "") -> Dict[str, float]:
+    """Every real-number leaf of nested dicts (NumPy's too), keyed by its
+    path joined with dots (``block_pool.free_blocks``); bools and None
+    are left out."""
+    out: Dict[str, float] = {}
+    for k, v in tree.items():
+        key = f"{prefix}{k}"
+        if isinstance(v, dict):
+            out.update(numeric_leaves(v, key + "."))
+        elif isinstance(v, numbers.Real) and not isinstance(v, bool):
+            out[key] = v
+    return out
+
+
+def leaves_delta(before: Dict[str, float],
+                 after: Dict[str, float]) -> Dict[str, float]:
+    """What a window added to each numeric leaf; a leaf that appeared in
+    it counts from 0."""
+    return {k: v - before.get(k, 0) for k, v in after.items()}

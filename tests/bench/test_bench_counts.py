@@ -8,7 +8,7 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from bench import counts  # noqa: E402
+from bench import counts, run  # noqa: E402
 
 TINY = json.loads((ROOT / "bench/configs/tinymistral_248m.json").read_text())[
     "model"]
@@ -48,3 +48,28 @@ def test_prefill_counts_causal_pairs_and_one_head_row():
     f = counts.prefill_flops(TINY, 10)
     blocks = counts.matmul_weights(TINY) - 1024 * 32005
     assert f == 2 * blocks * 10 + 2 * 1024 * 32005 + 12 * 4 * 32 * 32 * 55
+
+
+def test_a_config_without_counts_gets_the_dense_counts_unchanged():
+    # tinymistral_248m's file names no counts module: the window's
+    # decode_flops, decode_bytes and window_flops are bench/counts.py's,
+    # pinned here as they read before configurations could bring counts
+    cell = run.load_cell("tinymistral.decode.q4a8")
+    assert "counts" not in cell["config"]
+    work = run.counts_for(cell)
+    assert work is counts
+    m, prec = cell["config"]["model"], cell["traffic"]["precision"]
+    steps = [[300, 1000, 64], [301, 1001]]
+    dec = [work.decode_step(m, prec, c) for c in steps]
+    assert sum(f for f, _ in dec) == 2_283_292_672
+    assert sum(b for _, b in dec) == 247_170_240
+    assert (sum(f for f, _ in dec) + work.prefill_flops(m, 256)
+            + work.prefill_flops(m, 1000)) == 486_951_852_032
+    # the split by scope covers the step's work: all of its FLOPs, and
+    # its bytes but for the embedding rows
+    split = work.decode_scopes(m, prec, steps + [[]], {})
+    assert split == {"sail_mm": (2_152_253_440, 2 * counts.weight_bytes(m, prec)),
+                     "attn": (131_039_232, (2666 + 5) * 6912)}
+    assert sum(f for f, _ in split.values()) == sum(f for f, _ in dec)
+    assert (sum(b for _, b in split.values()) + 5 * 4 * 1024
+            == sum(b for _, b in dec))

@@ -3,6 +3,7 @@ labelled idle gaps, on a small trace written by hand."""
 import json
 import sys
 from pathlib import Path
+from types import SimpleNamespace
 
 import pytest
 
@@ -12,12 +13,16 @@ sys.path.insert(0, str(HERE.parents[1]))
 from bench import trace_reduce  # noqa: E402
 
 
-@pytest.fixture
-def small():
-    raw = json.loads((HERE / "data" / "trace_small.json").read_text())
+def _read(name):
+    raw = json.loads((HERE / "data" / f"{name}.json").read_text())
     return {"device": {p: {k: [tuple(e) for e in v] for k, v in lines.items()}
                        for p, lines in raw["device"].items()},
             "host": [tuple(e) for e in raw["host"]]}
+
+
+@pytest.fixture
+def small():
+    return _read("trace_small")
 
 
 def test_window_is_the_generate_span(small):
@@ -47,6 +52,41 @@ def test_idle_gaps_are_longest_first_and_labelled_by_host_span(small):
     assert l2 == "bench.step > PjitFunction(decode_step)"
     assert s2 == pytest.approx(150 / 1e9)
     assert (l3, s3) == ("bench.step", pytest.approx(70 / 1e9))
+
+
+def test_a_plane_that_runs_nothing_counts_no_busy_time(small):
+    # a v5e trace holds /device:TPU:0 and an empty
+    # /device:CUSTOM:Megascale Trace; busy time averaged over both read
+    # half the chip's
+    two = _read("trace_two_planes")
+    assert set(two["device"]) == {"/device:TPU:0",
+                                  "/device:CUSTOM:Megascale Trace"}
+    r = trace_reduce.reduce(two, 0, 1000)
+    assert r["busy_s"] == pytest.approx((30 + 150 + 50 + 100) / 1e9)
+    # module time and the idle gaps are those of the one plane alone
+    assert r == trace_reduce.reduce(small, 0, 1000)
+    assert [g[0] for g in r["idle_gaps"]] == [
+        "bench.wait_arrival", "bench.step > PjitFunction(decode_step)",
+        "bench.step"]
+
+
+def test_load_keeps_only_device_planes_that_run_ops():
+    ev = lambda n, s, d: SimpleNamespace(name=n, start_ns=s, duration_ns=d)
+    line = lambda n, *es: SimpleNamespace(name=n, events=list(es))
+    plane = lambda n, *ls: SimpleNamespace(name=n, lines=list(ls))
+    device, host = trace_reduce.read_planes([
+        plane("/device:TPU:0", line("XLA Ops", ev("fusion", 5, 2)),
+              line("XLA Modules", ev("jit_f(1)", 4, 4))),
+        plane("/device:TPU:1", line("XLA Modules", ev("jit_f(1)", 4, 4))),
+        plane("/device:CUSTOM:Megascale Trace"),
+        plane("/device:TPU:2", line("XLA Ops")),
+        plane("/host:CPU", line("python", ev("bench.generate", 0, 9))),
+        plane("/device:CPU:0", line("XLA Ops", ev("fusion", 5, 2)))])
+    assert set(device) == {"/device:TPU:0", "/device:TPU:1"}
+    assert device["/device:TPU:0"] == {"ops": [("fusion", 5, 2)],
+                                       "modules": [("jit_f(1)", 4, 4)],
+                                       "sync_ops": [("fusion", 5, 2)]}
+    assert host == [("bench.generate", 0, 9)]
 
 
 def test_a_trace_without_a_device_is_refused():

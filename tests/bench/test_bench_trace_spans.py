@@ -1,5 +1,5 @@
 """The program's spans and scopes in a trace (``bench/trace_spans.py``)
-and the six readers of them, on small traces written by hand."""
+and the eight readers of them, on small traces written by hand."""
 import json
 import sys
 from pathlib import Path
@@ -44,20 +44,37 @@ def test_idle_is_put_down_to_the_innermost_engine_span(spans):
     assert sum(got.values()) == pytest.approx(r["window_s"] - r["busy_s"])
 
 
+SCOPES = json.loads((ROOT / "bench/configs/tinymistral_248m.json")
+                    .read_text())["scopes"]
+
+
 def test_decode_step_op_time_is_split_by_scope(spans):
-    got = trace_spans.scope_s(spans, 0, 1000)
+    got = trace_spans.scope_s(spans, 0, 1000, SCOPES)
     # the jit_scan op of a sail_mm scope lies outside decode_step; the
     # while op counts its own 10 ns beside copy.3's 30
     assert got == pytest.approx({"sail_mm": 200e-9, "attn": 90e-9,
                                  "other": 40e-9})
-    assert trace_spans.scope_of("a/attn_norm/mul:") == "other"
+    assert trace_spans.scope_of("a/attn_norm/mul:", SCOPES) == "other"
     ops = spans["device"]["/device:TPU:0"]["sync_ops"]
     assert trace_spans.self_times(ops)[:4] == [10, 100, 60, 30]
 
 
+@pytest.mark.parametrize("scopes, want", [
+    (SCOPES, {"sail_mm": 200, "attn": 90, "other": 40}),
+    (["attn"], {"attn": 90, "other": 240}),
+    (["attn", "body"], {"attn": 90, "body": 230, "other": 10}),
+    ([], {"other": 330}),
+], ids=["config", "attn", "first_listed", "none"])
+def test_scope_list_is_the_configurations(spans, scopes, want):
+    # an op counts for the first listed scope on its path, else other
+    got = trace_spans.scope_s(spans, 0, 1000, scopes)
+    assert got == pytest.approx({k: v * 1e-9 for k, v in want.items()})
+    assert sum(got.values()) == pytest.approx(330e-9)
+
+
 def test_a_trace_without_program_spans_reads_only_none(small):
     r = trace_reduce.reduce(small, 0, 1000)
-    got = trace_spans.reduce(small, 0, 1000)
+    got = trace_spans.reduce(small, 0, 1000, SCOPES)
     assert got["idle_by_span"] == pytest.approx(
         {"none": r["window_s"] - r["busy_s"]})
     assert got["scope_s"] == {}
@@ -145,6 +162,24 @@ def test_span_deltas_over_a_window():
     assert trace_spans.delta(None, None) is None
 
 
+def test_stats_numbers_by_dotted_path_and_their_window_change():
+    import numpy as np
+    before = {"decode_iterations": 10, "kv_bits": 8, "drift": None,
+              "mixed_precision": False, "plan_mode": "uniform",
+              "block_pool": {"free_blocks": np.int64(40), "util": 0.5}}
+    after = dict(before, decode_iterations=25,
+                 block_pool={"free_blocks": np.int64(31), "util": 0.75},
+                 moe={"experts_touched": 90})
+    leaves = trace_spans.numeric_leaves(after)
+    assert leaves == {"decode_iterations": 25, "kv_bits": 8,
+                      "block_pool.free_blocks": 31, "block_pool.util": 0.75,
+                      "moe.experts_touched": 90}
+    assert trace_spans.leaves_delta(trace_spans.numeric_leaves(before),
+                                    leaves) == {
+        "decode_iterations": 15, "kv_bits": 0, "block_pool.free_blocks": -9,
+        "block_pool.util": 0.25, "moe.experts_touched": 90}
+
+
 def _ctx(**kw):
     ctx = {"window_s": 30.0, "counters": {"prefill_tokens": 4000},
            "trace": {"window_s": 30.0,
@@ -160,7 +195,10 @@ def _ctx(**kw):
                                        "jit_events": 0},
                      "none": {"count": 0, "s": 0.0, "self_s": 0.0,
                               "jit_s": 0.3, "jit_events": 1}},
-           "queue": {"wait_s": 0.06, "admissions": 20}}
+           "queue": {"wait_s": 0.06, "admissions": 20},
+           "peaks": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+           "scope_work": {"sail_mm": (1e9, 819e9 * 0.09),
+                          "attn": (197e12 * 0.5, 1e6)}}
     ctx.update(kw)
     return ctx
 
@@ -176,6 +214,10 @@ READINGS = {
     "idle_device_wait_share": (40.0, dict(trace=None)),
     "decode_matmul_share": (75.0, dict(trace={"window_s": 30.0,
                                               "scope_s": {"other": 5.0}})),
+    # bound by bytes: 0.09 s of HBM time over 9 s in sail_mm
+    "lut_mm_roofline": (1.0, dict(scope_work=None)),
+    # bound by FLOPs: 0.5 s at the bf16 peak over 2 s in attn
+    "decode_attn_roofline": (25.0, dict(scope_work={"attn": (0, 0)})),
 }
 
 
@@ -185,7 +227,8 @@ def test_reader_gives_its_value_and_none_without_its_input(name):
     want, missing = READINGS[name]
     assert mod.read(_ctx()) == pytest.approx(want)
     assert mod.read(_ctx(**missing)) is None
-    # what the harness gives today: no span table, no program scopes
+    # a trace without program spans or scopes, an engine without a
+    # span table
     bare = _ctx(trace={"window_s": 30.0, "busy_s": 12.0})
     for k in ("spans", "queue"):
         del bare[k]
