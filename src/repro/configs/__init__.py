@@ -21,6 +21,7 @@ ARCHS: List[str] = [
     "granite_moe_1b_a400m",
     "mixtral_8x7b",
     "xlstm_350m",
+    "mellum2_12b_a2_5b",
     # the paper's own evaluation models
     "llama2_7b",
     "llama2_13b",

@@ -23,6 +23,7 @@ Dequantization supports two modes, mirroring the two LUT flavours:
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Optional
 
 import jax
@@ -236,33 +237,77 @@ def _erfinv(x):
     return np.sign(x) * np.sqrt(np.sqrt(t1 * t1 - ln1mx2 / a) - t1)
 
 
+def nearest_codes(normed: jax.Array, codebook: jax.Array,
+                  uniform: bool) -> jax.Array:
+    """Index of the ``codebook`` entry nearest each of ``normed`` (values
+    in [-1, 1]), the lowest index on a tie, as int32.
+
+    ``uniform`` (``codebook`` is ``_uniform_codebook(bits)``) rounds in
+    closed form: the rounded grid point and its two neighbours are the
+    only candidates, compared by the same float32 distances an
+    exhaustive search takes, so the codes are the search's, bit for bit,
+    without its ``[..., 2**bits]`` distance tensor.  A general codebook
+    is searched exhaustively."""
+    levels = codebook.shape[-1]
+    if not uniform or levels == 2:
+        return jnp.argmin(jnp.abs(normed[..., None] - codebook),
+                          axis=-1).astype(jnp.int32)
+    half = levels // 2
+    mid = jnp.round(normed * float(half - 1)).astype(jnp.int32) + half
+    best = best_d = None
+    for step in (-1, 0, 1):            # ascending: a tie keeps the lower
+        c = jnp.clip(mid + step, 0, levels - 1)
+        d = jnp.abs(normed - jnp.take(codebook, c))
+        if best is None:
+            best, best_d = c, d
+        else:
+            take = d < best_d
+            best = jnp.where(take, c, best)
+            best_d = jnp.where(take, d, best_d)
+    return best
+
+
+@partial(jax.jit, static_argnums=(2, 3))
+def quantize_slice(w, codebook: jax.Array, group_size: int, uniform: bool):
+    """One ``w[K, N]`` (a device or host array) to (packed codes, group
+    scales), in one compiled call per shape: the float32 copy and its
+    rounding live only inside it.  ``codebook`` (float32; the uniform
+    one where ``uniform``) is passed in, not built inside, so that the
+    compiler cannot fold it into constants that differ from its entries
+    in the last bit."""
+    k, n = w.shape
+    bits = codebook.shape[-1].bit_length() - 1
+    wg = w.astype(jnp.float32).reshape(k // group_size, group_size, n)
+    scale = jnp.max(jnp.abs(wg), axis=1)  # [K/G, N]
+    scale = jnp.where(scale == 0, 1.0, scale)
+    # the barrier keeps the division out of the distances' fusion, where
+    # it could round otherwise than the division alone
+    normed = jax.lax.optimization_barrier(wg / scale[:, None, :])
+    codes = nearest_codes(normed, codebook, uniform)
+    codes = codes.astype(jnp.uint32).reshape(k, n)
+    return pack_grouped(codes, bits, group_size), scale
+
+
 def quantize(w: jax.Array, bits: int, group_size: int = 128,
              codebook: Optional[jax.Array] = None) -> QTensor:
     """Group-wise quantization of ``w[K, N]`` along K.
 
     For the uniform codebook this is classic symmetric round-to-nearest;
     for a general codebook it is nearest-codebook-entry assignment with a
-    per-group absmax scale.
+    per-group absmax scale.  ``w`` may be a host (NumPy) array: it moves
+    to the device inside one compiled call.
     """
     if w.ndim != 2:
         raise ValueError(f"expected W[K, N], got shape {w.shape}")
     k, n = w.shape
     if k % group_size != 0:
         raise ValueError(f"K={k} not a multiple of group_size={group_size}")
-    if codebook is None:
-        codebook = _uniform_codebook(bits)
-    codebook = codebook.astype(jnp.float32)
-    w = w.astype(jnp.float32)
-    wg = w.reshape(k // group_size, group_size, n)
-    scale = jnp.max(jnp.abs(wg), axis=1)  # [K/G, N]
-    scale = jnp.where(scale == 0, 1.0, scale)
-    normed = wg / scale[:, None, :]
-    # nearest codebook entry: [KG, G, N, 1] vs [levels]
-    dist = jnp.abs(normed[..., None] - codebook)
-    codes = jnp.argmin(dist, axis=-1).astype(jnp.uint32)
-    codes = codes.reshape(k, n)
-    return QTensor(packed=pack_grouped(codes, bits, group_size), scales=scale,
-                   codebook=codebook, bits=bits, group_size=group_size, k=k)
+    uniform = codebook is None
+    codebook = (_uniform_codebook(bits) if uniform
+                else codebook.astype(jnp.float32))
+    packed, scale = quantize_slice(w, codebook, group_size, uniform)
+    return QTensor(packed=packed, scales=scale, codebook=codebook,
+                   bits=bits, group_size=group_size, k=k)
 
 
 def dequantize(qt: QTensor) -> jax.Array:

@@ -18,7 +18,8 @@ from repro.models import ssm as ssm_lib
 from repro.models import xlstm as xlstm_lib
 from repro.models.common import ModelConfig
 from repro.models.layers import (apply_attention, apply_mlp, apply_norm,
-                                 attention_init, mlp_init, norm_init)
+                                 attention_init, layer_attention, mlp_init,
+                                 norm_init)
 from repro.models.sail_linear import mm
 from repro.dist.sharding import maybe_constrain, tp_all_reduce
 
@@ -65,11 +66,13 @@ def block_init(key, cfg: ModelConfig):
 
 def block_apply_seq(p, x, cfg: ModelConfig, positions,
                     moe_mode: str = "dispatch",
-                    collect_cache: bool = False):
+                    collect_cache: bool = False, layer=None):
     """Full-sequence block.  Returns (x, aux_loss, cache_entries).
 
-    The output dtype always matches the input dtype (scan-carry stable
-    under bf16 mixed precision)."""
+    ``layer``: this layer's absolute index, which picks its window and
+    RoPE where ``cfg.layer_types`` mixes kinds.  The output dtype always
+    matches the input dtype (scan-carry stable under bf16 mixed
+    precision)."""
     in_dtype = x.dtype
     x = maybe_constrain(x, "batch", None, None)
     aux = jnp.zeros((), jnp.float32)
@@ -97,10 +100,13 @@ def block_apply_seq(p, x, cfg: ModelConfig, positions,
 
     # --- attention (+ parallel mamba for hybrid) -------------------------
     h = apply_norm(p["attn_norm"], x, cfg)
+    window, rope = layer_attention(cfg, layer)
     attn_out = apply_attention(p["attn"], h, cfg, positions=positions,
-                               causal=True, window=cfg.window)
+                               causal=True,
+                               window=cfg.window if rope is None else window,
+                               rope=rope)
     if collect_cache:
-        cache["kv"] = _kv_from_seq(p["attn"], h, cfg, positions)
+        cache["kv"] = _kv_from_seq(p["attn"], h, cfg, positions, rope)
     if cfg.family == "hybrid":
         hs = apply_norm(p["ssm_norm"], x, cfg)
         if collect_cache:
@@ -123,7 +129,7 @@ def block_apply_seq(p, x, cfg: ModelConfig, positions,
     return x, aux, cache
 
 
-def _kv_from_seq(attn_p, h, cfg: ModelConfig, positions):
+def _kv_from_seq(attn_p, h, cfg: ModelConfig, positions, rope=None):
     """Recompute K/V for the prefill cache (keys stored post-RoPE)."""
     from repro.models.layers import apply_rope, _qk_norm
     b, t, _ = h.shape
@@ -132,7 +138,7 @@ def _kv_from_seq(attn_p, h, cfg: ModelConfig, positions):
     if cfg.qk_norm:
         k = _qk_norm(k, attn_p["k_norm"]["scale"], cfg.norm_eps)
     if cfg.pos == "rope":
-        k = apply_rope(k, positions, cfg)
+        k = apply_rope(k, positions, cfg, rope)
     return {"k": k, "v": v}
 
 
@@ -141,13 +147,17 @@ def _kv_from_seq(attn_p, h, cfg: ModelConfig, positions):
 # ---------------------------------------------------------------------------
 
 def block_apply_decode(p, x, cfg: ModelConfig, layer_cache, position,
-                       cache_len: int, moe_mode: str = "dense",
+                       cache_len: int, moe_mode: str = "routed",
                        quant_kv: bool = False, block_tables=None,
-                       layer=None):
+                       layer=None, active=None):
     """One-token decode.  x: [B, 1, D]; position: [B] absolute positions.
 
     layer_cache holds this layer's state (ring-buffered KV of size
-    ``cache_len``, ssm/xlstm states).  Returns (x, new_cache).
+    ``cache_len``, ssm/xlstm states).  Returns (x, new_cache, routed):
+    ``routed`` is the routed MoE's tokens per expert [E] over the
+    ``active`` lanes ([B] bool; all where None), None for other layers.
+    ``layer`` (this layer's absolute index) picks the window and RoPE
+    where ``cfg.layer_types`` mixes kinds.
 
     block_tables: optional [B, max_blocks] int32 — paged mode, where
     layer_cache is the WHOLE block pool of every layer (lane-dense
@@ -181,8 +191,9 @@ def block_apply_decode(p, x, cfg: ModelConfig, layer_cache, position,
                     return_state=True)
                 new_cache[f"mlstm_{i}"] = st
             x = (x + y).astype(in_dtype)
-        return x, new_cache
+        return x, new_cache, None
 
+    window, rope = layer_attention(cfg, layer)
     h = apply_norm(p["attn_norm"], x, cfg)
     q = mm(h, p["attn"]["wq"]).reshape(b, 1, cfg.n_heads, cfg.head_dim)
     k = mm(h, p["attn"]["wk"]).reshape(b, 1, cfg.n_kv, cfg.head_dim)
@@ -191,8 +202,8 @@ def block_apply_decode(p, x, cfg: ModelConfig, layer_cache, position,
         q = _qk_norm(q, p["attn"]["q_norm"]["scale"], cfg.norm_eps)
         k = _qk_norm(k, p["attn"]["k_norm"]["scale"], cfg.norm_eps)
     if cfg.pos == "rope":
-        q = apply_rope(q, position[:, None], cfg)
-        k = apply_rope(k, position[:, None], cfg)
+        q = apply_rope(q, position[:, None], cfg, rope)
+        k = apply_rope(k, position[:, None], cfg, rope)
 
     # the KV write, the paged gather, the int8 dequant and attention:
     # one ``attn`` scope in the trace's op names
@@ -242,7 +253,8 @@ def block_apply_decode(p, x, cfg: ModelConfig, layer_cache, position,
                 new_cache.update(k=kc, v=vc)
                 kf, vf = kc, vc
 
-        attn_out = _decode_attend(q, kf, vf, position, cfg, cache_len)
+        attn_out = _decode_attend(q, kf, vf, position, cfg, cache_len,
+                                  window)
     attn_out = tp_all_reduce(
         mm(attn_out.reshape(b, 1, cfg.q_dim), p["attn"]["wo"]))
 
@@ -257,11 +269,14 @@ def block_apply_decode(p, x, cfg: ModelConfig, layer_cache, position,
         x = (x + attn_out).astype(in_dtype)
 
     h = apply_norm(p["mlp_norm"], x, cfg)
-    if cfg.family == "moe":
+    routed = None
+    if cfg.family == "moe" and moe_mode == "routed":
+        y, routed = moe_lib.apply_moe_routed(p["moe"], h, cfg, valid=active)
+    elif cfg.family == "moe":
         y, _ = moe_lib.apply_moe(p["moe"], h, cfg, mode=moe_mode)
     else:
         y = apply_mlp(p["mlp"], h, cfg)
-    return (x + y).astype(in_dtype), new_cache
+    return (x + y).astype(in_dtype), new_cache, routed
 
 
 def _ring_write(cache, val, slot):
@@ -318,8 +333,9 @@ def paged_view(blocks, cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 
 def block_apply_verify(p, x, cfg: ModelConfig, layer_cache, position,
-                       cache_len: int, moe_mode: str = "dense",
-                       quant_kv: bool = False, block_tables=None):
+                       cache_len: int, moe_mode: str = "routed",
+                       quant_kv: bool = False, block_tables=None,
+                       layer=None):
     """Multi-token decode for speculative verification.
 
     x: [B, T, D] — the pending token plus the drafted tokens, occupying
@@ -352,9 +368,10 @@ def block_apply_verify(p, x, cfg: ModelConfig, layer_cache, position,
         q = _qk_norm(q, p["attn"]["q_norm"]["scale"], cfg.norm_eps)
         k = _qk_norm(k, p["attn"]["k_norm"]["scale"], cfg.norm_eps)
     qpos = position[:, None] + jnp.arange(t)[None, :]    # [B, T] absolute
+    window, rope = layer_attention(cfg, layer)
     if cfg.pos == "rope":
-        q = apply_rope(q, qpos, cfg)
-        k = apply_rope(k, qpos, cfg)
+        q = apply_rope(q, qpos, cfg, rope)
+        k = apply_rope(k, qpos, cfg, rope)
 
     if block_tables is not None:
         bs = layer_cache["k"].shape[1]
@@ -398,7 +415,7 @@ def block_apply_verify(p, x, cfg: ModelConfig, layer_cache, position,
             new_cache.update(k=kc, v=vc)
             kf, vf = kc, vc
 
-    attn_out = _verify_attend(q, kf, vf, position, cfg, cache_len)
+    attn_out = _verify_attend(q, kf, vf, position, cfg, cache_len, window)
     attn_out = tp_all_reduce(
         mm(attn_out.reshape(b, t, cfg.q_dim), p["attn"]["wo"]))
     x = (x + attn_out).astype(in_dtype)
@@ -424,7 +441,8 @@ def _ring_write_multi(cache, val, slot):
         indices_are_sorted=False)
 
 
-def _verify_attend(q, k, v, position, cfg: ModelConfig, cache_len: int):
+def _verify_attend(q, k, v, position, cfg: ModelConfig, cache_len: int,
+                   window=None):
     """Chunk-causal attention of T query tokens over the ring cache.
 
     q: [B, T, H, Dh]; k, v: [B, S, KV, Dh] (f32).  All T tokens' KV is
@@ -446,7 +464,8 @@ def _verify_attend(q, k, v, position, cfg: ModelConfig, cache_len: int):
     age = (cur_slot - slots) % s                         # 0 = newest
     held = final - age                                   # [B, S] absolute
     qpos = position[:, None] + jnp.arange(t)[None, :]    # [B, T]
-    window = cfg.window if cfg.window is not None else cache_len
+    if window is None:
+        window = cfg.window if cfg.window is not None else cache_len
     valid = ((held[:, None, :] >= 0)
              & (held[:, None, :] <= qpos[:, :, None])
              & (held[:, None, :] > qpos[:, :, None] - window))  # [B, T, S]
@@ -456,11 +475,13 @@ def _verify_attend(q, k, v, position, cfg: ModelConfig, cache_len: int):
     return out.reshape(b, t, hh, dh).astype(q.dtype)
 
 
-def _decode_attend(q, k, v, position, cfg: ModelConfig, cache_len: int):
+def _decode_attend(q, k, v, position, cfg: ModelConfig, cache_len: int,
+                   window=None):
     """Attention of one query token over the ring cache.
 
     q: [B, 1, H, Dh]; k, v: [B, S, KV, Dh] (f32).  Valid slots: those
-    holding positions in (pos - effective_window, pos]."""
+    holding positions in (pos - effective_window, pos].  ``window``: the
+    layer's own (traced) window; None, ``cfg.window`` or the cache."""
     b, _, hh, dh = q.shape
     s, kv = k.shape[1], k.shape[2]
     g = hh // kv
@@ -475,7 +496,8 @@ def _decode_attend(q, k, v, position, cfg: ModelConfig, cache_len: int):
     cur_slot = pos % s
     age = (cur_slot - slots) % s                         # 0 = newest
     held = pos - age                                     # absolute position
-    window = cfg.window if cfg.window is not None else cache_len
+    if window is None:
+        window = cfg.window if cfg.window is not None else cache_len
     valid = (held >= 0) & (held > pos - window)
     scores = jnp.where(valid[:, None, None, :], scores, -jnp.inf)
     p = jax.nn.softmax(scores, axis=-1)

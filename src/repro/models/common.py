@@ -9,7 +9,9 @@ published dimensions plus a ``smoke()`` reduction for CPU tests.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
+
+LAYER_KINDS = ("sliding", "full")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -30,6 +32,17 @@ class ModelConfig:
     rope_theta: float = 10000.0
     qk_norm: bool = False          # qwen3
     window: Optional[int] = None   # SWA width (danube, mixtral, hymba attn)
+    # --- per-layer attention kind (mellum2: 3 sliding, 1 full) ---
+    # One period of the layers' kinds, "sliding" (windowed by ``window``)
+    # or "full", repeated over the depth.  None: every layer alike.
+    layer_types: Optional[Tuple[str, ...]] = None
+    # YaRN scaling of the full layers' RoPE, as transformers'
+    # ``rope_type: "yarn"``; None keeps the plain RoPE on every layer
+    yarn_factor: Optional[float] = None
+    yarn_original_max: int = 0     # original_max_position_embeddings
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: Optional[float] = None   # None: 0.1 ln(f) + 1
     attention_bias: bool = False
     mlp_bias: bool = False
     tie_embeddings: bool = False
@@ -57,6 +70,33 @@ class ModelConfig:
     dtype: str = "float32"
     remat: bool = True
     attn_chunk: int = 512          # flash-attention KV block in pure JAX
+
+    def __post_init__(self):
+        if self.layer_types is not None:
+            kinds = tuple(self.layer_types)    # a JSON list, made hashable
+            object.__setattr__(self, "layer_types", kinds)
+            if not kinds or set(kinds) - set(LAYER_KINDS):
+                raise ValueError(f"layer_types must be a non-empty period "
+                                 f"of {LAYER_KINDS}, got {kinds}")
+            if "sliding" in kinds and not self.window:
+                raise ValueError("sliding layers need a window")
+        if self.yarn_factor is not None and self.layer_types is None:
+            raise ValueError("yarn_factor scales the full layers' RoPE; "
+                             "it needs layer_types")
+
+    def layer_kind(self, i: int) -> str:
+        """Attention kind of layer ``i`` (absolute index)."""
+        if self.layer_types is None:
+            return "sliding" if self.window else "full"
+        return self.layer_types[i % len(self.layer_types)]
+
+    @property
+    def lane_window(self) -> Optional[int]:
+        """The window every layer is held to, or None where some layer
+        attends to the whole context: what a serving lane must keep."""
+        if self.layer_types is not None and "full" in self.layer_types:
+            return None
+        return self.window
 
     @property
     def head_dim(self) -> int:

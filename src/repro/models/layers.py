@@ -8,12 +8,14 @@ would have; the decode path uses repro.kernels.decode_attn semantics.
 from __future__ import annotations
 
 import math
-from typing import Optional
+from functools import lru_cache
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.models.common import ModelConfig
+from repro.models.common import LAYER_KINDS, ModelConfig
 from repro.models.sail_linear import mm
 from repro.dist.sharding import maybe_constrain, tp_all_reduce
 
@@ -61,11 +63,79 @@ def rope_freqs(cfg: ModelConfig, dtype=jnp.float32):
     return inv.astype(dtype)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, cfg: ModelConfig):
-    """x: [B, T, H, Dh]; positions: [B, T] (absolute)."""
-    inv = rope_freqs(cfg)
+def yarn_freqs(cfg: ModelConfig) -> Tuple[np.ndarray, float]:
+    """The full layers' YaRN inverse frequencies and attention factor,
+    as transformers' ``_compute_yarn_parameters`` defines them: each
+    frequency is extrapolated (kept) above the correction dim of
+    ``beta_fast`` rotations, interpolated (divided by the factor) below
+    that of ``beta_slow``, with a linear ramp between; cos and sin are
+    then scaled by the attention factor."""
+    d, base, f = cfg.head_dim, cfg.rope_theta, cfg.yarn_factor
+    pos = base ** (np.arange(0, d, 2, dtype=np.float64) / d)
+
+    def corr(rotations):
+        return (d * math.log(cfg.yarn_original_max
+                             / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(corr(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(corr(cfg.yarn_beta_slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3), 0, 1)
+    keep = 1.0 - ramp                  # 1: extrapolate, 0: interpolate
+    inv = (1.0 / (f * pos)) * (1.0 - keep) + (1.0 / pos) * keep
+    factor = cfg.yarn_attention_factor
+    if factor is None:
+        factor = 0.1 * math.log(f) + 1.0 if f > 1 else 1.0
+    return inv.astype(np.float32), float(factor)
+
+
+# the window of a layer that attends to the whole context
+FULL_WINDOW = 1 << 30
+
+
+@lru_cache(maxsize=None)
+def _kind_tables(cfg: ModelConfig):
+    """Per attention kind (0 sliding, 1 full): window, RoPE inverse
+    frequencies and cos/sin scale; and each layer of the period's kind."""
+    plain = (1.0 / (cfg.rope_theta ** (np.arange(0, cfg.head_dim, 2,
+                                                 dtype=np.float64)
+                                       / cfg.head_dim))).astype(np.float32)
+    full, scale = (yarn_freqs(cfg) if cfg.yarn_factor is not None
+                   else (plain, 1.0))
+    windows = np.asarray([cfg.window or FULL_WINDOW, FULL_WINDOW], np.int32)
+    kinds = np.asarray([LAYER_KINDS.index(t) for t in cfg.layer_types],
+                       np.int32)
+    return (windows, np.stack([plain, full]),
+            np.asarray([1.0, scale], np.float32), kinds)
+
+
+def layer_attention(cfg: ModelConfig, layer):
+    """(window, rope) of layer ``layer`` (an absolute index, traced inside
+    a layer scan) for a model whose layers differ in kind; ``rope`` is
+    ``(inverse frequencies, cos/sin scale)`` for :func:`apply_rope`.
+    (None, None) for a model without ``layer_types``: its one window and
+    plain RoPE, as before."""
+    if cfg.layer_types is None:
+        return None, None
+    if layer is None:
+        raise ValueError("a model whose layers differ in kind "
+                         "(layer_types) needs each layer's index")
+    windows, inv, scale, kinds = _kind_tables(cfg)
+    k = jnp.asarray(kinds)[layer % len(kinds)]
+    return (jnp.asarray(windows)[k],
+            (jnp.asarray(inv)[k], jnp.asarray(scale)[k]))
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, cfg: ModelConfig,
+               rope=None):
+    """x: [B, T, H, Dh]; positions: [B, T] (absolute).  ``rope``: the
+    layer's (inverse frequencies, cos/sin scale) from
+    :func:`layer_attention`; None, the plain RoPE of ``cfg``."""
+    inv = rope_freqs(cfg) if rope is None else rope[0]
     ang = positions[..., None].astype(jnp.float32) * inv  # [B, T, Dh/2]
     sin, cos = jnp.sin(ang), jnp.cos(ang)
+    if rope is not None:
+        sin, cos = sin * rope[1], cos * rope[1]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     sin, cos = sin[:, :, None, :], cos[:, :, None, :]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
@@ -136,7 +206,7 @@ def flash_attention(q, k, v, *, causal: bool, window: Optional[int],
         qb = jnp.moveaxis(q.reshape(b, nq, q_chunk, h, dh), 1, 0)
         s_full = k.shape[1]
 
-        if window is not None and causal and kv_valid is None \
+        if isinstance(window, int) and causal and kv_valid is None \
                 and s_full > 2 * (window + q_chunk):
             # §Perf C1: sliding-window attention only needs KV in
             # [q_lo - window, q_hi); slice that band per q block instead of
@@ -226,8 +296,10 @@ def flash_attention(q, k, v, *, causal: bool, window: Optional[int],
 def apply_attention(p, x, cfg: ModelConfig, *, positions, causal=True,
                     kv_x: Optional[jax.Array] = None,
                     kv_valid: Optional[jax.Array] = None,
-                    window: Optional[int] = None):
-    """Full (prefill/train) attention.  kv_x given -> cross attention."""
+                    window: Optional[int] = None, rope=None):
+    """Full (prefill/train) attention.  kv_x given -> cross attention.
+    ``window`` may be traced (a layer scan's per-layer window); ``rope``
+    as :func:`apply_rope` takes it."""
     b, t, d = x.shape
     src = kv_x if kv_x is not None else x
     q = mm(x, p["wq"])
@@ -245,8 +317,8 @@ def apply_attention(p, x, cfg: ModelConfig, *, positions, causal=True,
         q = _qk_norm(q, p["q_norm"]["scale"], cfg.norm_eps)
         k = _qk_norm(k, p["k_norm"]["scale"], cfg.norm_eps)
     if cfg.pos == "rope" and kv_x is None:
-        q = apply_rope(q, positions, cfg)
-        k = apply_rope(k, positions, cfg)
+        q = apply_rope(q, positions, cfg, rope)
+        k = apply_rope(k, positions, cfg, rope)
     out = flash_attention(q, k, v, causal=causal and kv_x is None,
                           window=window, chunk=cfg.attn_chunk,
                           kv_valid=kv_valid)

@@ -92,14 +92,23 @@ def _segment_len(segment) -> int:
 
 
 def _scan_segments(body_fn, x, segments):
-    """Run ``lax.scan(body_fn, x, seg)`` over each segment in order.
-
-    Returns (x, [per-segment stacked ys])."""
+    """Run ``lax.scan(body_fn, x, (seg, layer indices))`` over each
+    segment in order, each layer's absolute index beside its params (a
+    model whose layers differ in kind looks its window and RoPE up by
+    it).  Returns (x, [per-segment stacked ys])."""
     ys = []
+    offset = 0
     for seg in segments:
-        x, y = jax.lax.scan(body_fn, x, seg)
+        n_seg = _segment_len(seg)
+        x, y = jax.lax.scan(body_fn, x, (seg, _layer_ids(offset, n_seg)))
         ys.append(y)
+        offset += n_seg
     return x, ys
+
+
+def _layer_ids(offset: int, n: int) -> jax.Array:
+    """Absolute indices of a segment's layers."""
+    return jnp.arange(offset, offset + n, dtype=jnp.int32)
 
 
 def _concat_segments(parts):
@@ -147,10 +156,11 @@ def forward(params, tokens, cfg: ModelConfig,
     b, t, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(t), (b, t))
 
-    def body(carry, p_l):
+    def body(carry, inp):
         x = carry
+        p_l, layer = inp
         y, aux, _ = blk.block_apply_seq(p_l, x, cfg, positions,
-                                        moe_mode=moe_mode)
+                                        moe_mode=moe_mode, layer=layer)
         return y, aux
 
     body_fn = jax.checkpoint(body) if cfg.remat else body
@@ -212,9 +222,10 @@ def loss_fn(params, batch, cfg: ModelConfig, moe_mode: str = "dispatch",
     b, t, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(t), (b, t))
 
-    def body(carry, p_l):
+    def body(carry, inp):
+        p_l, layer = inp
         y, aux, _ = blk.block_apply_seq(p_l, carry, cfg, positions,
-                                        moe_mode=moe_mode)
+                                        moe_mode=moe_mode, layer=layer)
         return y, aux
 
     body_fn = jax.checkpoint(body) if cfg.remat else body
@@ -330,7 +341,7 @@ def prefill(params, tokens, cfg: ModelConfig, cache_len: int,
             quant_kv: bool = False,
             prefix_embeds: Optional[jax.Array] = None,
             lengths: Optional[jax.Array] = None,
-            moe_mode: str = "dense"):
+            moe_mode: str = "routed"):
     """Process the prompt, build the decode cache, return last logits.
 
     tokens: [B, T] (right-padded).  lengths: [B] true prompt lengths.
@@ -343,10 +354,11 @@ def prefill(params, tokens, cfg: ModelConfig, cache_len: int,
     tt = x.shape[1]
     positions = jnp.broadcast_to(jnp.arange(tt), (b, tt))
 
-    def body(x, p_l):
+    def body(x, inp):
+        p_l, layer = inp
         y, _, cache = blk.block_apply_seq(p_l, x, cfg, positions,
                                           moe_mode=moe_mode,
-                                          collect_cache=True)
+                                          collect_cache=True, layer=layer)
         return y, cache
 
     body_fn = jax.checkpoint(body) if cfg.remat else body
@@ -412,7 +424,7 @@ def prefill_into_slot(params, tokens, cache, slot, cfg: ModelConfig,
                       quant_kv: bool = False,
                       lengths: Optional[jax.Array] = None,
                       prefix_embeds: Optional[jax.Array] = None,
-                      moe_mode: str = "dense"):
+                      moe_mode: str = "routed"):
     """Prefill request(s) and write their KV into slots of an existing pool.
 
     tokens: [b, T] (right-padded prompts; typically b == 1 — one newly
@@ -472,7 +484,7 @@ _copy_blocks_jit = jax.jit(_copy_blocks, donate_argnums=(0,))
 def prefill_into_blocks(params, tokens, cache, slots, phys, offs,
                         cfg: ModelConfig, quant_kv: bool = False,
                         lengths: Optional[jax.Array] = None,
-                        moe_mode: str = "dense"):
+                        moe_mode: str = "routed"):
     """Prefill request(s) and scatter their KV into a paged block pool.
 
     tokens: [b, T] right-padded prompts.  cache: pool from
@@ -490,15 +502,17 @@ def prefill_into_blocks(params, tokens, cache, slots, phys, offs,
         jnp.asarray(phys, jnp.int32), jnp.asarray(offs, jnp.int32))
 
 
-_DECODE_STATIC = ("cfg", "quant_kv", "moe_mode", "capture_layer_inputs")
+_DECODE_STATIC = ("cfg", "quant_kv", "moe_mode", "capture_layer_inputs",
+                  "count_experts")
 
 
 @partial(jax.jit, static_argnames=_DECODE_STATIC)
 def decode_step(params, tokens, cache, cfg: ModelConfig,
-                quant_kv: bool = False, moe_mode: str = "dense",
+                quant_kv: bool = False, moe_mode: str = "routed",
                 active_mask: Optional[jax.Array] = None,
                 capture_layer_inputs: bool = False,
-                block_tables: Optional[jax.Array] = None):
+                block_tables: Optional[jax.Array] = None,
+                count_experts: bool = False):
     """One decode step.  tokens [B, 1] -> (logits [B, V], new cache).
 
     active_mask: optional [B] bool — retired slots keep their cache
@@ -528,6 +542,13 @@ def decode_step(params, tokens, cache, cfg: ModelConfig,
     the DFM's Pattern Reuse Table would see.  The serving engine feeds
     them to ``repro.planning.tap.ActivationTap`` so measured PRT
     discounts can recalibrate on live traffic.
+
+    count_experts: additionally return, last, the routed MoE's tokens per
+    expert over the active lanes, ``[n_layers, E]`` int32, computed in the
+    same program (the engine's ``moe.*`` counters).
+
+    A model whose layers differ in kind (``cfg.layer_types``) looks each
+    layer's window and RoPE up by its absolute index in either layout.
     """
     b = tokens.shape[0]
     position = cache["length"]                   # absolute position of token
@@ -543,36 +564,37 @@ def decode_step(params, tokens, cache, cfg: ModelConfig,
         cache_len = block_tables.shape[1] * cache["layers"]["k"].shape[2]
 
     segments = block_segments(params)
-    captured = []
+    captured, routed = [], []
     if block_tables is not None:
         def paged_body(carry, inp):
             x, pool = carry
             p_l, layer = inp
-            y, pool = blk.block_apply_decode(
+            y, pool, tokens_l = blk.block_apply_decode(
                 p_l, x, cfg, pool, position, cache_len,
                 moe_mode=moe_mode, quant_kv=quant_kv,
-                block_tables=block_tables, layer=layer)
-            return (y, pool), (x if capture_layer_inputs else None)
+                block_tables=block_tables, layer=layer, active=active_mask)
+            return (y, pool), (x if capture_layer_inputs else None,
+                               tokens_l if count_experts else None)
 
         pool = cache["layers"]
         offset = 0
         for seg in segments:
             n_seg = _segment_len(seg)
-            layers = jnp.arange(offset, offset + n_seg, dtype=jnp.int32)
-            (x, pool), xs_seg = jax.lax.scan(paged_body, (x, pool),
-                                             (seg, layers))
+            (x, pool), (xs_seg, tok_seg) = jax.lax.scan(
+                paged_body, (x, pool), (seg, _layer_ids(offset, n_seg)))
             captured.append(xs_seg)
+            routed.append(tok_seg)
             offset += n_seg
         new_layers = pool
     else:
         def body(x, inp):
-            p_l, cache_l = inp
-            y, new_cache_l = blk.block_apply_decode(
+            p_l, cache_l, layer = inp
+            y, new_cache_l, tokens_l = blk.block_apply_decode(
                 p_l, x, cfg, cache_l, position, cache_len,
-                moe_mode=moe_mode, quant_kv=quant_kv)
-            if capture_layer_inputs:
-                return y, (new_cache_l, x)
-            return y, new_cache_l
+                moe_mode=moe_mode, quant_kv=quant_kv, layer=layer,
+                active=active_mask)
+            return y, (new_cache_l, x if capture_layer_inputs else None,
+                       tokens_l if count_experts else None)
 
         new_parts = []
         offset = 0
@@ -580,10 +602,10 @@ def decode_step(params, tokens, cache, cfg: ModelConfig,
             n_seg = _segment_len(seg)
             cache_seg = jax.tree_util.tree_map(
                 lambda a: a[offset:offset + n_seg], cache["layers"])
-            x, new_seg = jax.lax.scan(body, x, (seg, cache_seg))
-            if capture_layer_inputs:
-                new_seg, xs_seg = new_seg
-                captured.append(xs_seg)
+            x, (new_seg, xs_seg, tok_seg) = jax.lax.scan(
+                body, x, (seg, cache_seg, _layer_ids(offset, n_seg)))
+            captured.append(xs_seg)
+            routed.append(tok_seg)
             new_parts.append(new_seg)
             offset += n_seg
         new_layers = _concat_segments(new_parts)
@@ -594,9 +616,12 @@ def decode_step(params, tokens, cache, cfg: ModelConfig,
     else:
         new_length = cache["length"] + active_mask.astype(jnp.int32)
     new_cache = {"length": new_length, "layers": new_layers}
+    out = (logits, new_cache)
     if capture_layer_inputs:
-        return logits, new_cache, jnp.concatenate(captured, axis=0)
-    return logits, new_cache
+        out += (jnp.concatenate(captured, axis=0),)
+    if count_experts:
+        out += (jnp.concatenate(routed, axis=0),)
+    return out
 
 
 @lru_cache(maxsize=None)
@@ -629,7 +654,7 @@ BONUS_SALT = 0x0B_00_05
 
 @partial(jax.jit, static_argnames=("cfg", "quant_kv", "moe_mode"))
 def verify_step(params, tokens, cache, cfg: ModelConfig,
-                quant_kv: bool = False, moe_mode: str = "dense",
+                quant_kv: bool = False, moe_mode: str = "routed",
                 active_mask: Optional[jax.Array] = None,
                 block_tables: Optional[jax.Array] = None):
     """Multi-token decode for speculative verification.
@@ -660,11 +685,11 @@ def verify_step(params, tokens, cache, cfg: ModelConfig,
         cache_len = block_tables.shape[1] * cache["layers"]["k"].shape[2]
 
     def body(x, inp):
-        p_l, cache_l = inp
+        p_l, cache_l, layer = inp
         y, new_cache_l = blk.block_apply_verify(
             p_l, x, cfg, cache_l, position, cache_len,
             moe_mode=moe_mode, quant_kv=quant_kv,
-            block_tables=block_tables)
+            block_tables=block_tables, layer=layer)
         return y, new_cache_l
 
     segments = block_segments(params)
@@ -674,7 +699,8 @@ def verify_step(params, tokens, cache, cfg: ModelConfig,
         n_seg = _segment_len(seg)
         cache_seg = jax.tree_util.tree_map(
             lambda a: a[offset:offset + n_seg], cache["layers"])
-        x, new_seg = jax.lax.scan(body, x, (seg, cache_seg))
+        x, new_seg = jax.lax.scan(body, x, (seg, cache_seg,
+                                            _layer_ids(offset, n_seg)))
         new_parts.append(new_seg)
         offset += n_seg
     new_layers = _concat_segments(new_parts)
@@ -690,7 +716,7 @@ def verify_step(params, tokens, cache, cfg: ModelConfig,
 @partial(jax.jit, static_argnames=("cfg", "k", "quant_kv", "moe_mode",
                                    "temperature", "seed"))
 def draft_tokens(params, tokens, cache, cfg: ModelConfig, k: int,
-                 quant_kv: bool = False, moe_mode: str = "dense",
+                 quant_kv: bool = False, moe_mode: str = "routed",
                  active_mask: Optional[jax.Array] = None,
                  block_tables: Optional[jax.Array] = None,
                  temperature: float = 0.0, seed: int = 0,
@@ -744,8 +770,8 @@ def greedy_generate(params, prompt, cfg: ModelConfig, max_new: int,
     """Reference generation loop (serving engine uses its own)."""
     b, t = prompt.shape
     cache_len = cache_len or (t + max_new)
-    if cfg.window is not None:
-        cache_len = min(cache_len, cfg.window)
+    if cfg.lane_window is not None:
+        cache_len = min(cache_len, cfg.lane_window)
     logits, cache = prefill(params, prompt, cfg, cache_len, quant_kv)
     out = []
     tok = jnp.argmax(logits, axis=-1)[:, None]

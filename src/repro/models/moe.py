@@ -1,14 +1,20 @@
-"""Mixture-of-Experts layer (granite-moe 32e top-8, mixtral 8e top-2).
+"""Mixture-of-Experts layer (granite-moe 32e top-8, mixtral 8e top-2,
+mellum2 64e top-8).
 
-Two dispatch modes:
+Three dispatch modes:
+  * "routed": dropless serving form — each token's top-k (token, expert)
+    pairs are sorted by expert and every projection is one grouped
+    matmul over the sorted rows (``jax.lax.ragged_dot``), so FLOPs scale
+    with top_k, not with E, and no token is dropped.  Prefill, decode
+    and verify run it.
   * "dispatch": GShard-style capacity-based token dispatch (one-hot combine
     tensors, einsum over expert-major buffers).  FLOPs scale with top_k and
     capacity_factor — used for training where efficiency matters; experts
     shard over the mesh 'model' axis (EP) or within-expert FFN dim (TP),
     per cfg.moe_shard.
   * "dense": every expert computed for every token, combined by routing
-    weights — exact (no capacity drops), used for tiny decode batches and
-    as the correctness oracle for the dispatch path.
+    weights — exact (no capacity drops), the CPU tests' oracle for the
+    other two.
 """
 from __future__ import annotations
 
@@ -17,7 +23,7 @@ import jax.numpy as jnp
 
 from repro.models.common import ModelConfig
 from repro.models.layers import dense_init
-from repro.models.sail_linear import einsum_q, mm
+from repro.models.sail_linear import einsum_q, mm, ragged_q
 from repro.dist.sharding import maybe_constrain
 
 
@@ -54,6 +60,44 @@ def apply_moe_dense(p, x, cfg: ModelConfig):
     y = einsum_q("tef,efd->ted", h, p["w_down"])        # [T, E, D]
     out = jnp.einsum("ted,te->td", y, comb)
     return out.reshape(*lead, d), _aux_loss(logits, comb, cfg)
+
+
+def apply_moe_routed(p, x, cfg: ModelConfig, valid=None):
+    """Dropless routed MoE: top-k over a float32 router, the (token,
+    expert) pairs sorted by expert, one grouped matmul per projection
+    over the sorted rows, and the outputs combined with the renormalized
+    top-k weights.  Returns (out, tokens per expert [E] int32), the
+    counts over the rows ``valid`` ([T] bool, every row where None) —
+    what the engine accumulates as ``moe.*`` counters.  Every op sits in
+    the ``moe`` scope of a profiler trace."""
+    with jax.named_scope("moe"):
+        *lead, d = x.shape
+        xt = x.reshape(-1, d)
+        t, k = xt.shape[0], cfg.top_k
+        # full float32 (never quantized): the router picks discrete experts
+        logits = jnp.dot(xt.astype(jnp.float32), p["router"],
+                         precision=jax.lax.Precision.HIGHEST)
+        topv, topi = jax.lax.top_k(logits, k)
+        probs = jax.nn.softmax(topv, axis=-1)             # renormalized
+        flat = topi.reshape(-1)                           # [T*k]
+        order = jnp.argsort(flat, stable=True)
+        sizes = jnp.bincount(flat, length=cfg.n_experts)
+        rows = xt[order // k]                             # sorted by expert
+        if cfg.act == "gelu":
+            h = jax.nn.gelu(ragged_q(rows, p["w_up"], sizes))
+        else:
+            h = jax.nn.silu(ragged_q(rows, p["w_gate"], sizes)) * \
+                ragged_q(rows, p["w_up"], sizes)
+        y = ragged_q(h, p["w_down"], sizes)[jnp.argsort(order)]
+        out = jnp.einsum("tkd,tk->td", y.reshape(t, k, d),
+                         probs.astype(y.dtype))
+        if valid is None:
+            counts = sizes
+        else:
+            counts = jnp.bincount(
+                flat, weights=jnp.repeat(valid.reshape(-1), k).astype(
+                    jnp.int32), length=cfg.n_experts)
+        return out.reshape(*lead, d).astype(x.dtype), counts.astype(jnp.int32)
 
 
 MOE_GROUP_TOKENS = 512   # GShard dispatch group; the dispatch tensor is
@@ -148,6 +192,9 @@ def _aux_loss(logits, comb, cfg: ModelConfig):
 
 
 def apply_moe(p, x, cfg: ModelConfig, mode: str = "dispatch"):
+    """(out, aux loss); "routed", a serving form, has no aux loss."""
+    if mode == "routed":
+        return apply_moe_routed(p, x, cfg)[0], jnp.zeros((), jnp.float32)
     if mode == "dense":
         return apply_moe_dense(p, x, cfg)
     return apply_moe_dispatch(p, x, cfg)

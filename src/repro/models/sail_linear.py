@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import dataclasses
 import re
+from functools import partial
 from typing import Any, Dict, List, Mapping, Optional, Tuple, Union
 
 import jax
@@ -56,7 +57,7 @@ from repro.core.quant import (SUPPORTED_ABITS, SUPPORTED_BITS, QTensor,
 __all__ = [
     "ActQuantWeight", "BitAllocation", "QuantPolicy", "QTensor",
     "StackedQTensor", "act_fake_quant", "dequantize_any", "einsum_q", "mm",
-    "nf_codebook", "quantize_params", "set_backend",
+    "nf_codebook", "quantize_params", "ragged_q", "set_backend",
 ]
 
 # Module-level backend switch: "jnp" (XLA path — used under pjit / dry-run)
@@ -308,7 +309,7 @@ def _check_abits(b: Optional[int]) -> Optional[int]:
 
 
 def _should_quantize(path: str, w, policy: QuantPolicy) -> bool:
-    if not hasattr(w, "ndim") or w.ndim != 2:
+    if not hasattr(w, "ndim") or w.ndim != 2 or "router" in path:
         return False
     if w.size < policy.min_size:
         return False
@@ -320,9 +321,11 @@ def _should_quantize(path: str, w, policy: QuantPolicy) -> bool:
 
 
 def _should_quantize_stacked(path: str, w, policy: QuantPolicy) -> bool:
-    """Scan-stacked [L, K, N] / MoE [L, E, K, N] weights."""
+    """Scan-stacked [L, K, N] / MoE [L, E, K, N] weights.  An expert
+    router stays float32: it picks which experts run, and deployments
+    keep it unrounded."""
     return (hasattr(w, "ndim") and w.ndim >= 3
-            and "embed" not in path
+            and "embed" not in path and "router" not in path
             and w.shape[-2] % policy.group_size == 0
             and w.shape[-2] * w.shape[-1] >= policy.min_size)
 
@@ -345,30 +348,39 @@ def _scalar_bits(spec: BitsSpec, path: str, offset: int,
     return check(int(spec))
 
 
+@partial(jax.jit, donate_argnums=(0, 1))
+def _put_slice(packed, scales, i, p, sc):
+    return packed.at[i].set(p), scales.at[i].set(sc)
+
+
 def _quantize_stacked(w, bits: int, policy: QuantPolicy,
                       abits: Optional[int] = None) -> "StackedQTensor":
-    """Quantize a stacked weight per slice (vmap over leading dims).
+    """Quantize a stacked weight one ``[K, N]`` slice at a time.
 
+    Each slice moves to the device (from the host, for a NumPy leaf) and
+    is rounded in its own compiled call, so neither the whole float32
+    stack nor its rounding temporaries are ever on the device at once;
+    under a trace (``jax.eval_shape``) the slices are mapped instead.
     The codebook is tiled along the first leading dim so the whole
     StackedQTensor can ride through ``lax.scan`` as an xs pytree."""
-    from repro.core.quant import pack_grouped
+    from repro.core.quant import quantize_slice
     lead = w.shape[:-2]
     k, n = w.shape[-2:]
     g = policy.group_size
     cb = policy.codebook_for(bits)
     codebook = (_uniform_codebook(bits) if cb is None else cb).astype(
         jnp.float32)
-
-    def one(w2d):
-        wg = w2d.astype(jnp.float32).reshape(k // g, g, n)
-        scale = jnp.max(jnp.abs(wg), axis=1)
-        scale = jnp.where(scale == 0, 1.0, scale)
-        codes = jnp.argmin(
-            jnp.abs((wg / scale[:, None, :])[..., None] - codebook),
-            axis=-1).astype(jnp.uint32).reshape(k, n)
-        return pack_grouped(codes, bits, g), scale
-
-    packed, scales = jax.vmap(one)(w.reshape((-1, k, n)))
+    flat = w.reshape((-1, k, n))
+    one = lambda a: quantize_slice(a, codebook, g, cb is None)  # noqa: E731
+    if isinstance(w, jax.core.Tracer):
+        packed, scales = jax.vmap(one)(flat)
+    else:
+        p0, s0 = jax.eval_shape(one, jax.ShapeDtypeStruct((k, n), w.dtype))
+        packed = jnp.zeros((flat.shape[0],) + p0.shape, p0.dtype)
+        scales = jnp.zeros((flat.shape[0],) + s0.shape, s0.dtype)
+        for i in range(flat.shape[0]):
+            packed, scales = _put_slice(
+                packed, scales, i, *one(flat[i]))
     packed = packed.reshape(lead + packed.shape[1:])
     scales = scales.reshape(lead + scales.shape[1:])
     return StackedQTensor(
@@ -409,7 +421,9 @@ def _quantize_tree(params, policy: QuantPolicy, offset: int = 0):
             out.append(stacked)
         else:
             after += w.size * w.dtype.itemsize
-            out.append(w)
+            # a host leaf goes to the device once, here, and not with
+            # every call of a step that takes the tree
+            out.append(jnp.asarray(w))
     return jax.tree_util.tree_unflatten(treedef, out), before, after
 
 
@@ -460,7 +474,9 @@ def quantize_params(params, policy: QuantPolicy = QuantPolicy()):
     """Convert a parameter tree to the SAIL serving format.
 
     Stacked weights — scan-stacked layers [L, K, N] and MoE experts
-    [L, E, K, N] — are quantized per slice (vmap over leading dims).
+    [L, E, K, N] — are quantized one [K, N] slice at a time, a host
+    (NumPy) leaf moving to the device slice by slice; the router stays
+    float32.
     Bits are resolved per path (``policy.bits_for``); a per-layer tuple on
     a ``blocks`` leaf splits the stack into uniform-bits segments and the
     returned tree carries ``params["blocks"]`` as a list of stacked trees
@@ -522,20 +538,19 @@ def dequantize_any(w):
         return dequantize(w)
     if isinstance(w, StackedQTensor):
         cb = w.codebook if w.codebook.ndim == 1 else w.codebook[0]
-
-        def one(packed, scales):
-            codes = unpack_grouped(packed, w.bits, w.group_size, w.k)
-            vals = cb[codes].reshape(
-                w.k // w.group_size, w.group_size, -1)
-            return (vals * scales[:, None, :]).reshape(w.k, -1)
-
-        if w.packed.ndim == 2:
-            return one(w.packed, w.scales)
         lead = w.packed.shape[:-2]
         flat_p = w.packed.reshape((-1,) + w.packed.shape[-2:])
         flat_s = w.scales.reshape((-1,) + w.scales.shape[-2:])
-        out = jax.vmap(one)(flat_p, flat_s)
-        return out.reshape(lead + out.shape[-2:])
+        codes = jax.vmap(lambda p: unpack_grouped(
+            p, w.bits, w.group_size, w.k))(flat_p)          # [S, K, N]
+        n = codes.shape[-1]
+        # look the codes up through a 2-D view: the TPU compiler turns a
+        # small table's gather with 2-D indices into selects, but keeps
+        # one with more index dims as an element-wise gather, far slower
+        vals = cb[codes.reshape(-1, n)].reshape(
+            codes.shape[0], w.k // w.group_size, w.group_size, n)
+        out = vals * flat_s[:, :, None, :]
+        return out.reshape(lead + (w.k, n))
     return w
 
 
@@ -559,6 +574,25 @@ def _einsum_scale_to_out(spec: str, x_shape, xs: jax.Array) -> Optional[jax.Arra
     for c in out:
         dims.append(next(it) if c in keep else 1)
     return xs_t.reshape(dims)
+
+
+def ragged_q(x: jax.Array, w: Any, group_sizes: jax.Array) -> jax.Array:
+    """Grouped matmul: rows of ``x [M, K]``, sorted by group, times their
+    group's ``w [G, K, N]`` (``jax.lax.ragged_dot``), where w may be
+    stacked-quantized.  A quantized stack is dequantized as
+    :func:`einsum_q` does; with ``abits`` the per-token integer codes go
+    in and the per-token scale is applied to the output."""
+    x, w = _apply_act_quant(x, w)
+    if isinstance(w, (QTensor, StackedQTensor)):
+        wd = dequantize_any(w)
+        if (w.abits is not None
+                and jnp.issubdtype(x.dtype, jnp.floating)):
+            xq, xs = quantize_activations(x, w.abits)
+            y = jax.lax.ragged_dot(xq.astype(jnp.float32),
+                                   wd.astype(jnp.float32), group_sizes)
+            return (y * xs).astype(x.dtype)
+        w = wd.astype(x.dtype)
+    return jax.lax.ragged_dot(x, w, group_sizes)
 
 
 def einsum_q(spec: str, x: jax.Array, w: Any) -> jax.Array:

@@ -79,6 +79,10 @@ def tp_supported(cfg: ModelConfig, tp: int) -> Optional[str]:
     """
     if tp <= 1:
         return None
+    if cfg.layer_types is not None:
+        return ("layer_types (layers of different attention kinds) are "
+                "not tensor-parallel servable: the shard_map entry points "
+                "do not carry each layer's window and RoPE")
     if cfg.family != "dense":
         return (f"family={cfg.family!r} is not tensor-parallel servable "
                 "(dense attention only — recurrent/expert state does not "

@@ -357,8 +357,18 @@ class Engine:
         # backend declined it
         self.decode_pool_donated = 0
         self.prefill_tokens = 0
-        clen = ecfg.cache_len if cfg.window is None \
-            else min(ecfg.cache_len, cfg.window)
+        # routed MoE decode steps, summed over (layer, step) pairs: the
+        # (token, expert) pairs of active lanes, the experts that got at
+        # least one, and the largest expert's tokens over the mean per
+        # touched expert (stats()["moe"])
+        self.moe_counts: Optional[Dict[str, float]] = (
+            dict.fromkeys(("layer_steps", "routed_pairs", "experts_touched",
+                           "imbalance_sum"), 0)
+            if cfg.family == "moe" else None)
+        # a lane keeps its window where every layer is windowed; a model
+        # with full-attention layers keeps the whole context
+        clen = ecfg.cache_len if cfg.lane_window is None \
+            else min(ecfg.cache_len, cfg.lane_window)
         self._clen = clen
         self._orig_plen: Dict[int, int] = {}
         self.peak_active = 0
@@ -372,6 +382,11 @@ class Engine:
                 raise ValueError(
                     f"paged KV is attention-only; family={cfg.family!r} "
                     "keeps recurrent state, not a block pool")
+        elif cfg.layer_types is not None:
+            raise ValueError(
+                "a model whose layers differ in kind (layer_types) serves "
+                "from the paged pool (kv_block_size): a ring lane would "
+                "wrap over positions its full-attention layers still read")
         if ecfg.mode == "continuous":
             self._cur = np.zeros((ecfg.batch_size,), np.int32)
             if self.paged:
@@ -421,6 +436,11 @@ class Engine:
                     "speculative decoding needs a pure-attention family "
                     "— recurrent state cannot roll back to the accepted "
                     f"frontier (family={cfg.family!r})")
+            if cfg.layer_types is not None:
+                raise ValueError(
+                    "speculative decoding is not supported for a model "
+                    "whose layers differ in kind (layer_types): its "
+                    "draft/verify round is untested there")
             if cfg.pos == "sinusoidal":
                 raise ValueError(
                     "speculative decoding does not support sinusoidal "
@@ -591,13 +611,13 @@ class Engine:
                         active_mask=jnp.asarray(mask),
                         capture_layer_inputs=capture,
                         block_tables=(jnp.asarray(self._tables_np)
-                                      if self.paged else None))
+                                      if self.paged else None),
+                        count_experts=self.moe_counts is not None)
                     self.decode_pool_donated += int(old_pool.is_deleted())
+                logits, self.cache, *rest = out
                 if capture:
-                    logits, self.cache, layer_inputs = out
-                    self.tap.observe(layer_inputs, mask)
-                else:
-                    logits, self.cache = out
+                    self.tap.observe(rest.pop(0), mask)
+                routed = rest[0] if rest else None
                 self.iterations += 1
                 self.decode_iterations += 1
                 uids = np.zeros((self.ecfg.batch_size,), np.uint32)
@@ -605,7 +625,12 @@ class Engine:
                 for req in active:
                     uids[req.slot] = req.uid
                     sidx[req.slot] = self._sample_index(req.uid)
-                nxt = self._sample(logits, uids, sidx)
+                if routed is None:
+                    nxt = self._sample(logits, uids, sidx)
+                else:
+                    nxt, routed = self._sample(logits, uids, sidx,
+                                               also=routed)
+                    self._count_experts(routed)
             # _sample's np.asarray blocks on the device, so the span
             # covers the whole iteration (incl. any tap-capture sync)
             dt = sp.seconds
@@ -1289,24 +1314,42 @@ class Engine:
         index), and slot-vs-paged pool layout."""
         return len(self._gen[uid]) - self._orig_plen.get(uid, 0)
 
-    def _sample(self, logits, uids=None, indices=None) -> np.ndarray:
+    def _sample(self, logits, uids=None, indices=None, also=None):
         """Sample one token per logits row.
 
         ``uids``/``indices`` carry each row's (request uid, per-request
         sample index); rows without a live request (masked slots) pass
         uid 0 and their draws are discarded by the caller.  Greedy
         ignores them entirely.  The host waits here for the device (the
-        ``engine.sample`` span)."""
+        ``engine.sample`` span).  ``also``: a device array fetched in the
+        same transfer; then (tokens, its host copy) is returned."""
         with self.spans.span("engine.sample"):
             if self.ecfg.temperature <= 0:
-                return np.asarray(jnp.argmax(logits, axis=-1))
-            if uids is None:
-                uids = np.zeros((logits.shape[0],), np.uint32)
-                indices = np.zeros((logits.shape[0],), np.uint32)
-            return np.asarray(_sample_rows_jit(
-                logits, jnp.asarray(np.asarray(uids, np.uint32)),
-                jnp.asarray(np.asarray(indices, np.uint32)),
-                self.ecfg.seed, self.ecfg.temperature))
+                toks = jnp.argmax(logits, axis=-1)
+            else:
+                if uids is None:
+                    uids = np.zeros((logits.shape[0],), np.uint32)
+                    indices = np.zeros((logits.shape[0],), np.uint32)
+                toks = _sample_rows_jit(
+                    logits, jnp.asarray(np.asarray(uids, np.uint32)),
+                    jnp.asarray(np.asarray(indices, np.uint32)),
+                    self.ecfg.seed, self.ecfg.temperature)
+            if also is None:
+                return np.asarray(toks)
+            return tuple(np.asarray(a) for a in jax.device_get((toks, also)))
+
+    def _count_experts(self, tokens: np.ndarray) -> None:
+        """Add one decode step's tokens per expert ``[layers, E]``."""
+        t = tokens.astype(np.int64)
+        pairs, top = t.sum(1), t.max(1)
+        touched = (t > 0).sum(1)
+        live = pairs > 0
+        m = self.moe_counts
+        m["layer_steps"] += int(live.sum())
+        m["routed_pairs"] += int(pairs.sum())
+        m["experts_touched"] += int(touched.sum())
+        m["imbalance_sum"] += float(
+            (top[live] * touched[live] / pairs[live]).sum())
 
     def _tp_stats(self) -> Dict[str, Any]:
         """Observability for tensor-parallel serving: shard count, wire
@@ -1359,6 +1402,11 @@ class Engine:
                        else None),
                 "block_pool": (self.block_mgr.stats()
                                if self.paged else None),
+                # routed experts over the decode steps (None without
+                # experts): layer_steps, routed_pairs, experts_touched,
+                # imbalance_sum
+                "moe": (dict(self.moe_counts)
+                        if self.moe_counts is not None else None),
                 # self-speculative decoding: draft plan, rounds,
                 # acceptance rate (None when not speculating)
                 "speculative": (self.spec_decoder.stats()

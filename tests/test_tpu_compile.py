@@ -182,3 +182,48 @@ def test_engine_decode_step_updates_pool_in_place_on_cpu(donate):
         assert moves == [] and alias >= pool_bytes(eng.cache)
     else:
         assert moves and alias == 0
+
+
+def test_mellum_decode_step_compiles_for_v5e(one_chip):
+    """The decode program of the ``mellum2.code.q4a8`` cell (8 layers at
+    Mellum2's widths, 64 experts top-8, batch 32, ``cache_len`` 5632,
+    block 16, int8 KV, ``uniform:4a8``, the engine's expert counts):
+    the routed experts lower to grouped matmuls, the int8 KV pool is
+    updated in place, and the step's temporaries leave room on a 16 GB
+    chip.  (The float32 scale pools, 64 lanes wide at 4 KV heads and
+    block 16, are moved through on-chip memory at each layer, as
+    a narrower scale layout would for TinyMistral: PERF.md §7.)"""
+    import json
+    from pathlib import Path
+    from repro.models.common import ModelConfig
+    conf = json.loads((Path(__file__).resolve().parents[1] / "bench"
+                       / "configs" / "mellum2_12b_a2_5b.json").read_text())
+    cfg = ModelConfig(**conf["model"])
+    batch, cache_len, block = 32, 5632, 16
+    cols = cache_len // block
+    num_blocks = batch * cols + 1
+    policy = planning.PlanSpec.parse("uniform:4a8").to_policy(
+        QuantPolicy(bits=4, group_size=128, min_size=1024))
+    params = jax.eval_shape(
+        lambda key: quantize_params(lm.init_params(key, cfg), policy)[0],
+        jax.random.PRNGKey(0))
+    assert isinstance(params["blocks"]["moe"]["router"], jax.ShapeDtypeStruct)
+    cache = jax.eval_shape(lambda: lm.init_paged_cache(
+        None, cfg, batch, num_blocks, block, quant_kv=True))
+    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip)
+    params, cache = jax.tree_util.tree_map(place, (params, cache))
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    compiled = lm.donating(lm.decode_step).lower(
+        params, sds((batch, 1), jnp.int32), cache, cfg, quant_kv=True,
+        active_mask=sds((batch,), jnp.bool_),
+        block_tables=sds((batch, cols), jnp.int32),
+        count_experts=True).compile()
+    hlo = compiled.as_text()
+    assert "ragged" in hlo
+    assert not [line for line in pool_moves(hlo, num_blocks)
+                if "s8[" in line]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes(cache) > 1.4e9
+    assert mem.temp_size_in_bytes < 4e9
