@@ -1,7 +1,11 @@
-"""Pallas TPU kernels for the paper's hot spots (LUT-GEMV, typeconv).
+"""Pallas TPU kernels for the paper's hot spots (LUT-GEMV, typeconv) and
+for paged decode attention.
 
-Backend selection for every kernel lives here: a kernel compiles for the
-TPU when one is attached and runs the Pallas interpreter otherwise.
+Backend selection for the LUT-GEMV and typeconv kernels lives here: a
+kernel compiles for the TPU when one is attached and runs the Pallas
+interpreter otherwise.  The decode attention kernel is lowered only for a
+TPU (``lax.platform_dependent`` in ``repro.models.blocks``); its tests
+run it interpreted.
 """
 from __future__ import annotations
 

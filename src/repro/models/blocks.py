@@ -20,8 +20,9 @@ from repro.models.common import ModelConfig
 from repro.models.layers import (apply_attention, apply_mlp, apply_norm,
                                  attention_init, layer_attention, mlp_init,
                                  norm_init)
+from repro.kernels.decode_attn.kernel import paged_decode_attention
 from repro.models.sail_linear import mm
-from repro.dist.sharding import maybe_constrain, tp_all_reduce
+from repro.dist.sharding import maybe_constrain, tp_active, tp_all_reduce
 
 
 # ---------------------------------------------------------------------------
@@ -165,11 +166,14 @@ def block_apply_decode(p, x, cfg: ModelConfig, layer_cache, position,
     KV*block_size]``; ``lm.init_paged_cache``), ``layer`` is this
     layer's index into it and ``cache_len == max_blocks * block_size``.
     The write scatters one token per lane at ``(layer, block, offset)``;
-    attention gathers the lane's blocks of this layer into a contiguous
-    view and reuses the ring validity math (paged lanes never wrap, so
-    "slot holds position slot" makes the two formulas agree).  The pool
-    goes in and out whole, so a caller that carries it through its layer
-    loop updates it in place.
+    attention then reads the lane's blocks of this layer: with an int8
+    pool through :func:`_paged_int8_attend` (on a TPU, a kernel that
+    reads only the live blocks in place), otherwise by gathering them
+    into a contiguous view that reuses the ring validity math (paged
+    lanes never wrap, so "slot holds position slot" makes the two
+    formulas agree).  ``active`` lanes ([B] bool) are the ones the kernel
+    reads for.  The pool goes in and out whole, so a caller that carries
+    it through its layer loop updates it in place.
     """
     from repro.core.quant import quantize_kv
     from repro.models.layers import apply_rope, _qk_norm
@@ -205,12 +209,12 @@ def block_apply_decode(p, x, cfg: ModelConfig, layer_cache, position,
         q = apply_rope(q, position[:, None], cfg, rope)
         k = apply_rope(k, position[:, None], cfg, rope)
 
-    # the KV write, the paged gather, the int8 dequant and attention:
-    # one ``attn`` scope in the trace's op names
+    # the KV write and attention over the cache: one ``attn`` scope in
+    # the trace's op names
     with jax.named_scope("attn"):
         if block_tables is not None:
-            # paged write: scatter through the block table, then gather the
-            # lane's blocks back into a contiguous [B, S, KV, Dh] view
+            # paged write: scatter through the block table, then attend
+            # over the lane's blocks
             bs = layer_cache["k"].shape[2]
             nbp = block_tables.shape[1]
             logical = jnp.clip(position // bs, 0, nbp - 1)
@@ -218,22 +222,24 @@ def block_apply_decode(p, x, cfg: ModelConfig, layer_cache, position,
             phys = jnp.take_along_axis(block_tables, logical[:, None],
                                        axis=1)[:, 0]
             write = lambda name, val: paged_write(
-                layer_cache[name], (layer,), phys, off, val[:, 0])
-            # one gather indexed by (layer, table): pool[layer][table]
-            # would slice the whole layer out of the pool first
-            gather = lambda arr: paged_view(arr[layer, block_tables], cfg)
+                layer_cache[name], (layer,), phys, off, val[:, 0],
+                one_per_block=True)
             if quant_kv:
                 kq, ks = quantize_kv(k)
                 vq, vs = quantize_kv(v)
                 kc, vc = write("k", kq), write("v", vq)
                 ksc, vsc = write("k_scale", ks), write("v_scale", vs)
                 new_cache.update(k=kc, v=vc, k_scale=ksc, v_scale=vsc)
-                kf = gather(kc).astype(jnp.float32) * gather(ksc)
-                vf = gather(vc).astype(jnp.float32) * gather(vsc)
+                attn_out = _paged_int8_attend(
+                    q, (kc, ksc, vc, vsc), layer, position, block_tables,
+                    active, cfg, cache_len, window)
             else:
                 kc, vc = write("k", k), write("v", v)
                 new_cache.update(k=kc, v=vc)
-                kf, vf = gather(kc), gather(vc)
+                gather = lambda arr: paged_view(arr[layer, block_tables],
+                                                cfg)
+                attn_out = _decode_attend(q, gather(kc), gather(vc),
+                                          position, cfg, cache_len, window)
         else:
             # ring-buffer write at position % cache_len
             slot = (position % cache_len)[:, None, None, None]
@@ -252,9 +258,8 @@ def block_apply_decode(p, x, cfg: ModelConfig, layer_cache, position,
                 vc = _ring_write(layer_cache["v"], v, slot)
                 new_cache.update(k=kc, v=vc)
                 kf, vf = kc, vc
-
-        attn_out = _decode_attend(q, kf, vf, position, cfg, cache_len,
-                                  window)
+            attn_out = _decode_attend(q, kf, vf, position, cfg, cache_len,
+                                      window)
     attn_out = tp_all_reduce(
         mm(attn_out.reshape(b, 1, cfg.q_dim), p["attn"]["wo"]))
 
@@ -279,6 +284,43 @@ def block_apply_decode(p, x, cfg: ModelConfig, layer_cache, position,
     return (x + y).astype(in_dtype), new_cache, routed
 
 
+def _paged_int8_attend(q, pool, layer, position, block_tables, active,
+                       cfg: ModelConfig, cache_len: int, window=None):
+    """Decode attention over the paged int8 pool, this step's token
+    already written; pool: (k codes, k scales, v codes, v scales) of
+    every layer.
+
+    On a TPU the Pallas kernel reads each active lane's live blocks in
+    place (``kernels.decode_attn``); elsewhere, and under tensor
+    parallelism, each lane's whole table is gathered, dequantized and
+    attended by :func:`_decode_attend`, the oracle the kernel is tested
+    against.  ``lax.platform_dependent`` picks the branch when the step
+    is lowered, so the compiled program holds only one of them."""
+    def gathered(q, kc, ksc, vc, vsc):
+        # one gather indexed by (layer, table): pool[layer][table] would
+        # slice the whole layer out of the pool first
+        view = lambda arr: paged_view(arr[layer, block_tables], cfg)
+        kf = view(kc).astype(jnp.float32) * view(ksc)
+        vf = view(vc).astype(jnp.float32) * view(vsc)
+        return _decode_attend(q, kf, vf, position, cfg, cache_len, window)
+
+    if tp_active():
+        return gathered(q, *pool)
+
+    def in_place(q, kc, ksc, vc, vsc):
+        w = window
+        if w is None:
+            w = cfg.window if cfg.window is not None else cache_len
+        lanes = position if active is None else jnp.where(active, position,
+                                                          -1)
+        out = paged_decode_attention(q[:, 0], kc, ksc, vc, vsc, layer,
+                                     lanes, block_tables, w)
+        return out[:, None].astype(q.dtype)
+
+    return jax.lax.platform_dependent(q, *pool, tpu=in_place,
+                                      default=gathered)
+
+
 def _ring_write(cache, val, slot):
     """Scatter one token into the ring cache (in-place under donation).
 
@@ -293,7 +335,7 @@ def _ring_write(cache, val, slot):
         indices_are_sorted=False)
 
 
-def paged_write(pool, lead, phys, off, val):
+def paged_write(pool, lead, phys, off, val, one_per_block: bool = False):
     """Scatter token rows into a lane-dense paged pool.
 
     pool: KV codes ``[.., NB, BS, KV*D]`` or their scales ``[.., NB,
@@ -304,12 +346,24 @@ def paged_write(pool, lead, phys, off, val):
     ``unique_indices``: retired lanes share the trash block, so duplicate
     destinations are expected — their values are dead either way (the
     engine never reads the trash block through a live table).
+
+    ``one_per_block`` (decode: no live block takes two tokens in one
+    call): scales are written as whole block rows, each read, given its
+    token's ``KV`` columns and written back.  An element scatter into the
+    float32 scale pool leads the TPU compiler to stage the whole pool
+    through on-chip memory around it, on every layer, once attention
+    leaves that memory free.
     """
     kv = val.shape[-2]
     if pool.ndim - len(lead) == 3:                   # codes
         rows = val.reshape(val.shape[:-2] + pool.shape[-1:])
         return pool.at[lead + (phys, off)].set(rows.astype(pool.dtype))
     bs = pool.shape[-1] // kv                        # scales
+    if one_per_block:
+        rows = pool[lead + (phys,)]                  # [.., N, KV*BS]
+        mine = jnp.arange(pool.shape[-1]) % bs == off[:, None]
+        new = jnp.repeat(val[..., 0], bs, axis=-1).astype(pool.dtype)
+        return pool.at[lead + (phys,)].set(jnp.where(mine, new, rows))
     cols = off[:, None] + bs * jnp.arange(kv)
     return pool.at[lead + (phys[:, None], cols)].set(
         val[..., 0].astype(pool.dtype))

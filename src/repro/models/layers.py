@@ -3,7 +3,11 @@
 Pure-functional JAX (params are nested dicts).  Attention in the training /
 prefill path is a chunked online-softmax ("flash") implementation in plain
 jnp — bounded live memory under remat, the structure a TPU splash kernel
-would have; the decode path uses repro.kernels.decode_attn semantics.
+would have.  Decode attention lives in ``repro.models.blocks``: over the
+paged int8 pool on a TPU, the Pallas kernel of
+``repro.kernels.decode_attn`` reads each lane's live blocks in place;
+every other decode path (the CPU, the ring cache, float32 pools, tensor
+parallelism, speculative verify) gathers the cache and attends in jnp.
 """
 from __future__ import annotations
 
@@ -124,6 +128,18 @@ def layer_attention(cfg: ModelConfig, layer):
     k = jnp.asarray(kinds)[layer % len(kinds)]
     return (jnp.asarray(windows)[k],
             (jnp.asarray(inv)[k], jnp.asarray(scale)[k]))
+
+
+def layer_windows(cfg: ModelConfig, cache_len: int) -> np.ndarray:
+    """Each layer's attention window at decode, [n_layers] int, on the
+    host: what :func:`layer_attention` gives a model whose layers differ
+    in kind, else ``cfg.window`` or, without one, ``cache_len``."""
+    if cfg.layer_types is None:
+        w = cfg.window if cfg.window is not None else cache_len
+        return np.full((cfg.n_layers,), w, np.int64)
+    windows, _, _, kinds = _kind_tables(cfg)
+    return windows[kinds[np.arange(cfg.n_layers) % len(kinds)]].astype(
+        np.int64)
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, cfg: ModelConfig,

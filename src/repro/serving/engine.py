@@ -82,8 +82,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.scheduler import DECODE, IterationScheduler, Request
+from repro.kernels.decode_attn.kernel import live_blocks
 from repro.models import lm
 from repro.models.common import ModelConfig
+from repro.models.layers import layer_windows
 from repro.models.sail_linear import QuantPolicy, quantize_params
 from repro.serving.trace import SpanTable
 
@@ -365,6 +367,11 @@ class Engine:
             dict.fromkeys(("layer_steps", "routed_pairs", "experts_touched",
                            "imbalance_sum"), 0)
             if cfg.family == "moe" else None)
+        # decode attention over the paged pool (stats()["decode_attn"]):
+        # steps that ran the Pallas kernel, the live blocks the steps
+        # attended (what the kernel reads), summed over lanes and layers,
+        # and the table blocks a gather of every lane's table reads
+        self.attn_counts: Optional[Dict[str, int]] = None
         # a lane keeps its window where every layer is windowed; a model
         # with full-attention layers keeps the whole context
         clen = ecfg.cache_len if cfg.lane_window is None \
@@ -405,12 +412,22 @@ class Engine:
                 self._tables_np = np.full(
                     (ecfg.batch_size, self._mbs), self._trash, np.int32)
                 self._len_np = np.zeros((ecfg.batch_size,), np.int64)
+                self.attn_counts = dict.fromkeys(
+                    ("kernel_steps", "blocks_read", "blocks_in_tables"), 0)
+                self._windows = layer_windows(cfg, self._mbs * bs)
             else:
                 self.cache = lm.init_cache(self.params, cfg,
                                            ecfg.batch_size, clen,
                                            self._quant_kv)
             if self.tp_serving is not None:
                 self.cache = self.tp_serving.shard_cache(self.cache)
+            # ``blocks._paged_int8_attend``'s dispatch: the kernel serves
+            # an int8 pool on a TPU outside tensor parallelism
+            pool = jax.tree_util.tree_leaves(self.cache["layers"])[0]
+            self._attn_kernel = (self.paged and self._quant_kv
+                                 and self.tp_serving is None
+                                 and next(iter(pool.devices())).platform
+                                 == "tpu")
         # self-speculative decoding: the plan's draft= sub-spec requants
         # the SAME raw tree aggressively; the draft tree stays resident
         # alongside the conservative one for the engine's lifetime
@@ -640,6 +657,7 @@ class Engine:
             if exp is not None:
                 self.modeled_seconds += exp
             if self.paged:
+                self._count_attention(mask)
                 self._len_np[mask] += 1
             for req in active:
                 self._cur[req.slot] = nxt[req.slot]
@@ -1338,6 +1356,17 @@ class Engine:
                 return np.asarray(toks)
             return tuple(np.asarray(a) for a in jax.device_get((toks, also)))
 
+    def _count_attention(self, mask: np.ndarray) -> None:
+        """Add one paged decode step: the live blocks its ``mask`` lanes
+        attend in every layer, and the table blocks a gather reads."""
+        c = self.attn_counts
+        _, live = live_blocks(self._len_np[mask][:, None],
+                              self._windows[None, :],
+                              self.ecfg.kv_block_size, xp=np)
+        c["kernel_steps"] += int(self._attn_kernel)
+        c["blocks_read"] += int(live.sum())
+        c["blocks_in_tables"] += mask.size * self._mbs * self._windows.size
+
     def _count_experts(self, tokens: np.ndarray) -> None:
         """Add one decode step's tokens per expert ``[layers, E]``."""
         t = tokens.astype(np.int64)
@@ -1402,6 +1431,10 @@ class Engine:
                        else None),
                 "block_pool": (self.block_mgr.stats()
                                if self.paged else None),
+                # decode attention over the paged pool (None without
+                # one): kernel_steps, blocks_read, blocks_in_tables
+                "decode_attn": (dict(self.attn_counts)
+                                if self.attn_counts is not None else None),
                 # routed experts over the decode steps (None without
                 # experts): layer_steps, routed_pairs, experts_touched,
                 # imbalance_sum

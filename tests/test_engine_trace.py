@@ -196,3 +196,47 @@ def test_named_scopes_leave_the_compiled_decode_step_unchanged(
     assert "sail_mm" in scoped and "/attn/" in scoped
     assert "sail_mm" not in plain and "/attn/" not in plain
     assert _op_count(scoped) == _op_count(plain) > 100
+
+
+def _live_blocks_by_hand(requests, windows, block):
+    """Blocks a decode step attends, summed over steps, lanes and layers:
+    a request of prompt ``n`` and ``new`` tokens decodes at positions
+    ``n .. n + new - 2`` (prefill gives its first token), and at
+    position ``p`` a layer of window ``w`` reads blocks ``max(0, p - w +
+    1) // block`` through ``p // block``."""
+    return sum(p // block - max(0, p - w + 1) // block + 1
+               for n, new in requests for p in range(n, n + new - 1)
+               for w in windows)
+
+
+@pytest.mark.parametrize("arch,windows", [
+    ("tinymistral_248m", None),
+    ("mellum2_12b_a2_5b", (8, 8, 8, 1 << 30)),
+], ids=["no_window", "sliding_and_full"])
+def test_decode_attn_counts_live_blocks(arch, windows):
+    """``stats()["decode_attn"]`` on the CPU: no step runs the kernel,
+    ``blocks_read`` is the hand count of the lanes' live blocks (a model
+    without a window reads every block up to its position; Mellum2's
+    sliding layers only the last 8 positions'), and ``blocks_in_tables``
+    is every lane's whole table in every layer of every step."""
+    import dataclasses
+    cfg = C.get_smoke(arch)
+    if windows is None:
+        cfg = dataclasses.replace(cfg, window=None)
+    params = lm.init_params(jax.random.PRNGKey(0), cfg)
+    block, batch = 4, 2
+    eng = Engine(params, cfg, EngineConfig(
+        batch_size=batch, cache_len=48, kv_block_size=block, quantize=True,
+        group_size=32, plan="uniform:4a8", eos_token=-1))
+    requests = ((13, 9), (21, 14), (6, 5))
+    for n, new in requests:
+        eng.submit([(7 * i + n) % cfg.vocab for i in range(n)], new)
+    eng.run()
+    got = eng.stats()["decode_attn"]
+    windows = windows or (48,) * cfg.n_layers
+    assert got["kernel_steps"] == 0
+    assert got["blocks_read"] == _live_blocks_by_hand(requests, windows,
+                                                      block) > 0
+    assert got["blocks_in_tables"] == (eng.decode_iterations * batch
+                                       * (48 // block) * cfg.n_layers)
+    assert got["blocks_read"] < got["blocks_in_tables"]

@@ -7,8 +7,9 @@ would: unsupported lowerings and scoped-VMEM overruns fail here, at no
 chip time.  Shapes are ``tinymistral_248m``'s quantized matrices at decode
 (M=8) and prefill (M=512) batch, with the blocks ``pick_blocks`` chooses.
 The decode step is compiled at the widths the engine serves it with in the
-``tinymistral.decode.q4a8`` benchmark cell, and its optimized HLO is held
-to updating the paged KV pool in place.
+``tinymistral.decode.q4a8`` and ``mellum2.code.q4a8`` benchmark cells, and
+its optimized HLO is held to updating the paged KV pool in place and to
+reading it in place through the decode attention kernel.
 """
 import functools
 import os
@@ -120,6 +121,19 @@ def pool_moves(hlo: str, num_blocks: int) -> list:
             and axis.search(m.group(1))]
 
 
+def attention_reads_pool_in_place(hlo: str, batch: int, cols: int,
+                                   block: int, row: int) -> bool:
+    """The decode attention kernel is on the path, in the ``attn`` scope
+    (``op_name`` metadata the trace's scope reader attributes by), and no
+    gathered ``[batch, cols, block, row]`` view of the pool is left."""
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               and "paged_decode_attention" in line]
+    gathered = re.compile(rf"\[{batch},{cols},{block},{row}\]")
+    return (len(kernels) == 1 and "/attn/" in kernels[0]
+            and not gathered.search(hlo))
+
+
 def pool_bytes(cache) -> int:
     return sum(a.size * np.dtype(a.dtype).itemsize
                for a in jax.tree_util.tree_leaves(cache["layers"]))
@@ -156,9 +170,12 @@ def test_engine_decode_step_updates_pool_in_place_for_v5e(one_chip):
     params, cache = jax.tree_util.tree_map(place, (params, cache))
     compiled = _lower_decode(lm.donating(lm.decode_step), params, cache,
                              cfg, batch, cols, sharding=one_chip).compile()
-    assert pool_moves(compiled.as_text(), num_blocks) == []
+    hlo = compiled.as_text()
+    assert pool_moves(hlo, num_blocks) == []
     assert (compiled.memory_analysis().alias_size_in_bytes
             >= pool_bytes(cache) > 0.6e9)
+    assert attention_reads_pool_in_place(hlo, batch, cols, block,
+                                         cfg.n_kv * cfg.head_dim)
 
 
 @pytest.mark.parametrize("donate", [True, False], ids=["donating", "plain"])
@@ -224,6 +241,31 @@ def test_mellum_decode_step_compiles_for_v5e(one_chip):
     assert "ragged" in hlo
     assert not [line for line in pool_moves(hlo, num_blocks)
                 if "s8[" in line]
+    assert attention_reads_pool_in_place(hlo, batch, cols, block,
+                                         cfg.n_kv * cfg.head_dim)
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pool_bytes(cache) > 1.4e9
     assert mem.temp_size_in_bytes < 4e9
+
+
+@pytest.mark.parametrize("shape", [(12, 32, 8, 32, 192), (8, 32, 4, 128, 352)],
+                         ids=["tinymistral", "mellum2"])
+def test_paged_decode_attn_compiles_for_v5e(one_chip, shape):
+    """The paged decode attention kernel alone at each cell's widths
+    (layers, heads, KV heads, head size, table columns; batch 32, block
+    16, the trash block in the pool) compiles.  (Alone, XLA may stage the
+    small scale pools in on-chip memory; the decode step tests above hold
+    the pool in place where the engine runs the kernel.)"""
+    from repro.kernels.decode_attn.kernel import paged_decode_attention
+    layers, h, kv, dh, cols = shape
+    batch, block = 32, 16
+    num_blocks = batch * cols + 1
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    codes = sds((layers, num_blocks, block, kv * dh), jnp.int8)
+    scales = sds((layers, num_blocks, kv * block), jnp.float32)
+    compiled = jax.jit(paged_decode_attention).lower(
+        sds((batch, h, dh), jnp.float32), codes, scales, codes, scales,
+        sds((), jnp.int32), sds((batch,), jnp.int32),
+        sds((batch, cols), jnp.int32), sds((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
