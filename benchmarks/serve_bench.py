@@ -1,12 +1,8 @@
-"""Serving benchmark: A/B scheduling comparison + trace-driven replay.
+"""Serving benchmark gates: trace-driven replay and engine A/B checks.
 
-Two entry points over the same trace machinery
-(``repro.serving.workload``):
+Each gate replays a trace (``repro.serving.workload``) through
+``repro.serving.engine.Engine``; one gate flag is required:
 
-  * default — replay one workload through both scheduling modes of
-    ``repro.serving.engine.Engine`` (continuous batching vs
-    run-to-completion) and report tokens/s, model iterations, mean/p99
-    request latency, and mean time-to-first-token;
   * ``--replay`` — replay the identical trace under several precision
     plans (``--plan`` is repeatable, ``--slo-solve`` appends an
     SLO-solved plan) and emit a modeled-vs-measured tokens/s error
@@ -19,7 +15,9 @@ Two entry points over the same trace machinery
     the paged block pool on a prefix-heavy trace (``--prefix-len``):
     the paged engine must sustain strictly higher peak concurrency with
     per-request token-identical completions — the CI gate for the
-    block-pool refactor.
+    block-pool refactor;
+  * ``--speculative`` — the baseline plan vs the same plan with a
+    ``draft=`` clause: token-identical output, faster decode.
 
 Arrivals are simulated at iteration granularity: request i is submitted
 once the engine has run ``arrival_iteration`` iterations (wall-clock
@@ -27,9 +25,7 @@ free, so a trace replays deterministically on any host).  Traces are
 seeded and JSON-serializable: ``--save-trace`` writes one, ``--trace``
 replays a saved file bit-identically.
 
-Run:  PYTHONPATH=src python benchmarks/serve_bench.py --requests 12 \
-          --max-new 24 --arrival-gap 3 --arrival poisson --seed 7
-      PYTHONPATH=src python benchmarks/serve_bench.py --replay \
+Run:  PYTHONPATH=src python benchmarks/serve_bench.py --replay \
           --arrival bursty --plan uniform:4 --slo-solve 1.2 \
           --controller --slo-frac 1.5 --json report.json
 """
@@ -209,7 +205,6 @@ def _replay(args, params, cfg, trace: Trace) -> Dict[str, Any]:
             ql=args.ql,
             group_size=32,
             quant_kv=True,
-            mode="continuous",
             plan=p["spec"],
             slo=slo,
             controller=args.controller or None,
@@ -328,7 +323,6 @@ def _paged_gate(args, params, cfg, trace: Trace) -> Dict[str, Any]:
         ql=args.ql,
         group_size=32,
         quant_kv=True,
-        mode="continuous",
         prefill_budget=args.prefill_budget,
     )
     slot = run_trace(
@@ -442,7 +436,6 @@ def _speculative_gate(args, params, cfg, trace: Trace) -> Dict[str, Any]:
         group_size=32,
         min_size=1024,
         quant_kv=False,
-        mode="continuous",
         prefill_budget=args.prefill_budget,
     )
 
@@ -532,7 +525,6 @@ def _tp_gate(args, params, cfg, trace: Trace) -> Dict[str, Any]:
         ql=args.ql,
         group_size=32,
         quant_kv=True,
-        mode="continuous",
         plan=label,
         prefill_budget=args.prefill_budget,
         kv_block_size=args.block_size if args.paged else None,
@@ -656,7 +648,7 @@ def main():
         action="append",
         default=None,
         help="precision plan (grammar string or plan.json path); "
-        "repeatable in --replay mode, single-valued otherwise",
+        "repeatable in --replay mode, the first one serves elsewhere",
     )
     ap.add_argument("--json", default=None, help="write the stats/report JSON here")
     # replay mode
@@ -787,6 +779,8 @@ def main():
         help="speculative gate: minimum measured decode tokens/s ratio",
     )
     args = ap.parse_args()
+    if not (args.replay or args.paged_gate or args.speculative):
+        ap.error("choose a gate: --replay, --paged-gate or --speculative")
 
     cfg = C.get_smoke(args.arch)
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
@@ -864,63 +858,6 @@ def main():
                 json.dump(report, f, indent=2)
             print(f"wrote {args.json}")
         _gate(args, report)
-        return
-
-    # --- default: continuous vs run-to-completion A/B ---------------------
-    plan = None
-    if args.plan:
-        if len(args.plan) > 1:
-            raise SystemExit("multiple --plan values need --replay")
-        # resolve once: an auto plan re-solved per mode would run the
-        # whole sensitivity calibration twice for the identical answer
-        from repro import planning
-        from repro.models.sail_linear import QuantPolicy
-
-        plan = planning.plan_from_arg(args.plan[0])
-        if not plan.solved:
-            plan = planning.resolve_plan(
-                plan, params, cfg, base=QuantPolicy(bits=args.ql, group_size=32, min_size=1024)
-            ).spec
-    results = {}
-    for mode in ("batch", "continuous"):
-        ecfg = EngineConfig(
-            batch_size=args.batch,
-            cache_len=args.cache_len,
-            quantize=True,
-            ql=args.ql,
-            group_size=32,
-            quant_kv=True,
-            mode=mode,
-            plan=plan,
-            prefill_budget=args.prefill_budget,
-        )
-        results[mode] = run_trace(params, cfg, ecfg, trace)
-        results[mode].pop("completion_tokens")
-
-    hdr = f"{'mode':<12} {'iters':>6} {'tok/s':>8} {'mean lat':>9} {'p99 lat':>9} {'TTFT':>7}"
-    print(hdr)
-    print("-" * len(hdr))
-    for mode, st in results.items():
-        print(
-            f"{mode:<12} {st['iterations']:>6} {st['tok_per_s']:>8.2f} "
-            f"{st['mean_latency_s']:>8.2f}s {st['p99_latency_s']:>8.2f}s "
-            f"{st['mean_ttft_s']:>6.2f}s"
-        )
-    b, c = results["batch"], results["continuous"]
-    assert c["generated_tokens"] == b["generated_tokens"] and c["requests"] == b["requests"], (
-        "modes served different workloads"
-    )
-    print(
-        f"continuous vs run-to-completion: "
-        f"{b['iterations']}/{c['iterations']} = "
-        f"{b['iterations'] / c['iterations']:.2f}x fewer model iterations, "
-        f"{c['tok_per_s'] / max(b['tok_per_s'], 1e-9):.2f}x tokens/s"
-    )
-    print(f"plan: {c['plan_hash']} ({c['plan_mode']})")
-    if args.json:
-        with open(args.json, "w") as f:
-            json.dump(results, f, indent=2)
-        print(f"wrote {args.json}")
 
 
 if __name__ == "__main__":

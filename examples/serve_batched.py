@@ -34,8 +34,6 @@ def main():
                          "(see repro.planning)")
     ap.add_argument("--full", action="store_true",
                     help="use the full config instead of smoke (slow)")
-    ap.add_argument("--mode", choices=("continuous", "batch"),
-                    default="continuous")
     args = ap.parse_args()
 
     cfg = C.get_config(args.arch) if args.full else C.get_smoke(args.arch)
@@ -46,7 +44,7 @@ def main():
     plan = plan_from_arg(args.plan) if args.plan is not None else None
     engine = Engine(params, cfg, EngineConfig(
         batch_size=args.batch, cache_len=256, quantize=True, ql=args.ql,
-        group_size=32, quant_kv=True, mode=args.mode, plan=plan))
+        group_size=32, quant_kv=True, plan=plan))
     wdesc = (f"mixed ({args.plan})"
              if engine.stats()["mixed_precision"] else f"Q{args.ql}")
     print(f"serving {cfg.name}: weights {wdesc}, "

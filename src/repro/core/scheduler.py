@@ -167,11 +167,7 @@ class IterationScheduler:
     to the free list at iteration granularity — a request arriving
     mid-decode joins the very next iteration instead of waiting for the
     cohort to drain.
-
-    ``admit()``/``step_complete()`` remain as the coarse batch-mode
-    interface (run-to-completion serving, kept for A/B comparison).
     """
-    target_batch: int = 8
     max_batch: int = 32
     prefill_budget: Optional[int] = None   # new prefill tokens / iteration
     waiting: List[Request] = dataclasses.field(default_factory=list)
@@ -182,8 +178,6 @@ class IterationScheduler:
 
     def submit(self, req: Request) -> None:
         self.waiting.append(req)
-
-    # --- continuous (slot) interface ------------------------------------
 
     def _ensure_slots(self) -> None:
         if not self._slots_init:
@@ -263,29 +257,6 @@ class IterationScheduler:
                 self.finished.append(r)
                 return r
         raise KeyError(f"uid {uid} not running")
-
-    # --- batch-mode (run-to-completion) interface ------------------------
-
-    def admit(self) -> List[Request]:
-        """Fill the running batch up to target from the FIFO queue."""
-        while self.waiting and len(self.running) < self.target_batch:
-            req = self.waiting.pop(0)
-            req.state = DECODE
-            self.running.append(req)
-        return list(self.running)
-
-    def step_complete(self, finished_uids: Sequence[int]) -> None:
-        done = set(finished_uids)
-        still = []
-        for r in self.running:
-            r.generated += 1
-            if r.uid in done or r.generated >= r.max_new_tokens:
-                r.done = True
-                r.state = DONE
-                self.finished.append(r)
-            else:
-                still.append(r)
-        self.running = still
 
     @property
     def active(self) -> int:

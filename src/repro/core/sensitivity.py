@@ -31,12 +31,6 @@ serving feature:
     ``abits_candidates`` it allocates ``(wbits, abits)`` JOINTLY under a
     projected-cycles budget (``allocate_bits_joint``), accepts held-out
     ``calib_batches``, and caps scan segmentation via ``max_segments``.
-  * ``parse_bit_policy`` / ``resolve_bit_policy`` — DEPRECATED shims
-    over ``repro.planning``: the serving-facing surface is now a typed
-    ``PlanSpec`` (``EngineConfig.plan``, ``--plan``), and the legacy
-    string grammar (``"uniform:<b>[a<ab>]"``, ``"rules:..."``,
-    ``"auto:q<b>[a<ab>][,prt=...][,maxseg=<n>][,slo=<tps>]"``,
-    ``"auto:<f>bpw"``) enters only via ``PlanSpec.parse``.
 
 Invariants the probes guarantee: every score is measured against an
 exact center (f32 reference logits from the SAME jitted forward), probes
@@ -1123,94 +1117,5 @@ def calibrate_policy(params, cfg, policy=None, budget_bytes=None,
     return dataclasses.replace(policy, allocation=allocation), report
 
 
-# ---------------------------------------------------------------------------
-# serving-facing spec surface (deprecated shims over repro.planning)
-# ---------------------------------------------------------------------------
-
 # public alias: the planner emits solved PlanSpecs from solver reports
 spec_map_from_units = _spec_map_from_units
-
-
-def parse_bit_policy(spec: str) -> Dict[str, Any]:
-    """DEPRECATED: use ``repro.planning.PlanSpec.parse``.
-
-    Kept as a thin shim for callers of the legacy string grammar
-    (``uniform:<b>[a<ab>]``, ``rules:<regex>=<b>[a<ab>],...``,
-    ``auto:q<b>[a<ab>][,prt=...][,maxseg=...]``, ``auto:<f>bpw``) —
-    parsing now happens in ``PlanSpec.parse`` and this function merely
-    re-emits its legacy dict form.
-    """
-    import warnings
-
-    from repro.planning import PlanSpec
-    warnings.warn(
-        "parse_bit_policy is deprecated; use repro.planning."
-        "PlanSpec.parse (the dict form it returns is the legacy "
-        "EngineConfig.bit_policy surface)", DeprecationWarning,
-        stacklevel=2)
-    return PlanSpec.parse(spec).to_legacy_dict()
-
-
-def resolve_bit_policy(bit_policy, params, cfg, base):
-    """DEPRECATED: use ``repro.planning.resolve_plan``.
-
-    EngineConfig.bit_policy (None | str | dict | QuantPolicy) -> the
-    QuantPolicy to quantize with.  ``base`` carries the engine's
-    group_size/min_size/default bits; auto mode runs the calibration.
-    Strings and legacy mode-dicts route through ``PlanSpec``; explicit
-    QuantPolicy objects and raw ``QuantPolicy.from_spec`` dicts resolve
-    as before.
-    """
-    import warnings
-
-    warnings.warn(
-        "resolve_bit_policy is deprecated; use repro.planning."
-        "resolve_plan (EngineConfig.plan)", DeprecationWarning,
-        stacklevel=2)
-    return _resolve_policy_like(bit_policy, params, cfg, base)
-
-
-def _resolve_policy_like(bit_policy, params, cfg, base):
-    """Shared resolution for the legacy ``bit_policy`` surface (no
-    deprecation warning — ``Engine`` calls this for compat configs after
-    warning once itself)."""
-    from repro import planning
-    from repro.models.sail_linear import QuantPolicy
-    if bit_policy is None:
-        return base
-    if isinstance(bit_policy, QuantPolicy):
-        return bit_policy
-    if isinstance(bit_policy, str):
-        return planning.resolve_plan(
-            planning.PlanSpec.parse(bit_policy), params, cfg,
-            base=base).policy
-    if not isinstance(bit_policy, Mapping):
-        raise TypeError(f"bit_policy must be None/str/dict/QuantPolicy, "
-                        f"got {type(bit_policy)!r}")
-    mode = bit_policy.get("mode", "spec")
-    if mode in ("uniform", "rules", "auto"):
-        try:
-            plan = planning.PlanSpec.from_legacy_dict(bit_policy)
-        except ValueError:
-            if mode != "auto":
-                raise
-            # full backward compat: auto dicts could carry arbitrary
-            # calibrate_policy kwargs (calib_batch, budget_bytes, ...)
-            # that have no PlanSpec field — forward them like the old
-            # resolve_bit_policy did
-            spec = dict(bit_policy)
-            spec.pop("mode")
-            abits = spec.pop("abits", None)
-            if abits is not None:
-                spec.setdefault("abits_candidates", SUPPORTED_ABITS)
-                spec.setdefault("match_uniform_abits", int(abits))
-            policy, _ = calibrate_policy(params, cfg, base, **spec)
-            return policy
-        return planning.resolve_plan(plan, params, cfg, base=base).policy
-    if mode == "spec":
-        spec = {k: v for k, v in bit_policy.items() if k != "mode"}
-        return QuantPolicy.from_spec({
-            "bits": base.bits, "group_size": base.group_size,
-            "min_size": base.min_size, "skip_embed": base.skip_embed,
-            **spec})
-    raise ValueError(f"unknown bit_policy mode {mode!r}")

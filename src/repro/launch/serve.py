@@ -6,8 +6,7 @@
 Quantizes weights to ``--ql`` bits (QTensor storage), int8 KV cache,
 continuous batching over a fixed pool of ``--batch`` KV-cache slots (one
 model iteration serves every active user — the paper's tensor-level
-scheduling).  ``--mode batch`` selects the old run-to-completion loop
-for A/B comparison.
+scheduling).
 
 Precision planning (``repro.planning``):
 
@@ -17,9 +16,6 @@ Precision planning (``repro.planning``):
 
     # SLO-driven: derive the cycle+DRAM budgets from a target tokens/s
     ... --slo 80 --tap 512        # tap live traffic for later replans
-
-``--bit-policy`` remains as a deprecated alias routed through
-``PlanSpec.parse``.
 """
 from __future__ import annotations
 
@@ -103,9 +99,6 @@ def main() -> None:
     ap.add_argument("--check-every", type=int, default=None,
                     help="controller: decode iterations between drift "
                          "checks (default 8)")
-    ap.add_argument("--bit-policy", default=None,
-                    help="DEPRECATED alias for --plan (grammar strings "
-                         "only)")
     ap.add_argument("--tp", type=int, default=1,
                     help="tensor-parallel shard count: shard the "
                          "quantized weight tree over a (1, M) mesh "
@@ -115,8 +108,6 @@ def main() -> None:
     ap.add_argument("--wire", type=int, default=32, choices=(8, 32),
                     help="TP all-reduce precision: 32 exact, 8 "
                          "compressed int8+scale partial sums")
-    ap.add_argument("--mode", choices=("continuous", "batch"),
-                    default="continuous")
     ap.add_argument("--prefill-budget", type=int, default=None,
                     help="max new prefill tokens admitted per iteration")
     ap.add_argument("--stream", action="store_true",
@@ -145,10 +136,10 @@ def main() -> None:
         ql=args.ql,
         group_size=(args.group_size if args.group_size is not None
                     else min(128, cfg.d_model)),
-        quant_kv=not args.no_quant_kv, mode=args.mode,
+        quant_kv=not args.no_quant_kv,
         plan=plan, slo=args.slo, tap_capacity=args.tap,
-        controller=controller, bit_policy=args.bit_policy,
-        prefill_budget=args.prefill_budget, tp=args.tp, wire=args.wire))
+        controller=controller, prefill_budget=args.prefill_budget,
+        tp=args.tp, wire=args.wire))
     st = eng.stats()
     quant_desc = (f"mixed-precision plan {st['plan_hash']}"
                   if st["mixed_precision"]
@@ -160,7 +151,7 @@ def main() -> None:
     print(f"{cfg.name}: {quant_desc} weights "
           f"({eng.compression:.2f}x compression), "
           f"{'int8' if not args.no_quant_kv else 'f32'} KV, "
-          f"{args.mode} scheduling{tp_desc}")
+          f"continuous scheduling{tp_desc}")
     if args.save_plan and eng.plan is not None:
         eng.plan.save(args.save_plan)
         print(f"wrote plan {eng.plan.spec_hash} to {args.save_plan}")
@@ -197,7 +188,7 @@ def main() -> None:
               f"{c['checks']} drift checks, "
               f"shed {c['shed']} / shrink {c['shrink']} / "
               f"replan {c['replan']} / resolve {c['resolve']}")
-    if args.tap and eng.tap is not None:   # taps attach in continuous mode
+    if args.tap:
         print(f"tap: {st['tapped_rows']} activation rows captured across "
               f"{eng.tap.n_layers} layers (Engine.replan() recalibrates "
               f"measured PRT discounts from them)")

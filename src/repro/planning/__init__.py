@@ -83,8 +83,7 @@ def plan_from_arg(value: Any) -> PlanSpec:
 
 def as_plan(obj: Any) -> PlanSpec:
     """Coerce any accepted plan form to a PlanSpec: an existing PlanSpec,
-    a grammar string (the only place the legacy grammar enters), or a
-    JSON/legacy dict."""
+    a grammar string, or a plan JSON dict."""
     if isinstance(obj, PlanSpec):
         return obj
     if isinstance(obj, str):

@@ -1,10 +1,8 @@
 """PlanSpec: the typed source of truth for precision planning.
 
-A serving deployment used to describe its precision configuration through
-a string grammar (``--bit-policy "auto:q4a8,prt=measured,maxseg=4"``)
-whose parsed dict was threaded differently through the engine, CLI,
-benchmarks, and checkpoint manifests.  ``PlanSpec`` replaces that plumbing
-with one frozen, JSON-serializable object:
+A serving deployment describes its precision configuration with one
+frozen, JSON-serializable object, shared by the engine, CLI, benchmarks
+and checkpoint manifests:
 
   * the *request*: mode (uniform / rules / auto), the uniform ``ql`` and
     activation precision, regex rules, the auto-mode budget anchor
@@ -15,9 +13,8 @@ with one frozen, JSON-serializable object:
     ``QuantPolicy`` (and therefore the exact mixed parameter tree)
     without re-running calibration.
 
-The legacy string grammar survives as a thin :meth:`PlanSpec.parse` /
-:meth:`PlanSpec.format` layer; ``repro.core.sensitivity.parse_bit_policy``
-is now a deprecated shim over it.
+The string grammar (``EngineConfig.plan``, ``--plan``) is a thin
+:meth:`PlanSpec.parse` / :meth:`PlanSpec.format` layer over it.
 """
 
 from __future__ import annotations
@@ -152,8 +149,8 @@ def _coerce_draft(val) -> Optional[Union[str, "DraftSpec"]]:
 class PlanRule:
     """One regex precision override: paths matching ``pattern`` serve at
     ``weight_bits`` (and ``act_bits`` activations when given).  A None
-    ``weight_bits`` pins only the activation side (legacy independent
-    ``act_rules`` entries); at least one side must be set."""
+    ``weight_bits`` pins only the activation side (an activation-only
+    override); at least one side must be set."""
 
     pattern: str
     weight_bits: Optional[int]
@@ -301,11 +298,11 @@ class PlanSpec:
         blob = json.dumps(self.to_json(), sort_keys=True, separators=(",", ":"))
         return hashlib.sha1(blob.encode()).hexdigest()[:12]
 
-    # -- string grammar (backward compat) ---------------------------------
+    # -- string grammar ----------------------------------------------------
 
     @staticmethod
     def parse(spec: str) -> "PlanSpec":
-        """Parse the legacy ``--bit-policy`` grammar into a PlanSpec.
+        """Parse the plan grammar into a PlanSpec.
 
           uniform:<b>[a<ab>][,kv=...][,draft=...][,tp=...][,wire=...]
                                               one precision everywhere
@@ -495,9 +492,6 @@ class PlanSpec:
 
     @staticmethod
     def from_json(spec: Mapping[str, Any]) -> "PlanSpec":
-        if "weight_bits" not in spec and "mode" in spec:
-            # legacy parse_bit_policy dict (pre-PlanSpec engine configs)
-            return PlanSpec.from_legacy_dict(spec)
         version = int(spec.get("version", PLAN_VERSION))
         if version > PLAN_VERSION:
             raise ValueError(f"plan version {version} is newer than {PLAN_VERSION}")
@@ -547,106 +541,6 @@ class PlanSpec:
     def load(path: str) -> "PlanSpec":
         with open(path) as f:
             return PlanSpec.from_json(json.load(f))
-
-    # -- legacy dict bridge (parse_bit_policy's output format) ------------
-
-    def to_legacy_dict(self) -> Dict[str, Any]:
-        """The exact dict :func:`repro.core.sensitivity.parse_bit_policy`
-        used to return — the deprecated shim's return value."""
-        if self.mode == "uniform":
-            out: Dict[str, Any] = {"mode": "uniform", "bits": int(self.weight_bits)}
-            if self.act_bits is not None:
-                out["abits"] = int(self.act_bits)
-            return out
-        if self.mode == "rules":
-            out = {
-                "mode": "rules",
-                "rules": [
-                    (r.pattern, int(r.weight_bits))
-                    for r in self.rules
-                    if r.weight_bits is not None
-                ],
-            }
-            act_rules = [(r.pattern, int(r.act_bits)) for r in self.rules if r.act_bits is not None]
-            if act_rules:
-                out["act_rules"] = act_rules
-            if self.weight_bits is not None:
-                out["bits"] = int(self.weight_bits)
-            if self.act_bits is not None:
-                out["abits"] = int(self.act_bits)
-            return out
-        out = {"mode": "auto"}
-        if self.budget_bpw is not None:
-            out["budget_bpw"] = float(self.budget_bpw)
-        else:
-            out["match_uniform"] = int(self.weight_bits)
-        if self.act_bits is not None:
-            out["abits"] = int(self.act_bits)
-        if self.prt != "paper":
-            out["prt"] = self.prt
-        if self.max_segments is not None:
-            out["max_segments"] = int(self.max_segments)
-        if self.target_tps is not None:
-            out["target_tps"] = float(self.target_tps)
-        return out
-
-    @staticmethod
-    def from_legacy_dict(spec: Mapping[str, Any]) -> "PlanSpec":
-        spec = dict(spec)
-        mode = spec.pop("mode", None)
-        known = {
-            "bits",
-            "abits",
-            "rules",
-            "act_rules",
-            "match_uniform",
-            "budget_bpw",
-            "prt",
-            "max_segments",
-            "target_tps",
-        }
-        extra = set(spec) - known
-        if extra:
-            raise ValueError(
-                f"unsupported legacy bit_policy keys {sorted(extra)} — these "
-                "solver options moved to repro.planning.Planner / "
-                "repro.core.sensitivity.calibrate_policy"
-            )
-        if mode == "uniform":
-            return PlanSpec(
-                mode="uniform",
-                weight_bits=int(spec["bits"]),
-                act_bits=(int(spec["abits"]) if spec.get("abits") is not None else None),
-            )
-        if mode == "rules":
-            act = {p: int(b) for p, b in spec.get("act_rules", ())}
-            rules = tuple(PlanRule(p, int(b), act.pop(p, None)) for p, b in spec.get("rules", ()))
-            # act-only patterns (no weight rule) keep their own entry —
-            # resolve_bit_policy applied the two rule lists independently
-            rules += tuple(PlanRule(p, None, b) for p, b in act.items())
-            bits = spec.get("bits")
-            return PlanSpec(
-                mode="rules",
-                weight_bits=int(bits) if bits is not None else None,
-                act_bits=(int(spec["abits"]) if spec.get("abits") is not None else None),
-                rules=rules,
-            )
-        if mode == "auto":
-            kw: Dict[str, Any] = {"mode": "auto"}
-            if "match_uniform" in spec:
-                kw["weight_bits"] = int(spec["match_uniform"])
-            if spec.get("budget_bpw") is not None:
-                kw["budget_bpw"] = float(spec["budget_bpw"])
-            if spec.get("abits") is not None:
-                kw["act_bits"] = int(spec["abits"])
-            if spec.get("prt") is not None:
-                kw["prt"] = spec["prt"]
-            if spec.get("max_segments") is not None:
-                kw["max_segments"] = int(spec["max_segments"])
-            if spec.get("target_tps") is not None:
-                kw["target_tps"] = float(spec["target_tps"])
-            return PlanSpec(**kw)
-        raise ValueError(f"unknown legacy bit_policy dict mode {mode!r}")
 
     # -- QuantPolicy bridge ------------------------------------------------
 
@@ -705,9 +599,9 @@ class PlanSpec:
 
     @staticmethod
     def from_policy(policy, quant_kv: bool = True) -> "PlanSpec":
-        """Best-effort PlanSpec for an explicit ``QuantPolicy`` (legacy
-        ``bit_policy=QuantPolicy(...)`` configs and checkpoint manifests)
-        — the codebook, which is not plan state, stays on the policy."""
+        """Best-effort PlanSpec for an explicit ``QuantPolicy`` (the
+        engine's default plan and checkpoint manifests) — the codebook,
+        which is not plan state, stays on the policy."""
         alloc = policy.allocation
         if alloc is not None:
             return PlanSpec(

@@ -18,10 +18,6 @@ that iteration the scheduling quantum, Orca/vLLM-style:
     of waiting for the cohort to drain — the weight-reuse window the
     paper optimizes is never wasted on a partially idle batch.
 
-``mode="batch"`` keeps the old run-to-completion loop (admit a cohort,
-decode it to the end, admit again) for A/B comparison — see
-``benchmarks/serve_bench.py``.
-
 Runs the SAIL path: weights SAIL-quantized (QTensor), KV cache optionally
 int8.  Precision comes from a ``repro.planning.PlanSpec``
 (``EngineConfig.plan`` / ``slo``; the engine always reports one —
@@ -137,11 +133,8 @@ class EngineConfig:
     # autonomous SLO control loop: True (defaults), a knob dict, or a
     # repro.serving.control.ControllerConfig.  The controller sheds /
     # shrinks occupancy against the SLO and gates replans on measured-
-    # vs-modeled drift (continuous mode only).
+    # vs-modeled drift.
     controller: Any = None
-    # DEPRECATED legacy surface (use ``plan``): None, QuantPolicy, policy
-    # spec dict, or grammar string.
-    bit_policy: Any = None
     eos_token: int = -1            # -1: never stop early
     temperature: float = 0.0       # 0 = greedy
     # PRNG root for temperature>0 sampling.  Tokens are drawn with a key
@@ -149,11 +142,10 @@ class EngineConfig:
     # request's sampled sequence is invariant to batch composition,
     # sheds, preemption/resume, and slot-vs-paged pool layout.
     seed: int = 0
-    mode: str = "continuous"       # "continuous" | "batch" (run-to-completion)
     prefill_budget: Optional[int] = None  # new prefill tokens per iteration
     prompt_bucket: int = 16        # prompts padded to a multiple (compile reuse)
-    # Paged KV pool (continuous mode, attention families).  Setting a
-    # block size replaces the fixed [batch, cache_len] slot pool with a
+    # Paged KV pool (attention families).  Setting a block size
+    # replaces the fixed [batch, cache_len] slot pool with a
     # shared pool of fixed-size blocks managed by a
     # repro.serving.block_pool.BlockSpaceManager: per-request block
     # tables, copy-on-write prefix sharing, block-gated admission, and
@@ -169,9 +161,9 @@ class EngineConfig:
     # quantized weight tree over ``tp`` model-parallel shards and run
     # decode/prefill under shard_map.  A plan carrying a concrete
     # ``tp=``/``wire=`` dimension overrides these knobs.  tp > 1
-    # requires mode="continuous", no tap, no draft, and tp visible
-    # devices (CPU: XLA_FLAGS=--xla_force_host_platform_device_count=N
-    # before importing jax).
+    # requires no tap, no draft, and tp visible devices (CPU:
+    # XLA_FLAGS=--xla_force_host_platform_device_count=N before
+    # importing jax).
     tp: int = 1
     wire: int = 32                 # all-reduce bits: 32 exact, 8 compressed
 
@@ -187,7 +179,6 @@ class Completion:
 class Engine:
     def __init__(self, params, cfg: ModelConfig, ecfg: EngineConfig):
         from repro import planning
-        assert ecfg.mode in ("continuous", "batch"), ecfg.mode
         self.cfg = cfg
         self.ecfg = ecfg
         self.quant_policy: Optional[QuantPolicy] = None
@@ -216,30 +207,15 @@ class Engine:
         # modeled seconds of the SAME iterations at their true occupancy
         # — the occupancy-matched reference side of stats()["drift"]
         self.modeled_seconds = 0.0
-        if (ecfg.bit_policy is not None or ecfg.plan is not None) \
-                and not ecfg.quantize:
+        if ecfg.plan is not None and not ecfg.quantize:
             raise ValueError("a precision plan requires quantize=True")
-        if ecfg.plan is not None and ecfg.bit_policy is not None:
-            raise ValueError("pass plan= OR the deprecated bit_policy=, "
-                             "not both")
-        if ecfg.slo is not None and ecfg.bit_policy is not None:
-            raise ValueError("slo= requires plan= — the deprecated "
-                             "bit_policy surface has no SLO semantics "
-                             "and would silently ignore the target")
         if ecfg.tap_capacity > 0:
-            if ecfg.mode == "continuous":
-                self.tap = planning.ActivationTap(ecfg.tap_capacity,
-                                                  ecfg.tap_every)
-            else:
-                warnings.warn(
-                    "tap_capacity is ignored in mode='batch' — the "
-                    "ActivationTap hooks the continuous engine's masked "
-                    "decode iteration", UserWarning, stacklevel=2)
+            self.tap = planning.ActivationTap(ecfg.tap_capacity,
+                                              ecfg.tap_every)
         if ecfg.quantize:
             base = self._base_policy()
             plan_in = ecfg.plan
-            if plan_in is None and ecfg.bit_policy is None \
-                    and ecfg.slo is not None:
+            if plan_in is None and ecfg.slo is not None:
                 # bare --slo: joint SLO solve anchored at the engine's ql
                 plan_in = planning.PlanSpec(
                     mode="auto", weight_bits=ecfg.ql, act_bits=8,
@@ -276,17 +252,6 @@ class Engine:
                 policy = result.policy
                 self.plan = result.spec
                 self.plan_report = result.report
-            elif ecfg.bit_policy is not None:
-                warnings.warn(
-                    "EngineConfig.bit_policy is deprecated; use "
-                    "EngineConfig.plan (a repro.planning.PlanSpec, "
-                    "grammar string, or plan JSON)", DeprecationWarning,
-                    stacklevel=2)
-                from repro.core.sensitivity import _resolve_policy_like
-                policy = _resolve_policy_like(ecfg.bit_policy, params,
-                                              cfg, base)
-                self.plan = planning.PlanSpec.from_policy(
-                    policy, quant_kv=ecfg.quant_kv)
             else:
                 policy = base
                 self.plan = planning.PlanSpec.from_policy(
@@ -310,7 +275,7 @@ class Engine:
             self.params, self.compression = params, 1.0
         # KV precision: the plan's kv_bits dimension (when concrete — the
         # Planner resolves "auto" before the spec reaches here) overrides
-        # the legacy quant_kv flag; the pool dtype is fixed from here on.
+        # the quant_kv flag; the pool dtype is fixed from here on.
         kvb = (self.plan.kv_bits
                if self.plan is not None
                and isinstance(self.plan.kv_bits, int) else None)
@@ -331,9 +296,6 @@ class Engine:
         self.tp_serving = None
         if self.tp > 1:
             from repro.serving.distributed import TPServing
-            if ecfg.mode != "continuous":
-                raise ValueError("tensor-parallel serving requires "
-                                 "mode='continuous'")
             if self.tap is not None:
                 raise ValueError(
                     "tensor-parallel serving and an ActivationTap cannot "
@@ -341,8 +303,7 @@ class Engine:
                     "capture path")
             self.tp_serving = TPServing(cfg, self.tp, self.wire_bits)
             self.params = self.tp_serving.shard_params(self.params)
-        self.sched = IterationScheduler(target_batch=ecfg.batch_size,
-                                        max_batch=ecfg.batch_size,
+        self.sched = IterationScheduler(max_batch=ecfg.batch_size,
                                         prefill_budget=ecfg.prefill_budget)
         self._uid = 0
         self.completions: Dict[int, Completion] = {}
@@ -382,9 +343,6 @@ class Engine:
         self.paged = ecfg.kv_block_size is not None
         self.block_mgr = None
         if self.paged:
-            if ecfg.mode != "continuous":
-                raise ValueError("paged KV (kv_block_size) requires "
-                                 "mode='continuous'")
             if cfg.family in ("ssm", "hybrid"):
                 raise ValueError(
                     f"paged KV is attention-only; family={cfg.family!r} "
@@ -394,40 +352,39 @@ class Engine:
                 "a model whose layers differ in kind (layer_types) serves "
                 "from the paged pool (kv_block_size): a ring lane would "
                 "wrap over positions its full-attention layers still read")
-        if ecfg.mode == "continuous":
-            self._cur = np.zeros((ecfg.batch_size,), np.int32)
-            if self.paged:
-                from repro.serving.block_pool import BlockSpaceManager
-                bs = int(ecfg.kv_block_size)
-                self._mbs = -(-clen // bs)     # table columns per lane
-                nblocks = self._paged_pool_blocks(bs)
-                self.block_mgr = BlockSpaceManager(
-                    nblocks, bs, share_prefix=ecfg.share_prefix)
-                # one extra physical block: the trash block every dead
-                # table entry / masked lane points at
-                self._trash = nblocks
-                self.cache = lm.init_paged_cache(
-                    self.params, cfg, ecfg.batch_size, nblocks + 1, bs,
-                    self._quant_kv)
-                self._tables_np = np.full(
-                    (ecfg.batch_size, self._mbs), self._trash, np.int32)
-                self._len_np = np.zeros((ecfg.batch_size,), np.int64)
-                self.attn_counts = dict.fromkeys(
-                    ("kernel_steps", "blocks_read", "blocks_in_tables"), 0)
-                self._windows = layer_windows(cfg, self._mbs * bs)
-            else:
-                self.cache = lm.init_cache(self.params, cfg,
-                                           ecfg.batch_size, clen,
-                                           self._quant_kv)
-            if self.tp_serving is not None:
-                self.cache = self.tp_serving.shard_cache(self.cache)
-            # ``blocks._paged_int8_attend``'s dispatch: the kernel serves
-            # an int8 pool on a TPU outside tensor parallelism
-            pool = jax.tree_util.tree_leaves(self.cache["layers"])[0]
-            self._attn_kernel = (self.paged and self._quant_kv
-                                 and self.tp_serving is None
-                                 and next(iter(pool.devices())).platform
-                                 == "tpu")
+        self._cur = np.zeros((ecfg.batch_size,), np.int32)
+        if self.paged:
+            from repro.serving.block_pool import BlockSpaceManager
+            bs = int(ecfg.kv_block_size)
+            self._mbs = -(-clen // bs)     # table columns per lane
+            nblocks = self._paged_pool_blocks(bs)
+            self.block_mgr = BlockSpaceManager(
+                nblocks, bs, share_prefix=ecfg.share_prefix)
+            # one extra physical block: the trash block every dead
+            # table entry / masked lane points at
+            self._trash = nblocks
+            self.cache = lm.init_paged_cache(
+                self.params, cfg, ecfg.batch_size, nblocks + 1, bs,
+                self._quant_kv)
+            self._tables_np = np.full(
+                (ecfg.batch_size, self._mbs), self._trash, np.int32)
+            self._len_np = np.zeros((ecfg.batch_size,), np.int64)
+            self.attn_counts = dict.fromkeys(
+                ("kernel_steps", "blocks_read", "blocks_in_tables"), 0)
+            self._windows = layer_windows(cfg, self._mbs * bs)
+        else:
+            self.cache = lm.init_cache(self.params, cfg,
+                                       ecfg.batch_size, clen,
+                                       self._quant_kv)
+        if self.tp_serving is not None:
+            self.cache = self.tp_serving.shard_cache(self.cache)
+        # ``blocks._paged_int8_attend``'s dispatch: the kernel serves
+        # an int8 pool on a TPU outside tensor parallelism
+        pool = jax.tree_util.tree_leaves(self.cache["layers"])[0]
+        self._attn_kernel = (self.paged and self._quant_kv
+                             and self.tp_serving is None
+                             and next(iter(pool.devices())).platform
+                             == "tpu")
         # self-speculative decoding: the plan's draft= sub-spec requants
         # the SAME raw tree aggressively; the draft tree stays resident
         # alongside the conservative one for the engine's lifetime
@@ -440,9 +397,6 @@ class Engine:
                 raise ValueError(
                     "plan.draft is unresolved ('auto') — solve the plan "
                     "(Planner / resolve_plan with an SLO) before serving")
-            if ecfg.mode != "continuous":
-                raise ValueError("speculative decoding (plan draft=) "
-                                 "requires mode='continuous'")
             if self.tp_serving is not None:
                 raise ValueError(
                     "speculative decoding is not supported under "
@@ -470,25 +424,17 @@ class Engine:
             self.spec_decoder = SpeculativeDecoder(
                 params, cfg, draft, self.quant_policy)
         if ecfg.controller:
-            if ecfg.mode != "continuous":
-                warnings.warn(
-                    "controller is ignored in mode='batch' — the "
-                    "SloController hooks the continuous engine's "
-                    "iteration loop", UserWarning, stacklevel=2)
-            else:
-                from repro.serving.control import (ControllerConfig,
-                                                   SloController)
-                self.controller = SloController(
-                    ControllerConfig.coerce(ecfg.controller),
-                    slo=self.slo,
-                    iter_seconds=(self._modeled_iter_seconds
-                                  if self._plan_units is not None
-                                  else None),
-                    planned_tps=self.planned_tps(),
-                    plan_hit_rate=self.prt_hit_rate,
-                    tokens_per_iter=(self.spec_decoder.expected_tokens()
-                                     if self.spec_decoder is not None
-                                     else 1.0))
+            from repro.serving.control import ControllerConfig, SloController
+            self.controller = SloController(
+                ControllerConfig.coerce(ecfg.controller),
+                slo=self.slo,
+                iter_seconds=(self._modeled_iter_seconds
+                              if self._plan_units is not None else None),
+                planned_tps=self.planned_tps(),
+                plan_hit_rate=self.prt_hit_rate,
+                tokens_per_iter=(self.spec_decoder.expected_tokens()
+                                 if self.spec_decoder is not None
+                                 else 1.0))
 
     # --- client API -------------------------------------------------------
     def submit(self, prompt: List[int], max_new_tokens: int,
@@ -511,7 +457,7 @@ class Engine:
                     f"holds {room} ({self._mbs} blocks x "
                     f"{self.ecfg.kv_block_size}) — paged lanes never "
                     "wrap; raise cache_len or shorten the request")
-        elif spec_extra and self.ecfg.mode == "continuous":
+        elif spec_extra:
             need = len(prompt) + max_new_tokens + spec_extra
             if need > self._clen:
                 raise ValueError(
@@ -540,9 +486,6 @@ class Engine:
             return self._step()
 
     def _step(self) -> bool:
-        if self.ecfg.mode != "continuous":
-            self._serve_batch()
-            return not self.sched.idle()
         ctl = self.controller
         with self.spans.span("engine.admit"):
             cap = None
@@ -790,7 +733,7 @@ class Engine:
         ev["preemptions"] = ev.get("preemptions", 0) + 1
         ev["preempted_iteration"] = self.iterations
 
-    # --- continuous internals ---------------------------------------------
+    # --- step internals ---------------------------------------------------
     def _speculative_round(self, active: List[Request], ctl) -> None:
         """One self-speculative round: fused k-token draft under the
         aggressive tree, one batched (k+1)-token verify under the
@@ -996,72 +939,6 @@ class Engine:
             latency_s=time.perf_counter() - self._t0[req.uid],
             ttft_s=self._ttft.get(req.uid, 0.0))
         self.events[req.uid]["finished_iteration"] = self.iterations
-
-    # --- batch-mode (run-to-completion) internals -------------------------
-    def _serve_batch(self) -> None:
-        batch = self.sched.admit()
-        if not batch:
-            return
-        ecfg, cfg = self.ecfg, self.cfg
-        b = len(batch)
-        maxlen = max(r.prompt_len for r in batch)
-        toks = np.zeros((b, maxlen), np.int32)
-        lengths = np.zeros((b,), np.int32)
-        for i, r in enumerate(batch):
-            p = self._gen[r.uid][:r.prompt_len]
-            toks[i, :len(p)] = p
-            lengths[i] = len(p)
-        self.peak_active = max(self.peak_active, b)
-        logits, cache = lm.prefill(
-            self.params, jnp.asarray(toks), cfg, cache_len=self._clen,
-            quant_kv=self._quant_kv, lengths=jnp.asarray(lengths))
-        self.iterations += 1
-        self.prefill_iterations += 1
-        self.prefill_tokens += int(lengths.sum())
-        cur = self._sample(logits, [r.uid for r in batch],
-                           [self._sample_index(r.uid) for r in batch])
-        now = time.perf_counter()
-        for r in batch:
-            self._ttft[r.uid] = now - self._t0[r.uid]
-        # iteration loop: one decode step serves the whole batch
-        active = list(batch)
-        steps = max(r.max_new_tokens for r in batch)
-        done_at: Dict[int, int] = {}
-        for step in range(steps):
-            for i, r in enumerate(active):
-                if r.uid not in done_at:
-                    if r.max_new_tokens <= 0:
-                        done_at[r.uid] = step
-                        continue
-                    self._gen[r.uid].append(int(cur[i]))
-                    cb = self._on_token.get(r.uid)
-                    if cb is not None:
-                        cb(r.uid, int(cur[i]))
-                    if (int(cur[i]) == ecfg.eos_token or
-                            step + 1 >= r.max_new_tokens):
-                        done_at[r.uid] = step
-            if len(done_at) == len(active) or step == steps - 1:
-                break
-            old_pool = jax.tree_util.tree_leaves(cache["layers"])[0]
-            logits, cache = lm.donating(lm.decode_step)(
-                self.params, cur[:, None], cache, cfg=cfg,
-                quant_kv=self._quant_kv)
-            self.decode_pool_donated += int(old_pool.is_deleted())
-            self.iterations += 1
-            self.decode_iterations += 1
-            cur = self._sample(logits, [r.uid for r in active],
-                               [self._sample_index(r.uid)
-                                for r in active])
-        for r in active:
-            gen = self._gen[r.uid][r.prompt_len:]
-            self.completions[r.uid] = Completion(
-                uid=r.uid, tokens=gen,
-                latency_s=time.perf_counter() - self._t0[r.uid],
-                ttft_s=self._ttft.get(r.uid, 0.0))
-        self.sched.step_complete([r.uid for r in active])
-        # mark any remaining (shouldn't happen in sync mode)
-        self.sched.running = [r for r in self.sched.running
-                              if r.uid not in self.completions]
 
     # --- planning ---------------------------------------------------------
     def _base_policy(self) -> QuantPolicy:
@@ -1304,8 +1181,7 @@ class Engine:
         from repro import planning
         if self.tap is None:
             raise ValueError("no ActivationTap attached — set "
-                             "EngineConfig.tap_capacity > 0 (taps only "
-                             "attach in mode='continuous')")
+                             "EngineConfig.tap_capacity > 0")
         if self._raw_params is None:
             raise ValueError("replan needs the raw weights resident — "
                              "construct the engine with quantize=True "

@@ -202,7 +202,7 @@ def tiny():
 def make_engine(tiny, batch=4, **kw):
     cfg, params = tiny
     opts = dict(batch_size=batch, cache_len=64, quantize=True, ql=4,
-                group_size=32, quant_kv=True, mode="continuous")
+                group_size=32, quant_kv=True)
     opts.update(kw)
     return Engine(params, cfg, EngineConfig(**opts))
 
@@ -304,15 +304,10 @@ def test_equal_memory_admits_more_with_sharing(tiny):
     assert paged.stats()["peak_active"] > slot.stats()["peak_active"]
 
 
-def test_paged_rejects_oversized_and_wrong_mode(tiny):
-    cfg, params = tiny
+def test_paged_rejects_oversized_request(tiny):
     eng = make_engine(tiny, kv_block_size=8)
-    with pytest.raises(ValueError):
+    with pytest.raises(ValueError, match="never wrap"):
         eng.submit(list(range(60)), 10)        # 70 > 64-token lane
-    with pytest.raises(ValueError):
-        Engine(params, cfg, EngineConfig(
-            batch_size=2, cache_len=64, quantize=False, mode="batch",
-            kv_block_size=8))
 
 
 def test_kv_bits_plan_threads_to_engine_and_stats(tiny):
@@ -322,7 +317,7 @@ def test_kv_bits_plan_threads_to_engine_and_stats(tiny):
     cfg, params = tiny
     eng = Engine(params, cfg, EngineConfig(
         batch_size=4, cache_len=64, quantize=True, ql=4, group_size=32,
-        quant_kv=False, mode="continuous", kv_block_size=8,
+        quant_kv=False, kv_block_size=8,
         plan="uniform:4,kv=8"))
     assert eng.stats()["kv_bits"] == 8
     assert eng.cache["layers"]["k"].dtype == np.int8

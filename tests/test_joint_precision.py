@@ -20,6 +20,7 @@ from repro.models.common import ModelConfig
 from repro.models.sail_linear import (BitAllocation, QuantPolicy, QTensor,
                                       StackedQTensor, act_fake_quant,
                                       mm, quantize_params)
+from repro.planning import PlanRule, PlanSpec, resolve_plan
 
 
 def tiny_cfg(**kw):
@@ -53,24 +54,23 @@ def iter_qtensors(tree, prefix=""):
 # ---------------------------------------------------------------------------
 
 def test_parse_bit_policy_activation_grammar():
-    assert sens.parse_bit_policy("uniform:4a8") == {
-        "mode": "uniform", "bits": 4, "abits": 8}
-    r = sens.parse_bit_policy("rules:attn=5a6,mlp=3,default=4a8")
-    assert r["rules"] == [("attn", 5), ("mlp", 3)]
-    assert r["act_rules"] == [("attn", 6)]
-    assert r["bits"] == 4 and r["abits"] == 8
-    a = sens.parse_bit_policy("auto:q4a8,prt=measured,maxseg=2")
-    assert a == {"mode": "auto", "match_uniform": 4, "abits": 8,
-                 "prt": "measured", "max_segments": 2}
-    # legacy weight-only forms are unchanged
-    assert sens.parse_bit_policy("auto:q4") == {"mode": "auto",
-                                                "match_uniform": 4}
-    assert sens.parse_bit_policy("uniform:6") == {"mode": "uniform",
-                                                  "bits": 6}
+    assert PlanSpec.parse("uniform:4a8") == PlanSpec(
+        mode="uniform", weight_bits=4, act_bits=8)
+    r = PlanSpec.parse("rules:attn=5a6,mlp=3,default=4a8")
+    assert r.rules == (PlanRule("attn", 5, 6), PlanRule("mlp", 3))
+    assert r.weight_bits == 4 and r.act_bits == 8
+    a = PlanSpec.parse("auto:q4a8,prt=measured,maxseg=2")
+    assert a == PlanSpec(mode="auto", weight_bits=4, act_bits=8,
+                         prt="measured", max_segments=2)
+    # weight-only forms carry no activation precision
+    assert PlanSpec.parse("auto:q4") == PlanSpec(mode="auto",
+                                                 weight_bits=4)
+    assert PlanSpec.parse("uniform:6") == PlanSpec(mode="uniform",
+                                                   weight_bits=6)
     with pytest.raises(ValueError):
-        sens.parse_bit_policy("auto:q4a8,prt=sometimes")
+        PlanSpec.parse("auto:q4a8,prt=sometimes")
     with pytest.raises(ValueError):
-        sens.parse_bit_policy("uniform:4b8")
+        PlanSpec.parse("uniform:4b8")
 
 
 def test_policy_spec_roundtrip_with_activations():
@@ -106,10 +106,10 @@ def test_resolve_bit_policy_activation_strings():
     cfg = tiny_cfg()
     params = tiny_params(cfg)
     base = QuantPolicy(bits=4, **POLICY)
-    uni = sens.resolve_bit_policy("uniform:6a8", params, cfg, base)
+    uni = resolve_plan("uniform:6a8", params, cfg, base=base).policy
     assert uni.bits == 6 and uni.act_bits == 8
-    rules = sens.resolve_bit_policy("rules:mlp=4a6,default=6a8", params,
-                                    cfg, base)
+    rules = resolve_plan("rules:mlp=4a6,default=6a8", params, cfg,
+                         base=base).policy
     assert rules.act_rules == (("mlp", 6),) and rules.act_bits == 8
 
 
@@ -121,19 +121,36 @@ def test_resolve_bit_policy_activation_strings():
 @given(abits=st.sampled_from(SUPPORTED_ABITS), bits=st.sampled_from([2, 4, 8]),
        seed=st.integers(0, 99))
 def test_property_mm_applies_activation_quant(abits, bits, seed):
-    """mm on a QTensor carrying abits must equal the same matmul on
-    explicitly fake-quantized activations — and differ from the f32
-    path whenever quantization actually rounds."""
+    """mm on a QTensor carrying abits must run the integer-activation
+    matmul, equal the same matmul on explicitly fake-quantized
+    activations up to float32 rounding — and differ from the f32 path
+    whenever quantization actually rounds."""
+    from repro.core.quant import dequantize, quantize_activations
     from repro.kernels.lut_gemv.ops import lut_matmul
+    from repro.kernels.lut_gemv.ref import lut_matmul_ref_int
     rng = np.random.default_rng(seed)
-    w = jnp.asarray(rng.standard_normal((64, 16)), jnp.float32)
-    x = jnp.asarray(rng.standard_normal((3, 64)), jnp.float32)
+    k = 64
+    w = jnp.asarray(rng.standard_normal((k, 16)), jnp.float32)
+    x = jnp.asarray(rng.standard_normal((3, k)), jnp.float32)
     qt = quantize(w, bits, 32)
     qta = dataclasses.replace(qt, abits=int(abits))
     got = mm(x, qta)
-    want = lut_matmul(act_fake_quant(x, abits), qt, backend="jnp")
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-6)
+    # like with like: the integer-code oracle, scale applied after the dot
+    x_q, x_scale = quantize_activations(x, abits)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(lut_matmul_ref_int(x_q, x_scale, qt)),
+        rtol=1e-5, atol=1e-6)
+    # fake-quant folds the scale in before the dot: mathematically equal,
+    # not bitwise.  Each side is a K-term float32 dot plus one scale
+    # product, so each lies within (K + 2) * eps * sum|x_i * w_i| of the
+    # exact value (the standard dot-product rounding bound) and the two
+    # within twice that; a bound relative to |want| fails where the sum
+    # cancels to near zero.
+    fq = act_fake_quant(x, abits)
+    want = lut_matmul(fq, qt, backend="jnp")
+    bound = 2 * (k + 2) * np.finfo(np.float32).eps * (
+        jnp.abs(fq) @ jnp.abs(dequantize(qt)))
+    assert bool(jnp.all(jnp.abs(got - want) <= bound))
     f32 = mm(x, qt)
     assert float(jnp.max(jnp.abs(got - f32))) > 0.0
 
@@ -360,7 +377,7 @@ def test_enforce_max_segments_cap_and_losslessness():
 
 def test_max_segments_validated():
     with pytest.raises(ValueError, match="maxseg"):
-        sens.parse_bit_policy("auto:q4a8,maxseg=0")
+        PlanSpec.parse("auto:q4a8,maxseg=0")
     with pytest.raises(ValueError, match="max_segments"):
         sens.enforce_max_segments([], {}, 0)
 
@@ -479,7 +496,7 @@ def test_engine_activation_bit_policy():
     params = tiny_params(cfg)
     eng = Engine(params, cfg, EngineConfig(
         batch_size=2, cache_len=32, quantize=True, ql=8, group_size=32,
-        quant_kv=False, bit_policy="rules:mlp=4a6,default=6a8"))
+        quant_kv=False, plan="rules:mlp=4a6,default=6a8"))
     abits = {p: q.abits for p, q in iter_qtensors(eng.params)}
     assert abits["['blocks']['mlp']['w_up']"] == 6
     assert abits["['blocks']['attn']['wq']"] == 8

@@ -18,6 +18,7 @@ from repro.models.common import ModelConfig
 from repro.models.sail_linear import (BitAllocation, QuantPolicy,
                                       QTensor, StackedQTensor,
                                       dequantize_any, quantize_params)
+from repro.planning import PlanRule, PlanSpec
 
 
 def tiny_cfg(**kw):
@@ -72,17 +73,17 @@ def test_policy_spec_roundtrip():
 
 
 def test_parse_bit_policy_grammar():
-    assert sens.parse_bit_policy("uniform:6") == {"mode": "uniform",
-                                                  "bits": 6}
-    r = sens.parse_bit_policy("rules:attn=5,mlp=3,default=4")
-    assert r["mode"] == "rules" and r["bits"] == 4
-    assert ("attn", 5) in r["rules"] and ("mlp", 3) in r["rules"]
-    assert sens.parse_bit_policy("auto:q4") == {"mode": "auto",
-                                                "match_uniform": 4}
-    assert sens.parse_bit_policy("auto:4.5bpw") == {"mode": "auto",
-                                                    "budget_bpw": 4.5}
+    u = PlanSpec.parse("uniform:6")
+    assert (u.mode, u.weight_bits, u.act_bits) == ("uniform", 6, None)
+    r = PlanSpec.parse("rules:attn=5,mlp=3,default=4")
+    assert r.mode == "rules" and r.weight_bits == 4
+    assert PlanRule("attn", 5) in r.rules and PlanRule("mlp", 3) in r.rules
+    a = PlanSpec.parse("auto:q4")
+    assert (a.mode, a.weight_bits, a.budget_bpw) == ("auto", 4, None)
+    b = PlanSpec.parse("auto:4.5bpw")
+    assert (b.mode, b.budget_bpw) == ("auto", 4.5)
     with pytest.raises(ValueError):
-        sens.parse_bit_policy("nope:1")
+        PlanSpec.parse("nope:1")
 
 
 def test_unsupported_bits_rejected():
@@ -351,34 +352,45 @@ def test_calibrate_policy_matches_budget():
 # engine integration
 # ---------------------------------------------------------------------------
 
-def test_engine_mixed_bit_policy_token_identical_to_f32():
+def test_engine_mixed_plan_matches_reference_and_f32_logits():
     """A high-precision mixed allocation (per-layer 6/8 bits -> segmented
-    serving path) must produce token-identical greedy output to the
-    unquantized model on short smoke prompts."""
+    serving path) serves exactly the plain reference loop's greedy tokens
+    over the same quantized weights, and its logits stay within 6-bit
+    rounding of the unquantized model's."""
     from repro.serving.engine import Engine, EngineConfig
     cfg = C.get_smoke("tinymistral_248m")
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
     prompts = [[1, 2, 3], [4, 5, 6, 7], [8, 9]]
-
-    def run(ecfg):
-        eng = Engine(params, cfg, ecfg)
-        for p in prompts:
-            eng.submit(p, max_new_tokens=8)
-        return {c.uid: c.tokens for c in eng.run()}, eng
-
-    ref, _ = run(EngineConfig(batch_size=4, cache_len=64, quantize=False,
-                              quant_kv=False))
     alloc = BitAllocation(
         per_path={"['blocks']['mlp']['w_down']": (6, 8)})
     pol = QuantPolicy(bits=8, group_size=32, min_size=1024,
                       allocation=alloc)
-    mixed, eng = run(EngineConfig(batch_size=4, cache_len=64, quantize=True,
-                                  ql=8, group_size=32, quant_kv=False,
-                                  bit_policy=pol))
+    eng = Engine(params, cfg, EngineConfig(
+        batch_size=4, cache_len=64, quantize=True, ql=8, group_size=32,
+        quant_kv=False, plan=PlanSpec.from_policy(pol, quant_kv=False)))
     assert isinstance(eng.params["blocks"], list), \
         "per-layer allocation must serve through the segmented path"
     assert eng.stats()["mixed_precision"]
-    assert mixed == ref
+    uids = [eng.submit(p, max_new_tokens=8) for p in prompts]
+    served = {c.uid: c.tokens for c in eng.run()}
+    # like with like: the reference loop over the engine's own weights
+    for uid, p in zip(uids, prompts):
+        ref = lm.greedy_generate(eng.params, np.asarray([p], np.int32),
+                                 cfg, 8, cache_len=64)
+        assert served[uid] == np.asarray(ref[0]).tolist(), p
+    # against f32 only on logits: greedy tokens of a random-init model
+    # sit near ties, so one rounding can flip an argmax.  A b-bit code
+    # rounds each weight by at most 1/(2^b - 1) of its group's absmax;
+    # 6 bits is the coarsest here, so each of the model's matmuls (7 per
+    # layer, plus lm_head) carries at most 1/63 relative error, and the
+    # independent errors add in quadrature.
+    tol = np.sqrt(7 * cfg.n_layers + 1) / (2 ** 6 - 1)
+    for uid, p in zip(uids, prompts):
+        seq = jnp.asarray([p + served[uid]], jnp.int32)
+        want, _ = lm.forward(params, seq, cfg)
+        got, _ = lm.forward(eng.params, seq, cfg)
+        err = jnp.linalg.norm(got - want) / jnp.linalg.norm(want)
+        assert float(err) <= tol, (p, float(err), tol)
 
 
 def test_engine_auto_bit_policy_smoke():
@@ -389,7 +401,7 @@ def test_engine_auto_bit_policy_smoke():
     params = tiny_params(cfg)
     eng = Engine(params, cfg, EngineConfig(
         batch_size=2, cache_len=32, quantize=True, ql=4, group_size=32,
-        quant_kv=True, bit_policy="auto:q4"))
+        quant_kv=True, plan="auto:q4"))
     assert eng.quant_policy.allocation is not None
     eng.submit([1, 2, 3], max_new_tokens=4)
     done = eng.run()
@@ -419,7 +431,7 @@ def test_engine_rules_bit_policy_string():
     params = tiny_params(cfg)
     eng = Engine(params, cfg, EngineConfig(
         batch_size=2, cache_len=32, quantize=True, ql=4, group_size=32,
-        bit_policy="rules:mlp=2,default=6"))
+        plan="rules:mlp=2,default=6"))
     bits = {p: q.bits for p, q in iter_qtensors(eng.params)}
     assert bits["['blocks']['mlp']['w_up']"] == 2
     assert bits["['blocks']['attn']['wq']"] == 6

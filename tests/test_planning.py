@@ -1,8 +1,9 @@
-"""Planning API: PlanSpec round-trips and grammar shims, SLO->budget
+"""Planning API: PlanSpec round-trips and input forms, SLO->budget
 derivation, the DRAM-aware objective, Planner solve/replan, the
 ActivationTap capture path, live plan swaps in the engine, checkpoint
 plan provenance, and the joint-solver Pareto pruning regression."""
 import dataclasses
+import json
 import tempfile
 
 import jax
@@ -15,7 +16,7 @@ from repro.models import lm
 from repro.models.common import ModelConfig
 from repro.models.sail_linear import QuantPolicy, quantize_params
 from repro.planning import (ActivationTap, DecodeCostModel, Planner,
-                            PlanSpec, Slo)
+                            PlanRule, PlanSpec, Slo, as_plan, resolve_plan)
 
 
 def tiny_cfg(**kw):
@@ -48,7 +49,7 @@ def probes(tiny):
 
 
 # ---------------------------------------------------------------------------
-# PlanSpec: JSON <-> grammar round-trips, shims
+# PlanSpec: JSON <-> grammar round-trips, input forms
 # ---------------------------------------------------------------------------
 
 DOCUMENTED_SPECS = [
@@ -112,26 +113,26 @@ def test_planspec_validation():
 
 @pytest.mark.parametrize("spec", DOCUMENTED_SPECS)
 def test_parse_bit_policy_shim_equivalence(spec):
-    """The deprecated shim returns PlanSpec.parse's legacy dict form,
-    with a DeprecationWarning."""
-    with pytest.warns(DeprecationWarning):
-        legacy = sens.parse_bit_policy(spec)
-    assert legacy == PlanSpec.parse(spec).to_legacy_dict()
-    # and the legacy dict itself round-trips into the same plan
-    assert PlanSpec.from_legacy_dict(legacy) == PlanSpec.parse(spec)
+    """Every form ``EngineConfig.plan`` accepts — the grammar string, the
+    PlanSpec, its JSON dict and that dict through JSON text — names one
+    plan, with one hash, whose canonical grammar parses back to it."""
+    plan = PlanSpec.parse(spec)
+    forms = (spec, plan, plan.to_json(), json.loads(json.dumps(plan.to_json())))
+    for form in forms:
+        assert as_plan(form) == plan
+        assert as_plan(form).spec_hash == plan.spec_hash
+    assert PlanSpec.parse(plan.format()).format() == plan.format()
 
 
 def test_resolve_bit_policy_shim(tiny):
     cfg, params = tiny
     base = QuantPolicy(bits=4, **BASE)
-    with pytest.warns(DeprecationWarning):
-        pol = sens.resolve_bit_policy("uniform:6a8", params, cfg, base)
+    pol = resolve_plan("uniform:6a8", params, cfg, base=base).policy
     assert pol.bits == 6 and pol.act_bits == 8
-    with pytest.warns(DeprecationWarning):
-        pol = sens.resolve_bit_policy("rules:mlp=4a6,default=6a8",
-                                      params, cfg, base)
+    pol = resolve_plan("rules:mlp=4a6,default=6a8", params, cfg,
+                       base=base).policy
     assert pol.act_rules == (("mlp", 6),) and pol.act_bits == 8
-    # the PlanSpec path produces the identical policy
+    # resolving needs no solve for these: it is the spec's own policy
     assert pol == PlanSpec.parse("rules:mlp=4a6,default=6a8").to_policy(base)
 
 
@@ -144,17 +145,15 @@ def test_planspec_policy_bridge_roundtrip():
 
 
 def test_legacy_act_only_rules_preserved():
-    """resolve_bit_policy applied rules and act_rules independently; an
-    act_rules pattern with no weight rule must survive the PlanSpec
-    bridge instead of being silently dropped."""
+    """Weight rules and activation rules apply independently: an
+    activation-only rule (no weight bits) must reach the policy instead
+    of being silently dropped, and survive the JSON round trip."""
     base = QuantPolicy(bits=4, **BASE)
-    legacy = {"mode": "rules", "rules": [("attn", 4)],
-              "act_rules": [("mlp", 6)]}
-    plan = PlanSpec.from_legacy_dict(legacy)
+    plan = PlanSpec(mode="rules", rules=(PlanRule("attn", 4),
+                                         PlanRule("mlp", None, 6)))
     pol = plan.to_policy(base)
     assert pol.rules == (("attn", 4),)
     assert pol.act_rules == (("mlp", 6),)
-    assert PlanSpec.from_legacy_dict(plan.to_legacy_dict()) == plan
     assert PlanSpec.from_json(plan.to_json()) == plan
     # act-only rule tokens have a grammar form too
     assert PlanSpec.parse(plan.format()) == plan
@@ -432,20 +431,17 @@ def test_engine_replan_measures_prt_from_traffic(tiny):
 
 
 def test_engine_plan_equals_legacy_bit_policy(tiny):
+    """A grammar string, its PlanSpec and the PlanSpec's JSON dict serve
+    one quant_policy; a plan without quantization is refused."""
     cfg, params = tiny
     from repro.serving.engine import Engine, EngineConfig
-    e_plan = Engine(params, cfg, EngineConfig(
+    spec = PlanSpec.parse("rules:mlp=2,default=6")
+    pols = [Engine(params, cfg, EngineConfig(
         batch_size=2, cache_len=32, quantize=True, ql=4, group_size=32,
-        plan="rules:mlp=2,default=6"))
-    with pytest.warns(DeprecationWarning):
-        e_legacy = Engine(params, cfg, EngineConfig(
-            batch_size=2, cache_len=32, quantize=True, ql=4,
-            group_size=32, bit_policy="rules:mlp=2,default=6"))
-    assert e_plan.quant_policy == e_legacy.quant_policy
-    with pytest.raises(ValueError):
-        Engine(params, cfg, EngineConfig(
-            quantize=True, plan="uniform:4",
-            bit_policy="uniform:4"))
+        plan=form)).quant_policy
+        for form in ("rules:mlp=2,default=6", spec, spec.to_json())]
+    assert pols[0] == pols[1] == pols[2]
+    assert pols[0].rules == (("mlp", 2),) and pols[0].bits == 6
     with pytest.raises(ValueError):
         Engine(params, cfg, EngineConfig(quantize=False, plan="uniform:4"))
 
@@ -476,23 +472,6 @@ def test_engine_warns_on_unreachable_slo(tiny):
         Engine(params, cfg, EngineConfig(
             batch_size=2, cache_len=32, quantize=True, ql=4,
             group_size=32, plan="uniform:4a8", slo=1e12))
-    with pytest.warns(UserWarning, match="tap_capacity is ignored"):
-        Engine(params, cfg, EngineConfig(
-            batch_size=2, cache_len=32, quantize=True, ql=4,
-            group_size=32, mode="batch", tap_capacity=8))
-
-
-def test_legacy_auto_dict_with_solver_kwargs(tiny):
-    """resolve_bit_policy forwarded arbitrary calibrate_policy kwargs in
-    auto dicts; the compat shim must keep doing so."""
-    cfg, params = tiny
-    base = QuantPolicy(bits=4, **BASE)
-    with pytest.warns(DeprecationWarning):
-        pol = sens.resolve_bit_policy(
-            {"mode": "auto", "match_uniform": 4, "calib_batch": 2,
-             "calib_seq": 8, "bits_candidates": (3, 4, 6)},
-            params, cfg, base)
-    assert pol.allocation is not None
 
 
 # ---------------------------------------------------------------------------

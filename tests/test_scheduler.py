@@ -36,28 +36,31 @@ def test_pipeline_optimal_batch_knee():
 
 
 def test_iteration_scheduler_backfill():
-    s = IterationScheduler(target_batch=2)
+    s = IterationScheduler(max_batch=2)
     for i in range(4):
         s.submit(Request(uid=i, prompt_len=4, max_new_tokens=2))
-    batch = s.admit()
+    batch = s.schedule()
     assert [r.uid for r in batch] == [0, 1]
-    s.step_complete([])          # 1 token each
-    s.step_complete([])          # hit max_new -> finish
+    assert s.schedule() == []    # no free slot until a release
+    for uid in (0, 1):
+        s.release(uid)
     assert {r.uid for r in s.finished} == {0, 1}
-    batch = s.admit()
+    batch = s.schedule()
     assert [r.uid for r in batch] == [2, 3]
+    assert sorted(r.slot for r in batch) == [0, 1]
 
 
 @settings(max_examples=15, deadline=None)
 @given(n=st.integers(1, 12), tgt=st.integers(1, 5))
 def test_property_scheduler_conserves_requests(n, tgt):
-    s = IterationScheduler(target_batch=tgt)
+    s = IterationScheduler(max_batch=tgt)
     for i in range(n):
         s.submit(Request(uid=i, prompt_len=1, max_new_tokens=1))
     guard = 0
     while not s.idle():
-        s.admit()
-        s.step_complete([])
+        s.schedule()
+        for r in list(s.running):
+            s.release(r.uid)
         guard += 1
         assert guard < 100
     assert len(s.finished) == n
@@ -102,7 +105,7 @@ def _drain_continuous(s, decode_steps_fn):
        seed=st.integers(0, 99))
 def test_property_slots_never_exceed_max_batch(n, slots, seed):
     rng = np.random.default_rng(seed)
-    s = IterationScheduler(target_batch=slots, max_batch=slots)
+    s = IterationScheduler(max_batch=slots)
     for i in range(n):
         s.submit(Request(uid=i, prompt_len=int(rng.integers(1, 9)),
                          max_new_tokens=int(rng.integers(1, 5))))

@@ -42,7 +42,7 @@ def make_engine(tiny, plan, batch=4, **kw):
     cfg, params = tiny
     return Engine(params, cfg, EngineConfig(
         batch_size=batch, cache_len=64, quantize=True, group_size=32,
-        min_size=1024, quant_kv=False, mode="continuous", plan=plan, **kw))
+        min_size=1024, quant_kv=False, plan=plan, **kw))
 
 
 def run_all(eng, max_new=8, prompts=PROMPTS):
@@ -336,14 +336,6 @@ def test_controller_budget_scales_with_expected_tokens():
 # --- gating and capacity guards ---------------------------------------------
 
 
-def test_draft_requires_continuous_mode(tiny):
-    cfg, params = tiny
-    with pytest.raises(ValueError, match="continuous"):
-        Engine(params, cfg, EngineConfig(
-            batch_size=2, cache_len=64, quantize=True, min_size=1024,
-            mode="batch", plan="uniform:8a8,draft=q4:k2"))
-
-
 def test_draft_rejects_tap_and_recurrent_family(tiny):
     with pytest.raises(ValueError, match="ActivationTap"):
         make_engine(tiny, "uniform:8a8,draft=q4:k2", tap_capacity=16)
@@ -352,7 +344,7 @@ def test_draft_rejects_tap_and_recurrent_family(tiny):
     with pytest.raises(ValueError, match="attention"):
         Engine(sparams, scfg, EngineConfig(
             batch_size=2, cache_len=32, quantize=True, min_size=1024,
-            mode="continuous", plan="uniform:8a8,draft=q4:k2"))
+            plan="uniform:8a8,draft=q4:k2"))
 
 
 def test_submit_reserves_draft_lookahead(tiny):

@@ -188,7 +188,7 @@ def test_engine_decode_step_updates_pool_in_place_on_cpu(donate):
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
     eng = Engine(params, cfg, EngineConfig(
         batch_size=4, cache_len=64, quantize=True, group_size=32,
-        plan="uniform:4a8", mode="continuous", kv_block_size=8,
+        plan="uniform:4a8", kv_block_size=8,
         kv_pool_blocks=40))
     fn = lm.donating(lm.decode_step) if donate else lm.decode_step
     compiled = _lower_decode(fn, eng.params, eng.cache, cfg, 4,

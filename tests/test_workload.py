@@ -145,3 +145,19 @@ def test_build_workload_reproducible():
     assert a.spec.arrival.process == "poisson"
     c = build_workload(_Cfg, 6, 12, 3, seed=6, arrival="poisson")
     assert c.requests != a.requests
+
+
+def test_serve_bench_requires_a_gate(monkeypatch, capsys):
+    """serve_bench has no default mode: without a gate flag it stops with
+    argparse's usage error, which names the gates, before any model is
+    built."""
+    import benchmarks.serve_bench as sb
+
+    monkeypatch.setattr(sb, "enable_compile_cache", lambda: None)
+    monkeypatch.setattr(sys, "argv", ["serve_bench.py", "--requests", "2"])
+    with pytest.raises(SystemExit) as exc:
+        sb.main()
+    assert exc.value.code == 2
+    err = capsys.readouterr().err
+    for flag in ("--replay", "--paged-gate", "--speculative"):
+        assert flag in err
