@@ -414,12 +414,14 @@ def _scatter_slots(pool: Dict[str, Any], fresh: Dict[str, Any],
     return {"length": length, "layers": layers}
 
 
-# Donating the pool lets XLA update the written rows in place; eager
-# .at[].set would copy the whole [L, max_batch, cache_len, ...] arena on
-# every admission.
-_scatter_slots_jit = jax.jit(_scatter_slots, donate_argnums=(0,))
+_PREFILL_STATIC = ("cfg", "quant_kv", "moe_mode")
 
 
+# One program per admission shape: prefill and its scatter compile
+# together, cached on the shapes of ``tokens`` (batch, padded length);
+# the donated pool is written in place, not copied.  Called eagerly, the
+# layer scan would be retraced and recompiled at every admission.
+@partial(jax.jit, static_argnames=_PREFILL_STATIC, donate_argnames="cache")
 def prefill_into_slot(params, tokens, cache, slot, cfg: ModelConfig,
                       quant_kv: bool = False,
                       lengths: Optional[jax.Array] = None,
@@ -429,7 +431,8 @@ def prefill_into_slot(params, tokens, cache, slot, cfg: ModelConfig,
 
     tokens: [b, T] (right-padded prompts; typically b == 1 — one newly
     admitted request).  cache: the engine's ``[max_batch, cache_len]``
-    pool from ``init_cache``.  slot: int or [b] int array of target rows.
+    pool from ``init_cache``, donated: the caller rebinds it to the
+    returned pool.  slot: int or [b] int array of target rows.
     Returns (last-token logits [b, V], updated pool).  Handles the int8
     quant-KV path (codes + scales scattered together) and recurrent
     families (ssm/xlstm state rows are replaced the same way).
@@ -442,7 +445,7 @@ def prefill_into_slot(params, tokens, cache, slot, cfg: ModelConfig,
     logits, fresh = prefill(params, tokens, cfg, cache_len=cache_len,
                             quant_kv=quant_kv, lengths=lengths,
                             prefix_embeds=prefix_embeds, moe_mode=moe_mode)
-    return logits, _scatter_slots_jit(cache, fresh, slots)
+    return logits, _scatter_slots(cache, fresh, slots)
 
 
 def _scatter_blocks(pool: Dict[str, Any], fresh: Dict[str, Any],
@@ -468,9 +471,6 @@ def _scatter_blocks(pool: Dict[str, Any], fresh: Dict[str, Any],
     return {"length": length, "layers": layers}
 
 
-_scatter_blocks_jit = jax.jit(_scatter_blocks, donate_argnums=(0,))
-
-
 def _copy_blocks(layers: Dict[str, Any], src: jax.Array,
                  dst: jax.Array) -> Dict[str, Any]:
     """Copy-on-write: duplicate pool blocks ``src`` into free blocks ``dst``."""
@@ -481,6 +481,7 @@ def _copy_blocks(layers: Dict[str, Any], src: jax.Array,
 _copy_blocks_jit = jax.jit(_copy_blocks, donate_argnums=(0,))
 
 
+@partial(jax.jit, static_argnames=_PREFILL_STATIC, donate_argnames="cache")
 def prefill_into_blocks(params, tokens, cache, slots, phys, offs,
                         cfg: ModelConfig, quant_kv: bool = False,
                         lengths: Optional[jax.Array] = None,
@@ -488,16 +489,17 @@ def prefill_into_blocks(params, tokens, cache, slots, phys, offs,
     """Prefill request(s) and scatter their KV into a paged block pool.
 
     tokens: [b, T] right-padded prompts.  cache: pool from
-    :func:`init_paged_cache`.  slots: [b] decode-lane indices (for
-    ``length``).  phys/offs: flat [b*T] block destinations (trash-redirected
-    where a row must not be written — padding and shared prefix blocks).
+    :func:`init_paged_cache`, donated as in :func:`prefill_into_slot`.
+    slots: [b] decode-lane indices (for ``length``).  phys/offs: flat
+    [b*T] block destinations (trash-redirected where a row must not be
+    written — padding and shared prefix blocks).
     Returns (last-token logits [b, V], updated pool).
     """
     slots = jnp.atleast_1d(jnp.asarray(slots, jnp.int32))
     logits, fresh = prefill(params, tokens, cfg, cache_len=tokens.shape[1],
                             quant_kv=quant_kv, lengths=lengths,
                             moe_mode=moe_mode)
-    return logits, _scatter_blocks_jit(
+    return logits, _scatter_blocks(
         cache, fresh, slots,
         jnp.asarray(phys, jnp.int32), jnp.asarray(offs, jnp.int32))
 

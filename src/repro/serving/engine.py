@@ -314,6 +314,9 @@ class Engine:
         self.events: Dict[int, Dict[str, int]] = {}   # per-uid iteration marks
         self.iterations = 0            # total model iterations (prefill+decode)
         self.prefill_iterations = 0
+        # (batch, padded length) of every prefill run: each first one
+        # traces and compiles (or loads) its program
+        self._prefill_shapes: set = set()
         self.decode_iterations = 0
         # decode steps that took the KV pool's buffers over (donation);
         # equal to the single-device decode iterations unless the
@@ -862,6 +865,7 @@ class Engine:
                 self.queue_wait_seconds += now - req.arrived_at
                 self.queue_admissions += 1
         b = len(reqs)
+        self._prefill_shapes.add((b, padded))
         toks = np.zeros((b, padded), np.int32)
         lengths = np.zeros((b,), np.int32)
         for i, req in enumerate(reqs):
@@ -1323,6 +1327,10 @@ class Engine:
                                 else None),
                 "iterations": self.iterations,
                 "prefill_iterations": self.prefill_iterations,
+                # prefill calls, and those at a (batch, padded length)
+                # this engine had not run: its compiled-program misses
+                "prefill": {"calls": self.prefill_iterations,
+                            "new_shapes": len(self._prefill_shapes)},
                 "decode_iterations": self.decode_iterations,
                 "decode_pool_donated": self.decode_pool_donated,
                 "prefill_tokens": self.prefill_tokens,

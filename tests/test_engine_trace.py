@@ -240,3 +240,39 @@ def test_decode_attn_counts_live_blocks(arch, windows):
     assert got["blocks_in_tables"] == (eng.decode_iterations * batch
                                        * (48 // block) * cfg.n_layers)
     assert got["blocks_read"] < got["blocks_in_tables"]
+
+
+@pytest.mark.parametrize("arch", ["tinymistral_248m", "mellum2_12b_a2_5b"])
+def test_second_admission_at_a_shape_compiles_nothing(arch):
+    """Prefill is one program per (batch, padded length): after one
+    admission at a shape, a second at the same shape, with another
+    prompt, traces and compiles nothing (no backend compile, no JIT
+    event in ``engine.prefill``) and is not a new shape in
+    ``stats()["prefill"]``."""
+    cfg = C.get_smoke(arch)
+    params = lm.init_params(jax.random.PRNGKey(0), cfg)
+    eng = Engine(params, cfg, EngineConfig(
+        batch_size=4, cache_len=64, quantize=True, group_size=32,
+        plan="uniform:4a8", kv_block_size=8, eos_token=-1))
+    assert eng.paged and eng._quant_kv
+    eng.submit([(5 * i + 3) % cfg.vocab for i in range(12)], 4)
+    eng.step()
+    first = eng.stats().get("prefill")
+    jit_events = eng.spans["engine.prefill"][4]
+    compiles = []
+
+    def listen(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        eng.submit([(7 * i + 1) % cfg.vocab for i in range(11)], 4)
+        eng.step()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert compiles == []
+    assert eng.spans["engine.prefill"][0] == 2
+    assert eng.spans["engine.prefill"][4] == jit_events
+    assert first == {"calls": 1, "new_shapes": 1}
+    assert eng.stats()["prefill"] == {"calls": 2, "new_shapes": 1}

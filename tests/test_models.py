@@ -127,3 +127,65 @@ def test_param_count_formula():
             (arch, cfg.param_count())
     mx = C.get_config("mixtral_8x7b")
     assert abs(mx.active_param_count() - 12.9e9) / 12.9e9 < 0.15
+
+
+def test_jitted_paged_prefill_matches_eager_prefill_and_scatter():
+    """``lm.prefill_into_blocks``, one program, against eager ``prefill``
+    then ``_scatter_blocks`` on the same 4-bit a8 weights and int8 pool:
+    a padded bucket (prompts 10 and 7 in 12 positions) and a shared
+    prefix block on lane 0, whose rows and the padding rows go to the
+    trash block.  Logits agree to 1e-5; in every block but the trash one
+    the int8 codes are equal and the f32 scales within one ulp (XLA's CPU
+    backend rounds a fused ``absmax / 127`` differently from the lone
+    op); the shared block keeps what it held."""
+    cfg = C.get_smoke("tinymistral_248m")
+    params, _, _ = quantize_params(
+        lm.init_params(jax.random.PRNGKey(0), cfg),
+        QuantPolicy(bits=4, group_size=32, min_size=1024, act_bits=8))
+    bs, padded, n_blocks = 4, 12, 8
+    trash = n_blocks
+    pool = lm.init_paged_cache(params, cfg, 2, n_blocks + 1, bs, True)
+    rng = np.random.default_rng(0)
+    pool["layers"] = {   # old contents, so that an unwritten block shows
+        name: jnp.asarray(rng.integers(-100, 100, a.shape).astype(a.dtype))
+        for name, a in pool["layers"].items()}
+    lengths = np.asarray([10, 7], np.int32)
+    toks = np.zeros((2, padded), np.int32)
+    for i, n in enumerate(lengths):
+        toks[i, :n] = rng.integers(0, cfg.vocab, n)
+    tables, shared = ([1, 2, 3], [4, 5]), (1, 0)
+    phys = np.full((2, padded), trash, np.int32)
+    for i, n in enumerate(lengths):
+        for t in range(n):
+            if t // bs >= shared[i]:
+                phys[i, t] = tables[i][t // bs]
+    offs = np.tile(np.arange(padded, dtype=np.int32) % bs, (2, 1))
+    slots = np.asarray([0, 1], np.int32)
+    copy = lambda c: jax.tree_util.tree_map(lambda a: jnp.array(a), c)
+
+    logits_e, fresh = lm.prefill(params, jnp.asarray(toks), cfg,
+                                 cache_len=padded, quant_kv=True,
+                                 lengths=jnp.asarray(lengths))
+    pool_e = lm._scatter_blocks(copy(pool), fresh, jnp.asarray(slots),
+                                jnp.asarray(phys.ravel()),
+                                jnp.asarray(offs.ravel()))
+    logits_j, pool_j = lm.prefill_into_blocks(
+        params, jnp.asarray(toks), copy(pool), slots, phys.ravel(),
+        offs.ravel(), cfg, quant_kv=True, lengths=jnp.asarray(lengths))
+
+    np.testing.assert_allclose(np.asarray(logits_j), np.asarray(logits_e),
+                               rtol=0, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(pool_j["length"]),
+                                  np.asarray(pool_e["length"]))
+    for name, a in pool_j["layers"].items():
+        got = np.asarray(a)[:, :trash]
+        want = np.asarray(pool_e["layers"][name])[:, :trash]
+        if a.dtype == jnp.int8:
+            np.testing.assert_array_equal(got, want, err_msg=name)
+        else:
+            np.testing.assert_array_max_ulp(got, want, maxulp=1)
+        np.testing.assert_array_equal(
+            np.asarray(a)[:, 1], np.asarray(pool["layers"][name])[:, 1],
+            err_msg=f"shared block of {name} rewritten")
+        assert not np.array_equal(np.asarray(a)[:, 2],
+                                  np.asarray(pool["layers"][name])[:, 2])

@@ -1,5 +1,5 @@
 """Compile-only checks for a TPU v5e: the LUT-GEMV kernels and the
-serving engine's decode step.
+serving engine's decode and prefill steps.
 
 The TPU compiler is installed beside the CPU backend, so a described (not
 attached) ``v5e:2x2`` topology compiles the kernels exactly as the chip
@@ -148,6 +148,21 @@ def _lower_decode(fn, params, cache, cfg, batch, table_cols, **place):
                     block_tables=sds((batch, table_cols), jnp.int32))
 
 
+def _cell_shapes(cfg, batch, num_blocks, block, one_chip):
+    """Shapes of a cell's ``uniform:4a8`` weights and int8 paged pool,
+    placed on the described chip."""
+    policy = planning.PlanSpec.parse("uniform:4a8").to_policy(
+        QuantPolicy(bits=4, group_size=128, min_size=1024))
+    params = jax.eval_shape(
+        lambda key: quantize_params(lm.init_params(key, cfg), policy)[0],
+        jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: lm.init_paged_cache(
+        None, cfg, batch, num_blocks, block, quant_kv=True))
+    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip)
+    return jax.tree_util.tree_map(place, (params, cache))
+
+
 def test_engine_decode_step_updates_pool_in_place_for_v5e(one_chip):
     """The decode program of the ``tinymistral.decode.q4a8`` cell (batch
     32, ``cache_len`` 3072, block 16, int8 KV, ``uniform:4a8``; 6145
@@ -158,16 +173,7 @@ def test_engine_decode_step_updates_pool_in_place_for_v5e(one_chip):
     batch, cache_len, block = 32, 3072, 16
     cols = cache_len // block
     num_blocks = batch * cols + 1
-    policy = planning.PlanSpec.parse("uniform:4a8").to_policy(
-        QuantPolicy(bits=4, group_size=128, min_size=1024))
-    params = jax.eval_shape(
-        lambda key: quantize_params(lm.init_params(key, cfg), policy)[0],
-        jax.random.PRNGKey(0))
-    cache = jax.eval_shape(lambda: lm.init_paged_cache(
-        None, cfg, batch, num_blocks, block, quant_kv=True))
-    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=one_chip)
-    params, cache = jax.tree_util.tree_map(place, (params, cache))
+    params, cache = _cell_shapes(cfg, batch, num_blocks, block, one_chip)
     compiled = _lower_decode(lm.donating(lm.decode_step), params, cache,
                              cfg, batch, cols, sharding=one_chip).compile()
     hlo = compiled.as_text()
@@ -176,6 +182,26 @@ def test_engine_decode_step_updates_pool_in_place_for_v5e(one_chip):
             >= pool_bytes(cache) > 0.6e9)
     assert attention_reads_pool_in_place(hlo, batch, cols, block,
                                          cfg.n_kv * cfg.head_dim)
+
+
+def test_engine_prefill_updates_pool_in_place_for_v5e(one_chip):
+    """The prefill program of the ``tinymistral.decode.q4a8`` cell at its
+    smallest admission shape (one prompt padded to 128; the cell's pool,
+    int8 KV, ``uniform:4a8``): one program, whose output pool takes
+    over the donated pool's buffers.  (Its scatter still relays the int8
+    pools out through another layout and back, as the lone scatter
+    program did: PERF.md §7.)"""
+    cfg = C.get_config("tinymistral_248m")
+    batch, cache_len, block, padded = 32, 3072, 16, 128
+    num_blocks = batch * (cache_len // block) + 1
+    params, cache = _cell_shapes(cfg, batch, num_blocks, block, one_chip)
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                             sharding=one_chip)
+    compiled = lm.prefill_into_blocks.lower(
+        params, sds((1, padded)), cache, sds((1,)), sds((padded,)),
+        sds((padded,)), cfg, quant_kv=True, lengths=sds((1,))).compile()
+    assert (compiled.memory_analysis().alias_size_in_bytes
+            >= pool_bytes(cache) > 0.6e9)
 
 
 @pytest.mark.parametrize("donate", [True, False], ids=["donating", "plain"])
@@ -219,17 +245,8 @@ def test_mellum_decode_step_compiles_for_v5e(one_chip):
     batch, cache_len, block = 32, 5632, 16
     cols = cache_len // block
     num_blocks = batch * cols + 1
-    policy = planning.PlanSpec.parse("uniform:4a8").to_policy(
-        QuantPolicy(bits=4, group_size=128, min_size=1024))
-    params = jax.eval_shape(
-        lambda key: quantize_params(lm.init_params(key, cfg), policy)[0],
-        jax.random.PRNGKey(0))
+    params, cache = _cell_shapes(cfg, batch, num_blocks, block, one_chip)
     assert isinstance(params["blocks"]["moe"]["router"], jax.ShapeDtypeStruct)
-    cache = jax.eval_shape(lambda: lm.init_paged_cache(
-        None, cfg, batch, num_blocks, block, quant_kv=True))
-    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=one_chip)
-    params, cache = jax.tree_util.tree_map(place, (params, cache))
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                     sharding=one_chip)
     compiled = lm.donating(lm.decode_step).lower(
